@@ -55,6 +55,15 @@ COST_TOLERANCE = 0.05
 # partial reprs) churn per process; scrub them so the program hash is
 # stable across runs of the same code.
 _ADDR_RE = re.compile(r"0x[0-9a-fA-F]+")
+# A set prints in hash-seed order (shard_map's ``manual_axes=frozenset(
+# {'pipe', 'data'})``): sorted, so the program hash is the same in every
+# process.
+_SET_RE = re.compile(r"frozenset\(\{([^{}]*)\}\)")
+
+
+def _sorted_set(m: "re.Match") -> str:
+    items = sorted(x.strip() for x in m.group(1).split(","))
+    return "frozenset({" + ", ".join(items) + "})"
 
 
 class AuditUsageError(LintUsageError):
@@ -181,7 +190,7 @@ class EntrypointContext:
             static = _static_argnums(self.spec)
 
             def trace():
-                with jax.experimental.enable_x64():
+                with jax.enable_x64(True):
                     return jax.make_jaxpr(
                         self.spec.fn, static_argnums=static
                     )(*self.spec.args)
@@ -305,7 +314,7 @@ class EntrypointContext:
         """Content-addressed identity of the abstract program: the
         signature plus the address-scrubbed jaxpr text. Stable across
         processes for identical code; any semantic edit reopens it."""
-        body = _ADDR_RE.sub("0x", str(self.jaxpr))
+        body = _SET_RE.sub(_sorted_set, _ADDR_RE.sub("0x", str(self.jaxpr)))
         digest = hashlib.blake2s(
             (self.signature() + "\n" + body).encode(), digest_size=10
         ).hexdigest()
@@ -333,9 +342,11 @@ def _static_argnums(spec: ProgramSpec) -> tuple[int, ...]:
 
 
 def _subjaxprs(v, jax) -> Iterable:
-    if isinstance(v, jax.core.ClosedJaxpr):
+    from jax.extend import core as jex_core
+
+    if isinstance(v, jex_core.ClosedJaxpr):
         yield v.jaxpr
-    elif isinstance(v, jax.core.Jaxpr):
+    elif isinstance(v, jex_core.Jaxpr):
         yield v
     elif isinstance(v, (tuple, list)):
         for vv in v:

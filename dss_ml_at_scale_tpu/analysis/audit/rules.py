@@ -300,9 +300,10 @@ class ShardingCollectivesRule(AuditRule):
 
 # -- host interop -------------------------------------------------------------
 
+# jax 0.9.0 names: ``jax.debug.print`` lowers to its own ``debug_print``
+# primitive, ``jax.debug.callback`` to ``debug_callback``.
 _CALLBACK_PRIMS = {
-    "pure_callback", "io_callback", "debug_callback", "callback",
-    "outside_call",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
 }
 
 

@@ -8,10 +8,12 @@ discipline, now framework-owned): exactly one JSON line on stdout
 durable partials at ``--partial`` for parent-side salvage, exit 0
 either way — the parent judges the JSON, not the return code.
 
-The environment fingerprint is deliberately NOT computed here: the
-parent fingerprints once (it may need a jax import this child's
-scenario never pays for), and child-side samples are keyed by the
-parent's view of the host they both run on.
+The environment fingerprint is not computed in a scenario child (its
+scenario may never pay for a jax import). The isolating parent stays off
+JAX too — it would hold the chip its children need — so it takes the
+fingerprint from ``--fingerprint``, a child that prints
+``environment_fingerprint()`` as one JSON line and exits before the
+first scenario child starts.
 """
 
 from __future__ import annotations
@@ -21,15 +23,25 @@ import json
 import sys
 import traceback
 
-from .core import get_scenario, measure_scenario
+from .core import environment_fingerprint, get_scenario, measure_scenario
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(prog="dss_ml_at_scale_tpu.bench")
-    ap.add_argument("--scenario", required=True)
+    ap.add_argument("--scenario")
+    ap.add_argument("--fingerprint", action="store_true")
     ap.add_argument("--partial", default=None)
     ap.add_argument("--repetitions", type=int, default=None)
     args = ap.parse_args(argv)
+    if args.fingerprint:
+        # dsst: ignore[no-print] the one-JSON-line child protocol
+        print(json.dumps(environment_fingerprint()))
+        return 0
+    if not args.scenario:
+        ap.error("one of --scenario / --fingerprint is required")
+    from ..runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     try:
         sc = get_scenario(args.scenario)
         record = measure_scenario(

@@ -196,6 +196,29 @@ def environment_fingerprint() -> dict:
     }
 
 
+def child_fingerprint(timeout_s: float = 300.0) -> dict:
+    """:func:`environment_fingerprint` taken in a child of its own.
+
+    The isolating parent never touches JAX: a process that has, holds
+    the chip, and the scenario children that need it then fail or hang.
+    The fingerprint child exits (and lets the chip go) before the first
+    scenario child starts. It sees the parent's environment, XLA_FLAGS
+    included, so the device count is the one ``needs_mesh`` children
+    see."""
+    proc = subprocess.run(
+        [sys.executable, "-m", "dss_ml_at_scale_tpu.bench", "--fingerprint"],
+        cwd=str(REPO_ROOT), timeout=timeout_s, capture_output=True,
+        text=True,
+    )
+    lines = proc.stdout.strip().splitlines()
+    if proc.returncode != 0 or not lines:
+        tail = " | ".join((proc.stderr or "").strip().splitlines()[-3:])
+        raise BenchUsageError(
+            f"fingerprint child failed (rc={proc.returncode}): {tail}"
+        )
+    return json.loads(lines[-1])
+
+
 def fingerprint_key(env: Mapping[str, Any]) -> str:
     parts = (
         str(env.get("platform", "?")),
@@ -581,7 +604,7 @@ def run_bench(
     if repetitions is not None and repetitions < 1:
         raise BenchUsageError("repetitions must be >= 1")
     names = resolve_selection(scenarios, tier)
-    env = environment_fingerprint()
+    env = child_fingerprint() if isolation else environment_fingerprint()
     fp_key = fingerprint_key(env)
     bl_path = (
         DEFAULT_BENCH_BASELINE if baseline_path is None else baseline_path

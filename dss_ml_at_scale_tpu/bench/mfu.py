@@ -24,9 +24,31 @@ from __future__ import annotations
 import json
 from pathlib import Path
 
-# Public peak bf16 figures per chip (bench.py's roofline table, shared
-# here so utilization and the headline sweep price peak identically).
-PEAK_BF16_FLOPS = {"TPU v5 lite": 197e12, "TPU v4": 275e12}
+# Public per-chip peaks (Google Cloud documentation, "TPU v5e": 197
+# TFLOP/s bf16, 819 GB/s HBM), keyed by the exact ``device_kind`` string
+# JAX reports on that chip. The one table: bench.py's sweep reads it too.
+PEAK_BF16_FLOPS = {"TPU v5 lite": 197e12}
+PEAK_HBM_BYTES = {"TPU v5 lite": 819e9}
+
+
+class UnknownDeviceKind(LookupError):
+    pass
+
+
+def peak_for(table: dict, device_kind: str | None) -> float | None:
+    """The peak of ``device_kind`` from ``table``; None for the CPU (a
+    CPU run has no utilization to report). Any other kind missing from
+    the table raises — another chip's peak is never assumed, and
+    utilization is never dropped in silence."""
+    kind = device_kind or ""
+    if kind in table:
+        return table[kind]
+    if kind == "cpu":
+        return None
+    raise UnknownDeviceKind(
+        f"unknown device kind {kind!r}: no peak in bench/mfu.py "
+        f"(known: {', '.join(sorted(table))})"
+    )
 
 
 def pinned_flops(entrypoint: str,
@@ -50,9 +72,11 @@ def pinned_flops(entrypoint: str,
 def publish_achieved(entrypoint: str, steps_per_sec: float, *,
                      device_kind: str | None = None,
                      baseline_path: Path | None = None) -> dict | None:
-    """Set the achieved-FLOPs/s (and, when the device's peak is known,
-    utilization) gauges for ``entrypoint``; returns the published block
-    or None when the entrypoint has no pinned budget."""
+    """Set the achieved-FLOPs/s and utilization gauges for
+    ``entrypoint``; returns the published block or None when the
+    entrypoint has no pinned budget. On the CPU ``utilization`` is None;
+    for any other device kind without a peak in the table the block
+    names the gap (``"peak": "unknown device kind <k>"``)."""
     from .. import telemetry
 
     flops = pinned_flops(entrypoint, baseline_path)
@@ -71,7 +95,11 @@ def publish_achieved(entrypoint: str, steps_per_sec: float, *,
         "achieved_flops_per_sec": achieved,
         "utilization": None,
     }
-    peak = PEAK_BF16_FLOPS.get(device_kind or "")
+    try:
+        peak = peak_for(PEAK_BF16_FLOPS, device_kind)
+    except UnknownDeviceKind:
+        peak = None
+        block["peak"] = f"unknown device kind {device_kind}"
     if peak:
         util = achieved / peak
         telemetry.gauge(

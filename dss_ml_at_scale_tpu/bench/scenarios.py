@@ -376,7 +376,7 @@ register_scenario(Scenario(
 
 
 def _group_panel(n_sku: int, weeks: int, seed: int = 0):
-    """Synthetic demand panel at the BENCH_r05 group-child recipe
+    """Synthetic demand panel at bench.py's group-child recipe
     (level + damped random walk + noise, weekly dates), built
     vectorized so 10k-SKU setup is numpy-bound, not loop-bound."""
     import numpy as np
@@ -402,14 +402,14 @@ def _group_panel(n_sku: int, weeks: int, seed: int = 0):
 
 def _group_mesh():
     """The operator mesh for the group-fit launches: every REAL device
-    the box has — the shape ``dsst forecast`` runs and the shape
-    BENCH_r05's group child measured 1.28 skus/sec on. On an 8-chip box
-    this is exactly the audited ``sarimax.batched_fit`` topology; on a
-    CPU host the harness's 8-way multiplexed view exists for structural
-    audits, not silicon — partitioning the vectorized fit plane across
-    fake devices only fragments it, so the launch runs single-device
-    there (what the r05 comparison point did). The per-SKU math (and so
-    the audit FLOPs pin pricing the launches) is identical either way.
+    the box has — the shape ``dsst forecast`` runs (unmeasured on the
+    chip). On an 8-chip box this is exactly the audited
+    ``sarimax.batched_fit`` topology; on a CPU host the harness's 8-way
+    multiplexed view exists for structural audits, not silicon —
+    partitioning the vectorized fit plane across fake devices only
+    fragments it, so the launch runs single-device there. The per-SKU
+    math (and so the audit FLOPs pin pricing the launches) is identical
+    either way.
     """
     import jax
 
@@ -481,9 +481,7 @@ register_scenario(Scenario(
     "weeks x the full 8-order grid of the reduced bench bounds) "
     "through tune_and_forecast_panel on the operator mesh — ONE "
     "launch fits and tunes every SKU via the sarimax.batched_fit "
-    "program family, so the audit FLOPs pin prices skus/sec "
-    "(BENCH_r05 group-child comparison point: 1.28 skus/sec per-round "
-    "TPE at this 32-group geometry)",
+    "program family, so the audit FLOPs pin prices skus/sec",
     tier="tier1",
     metrics=(
         Metric("group_fit_skus_per_sec", "skus/sec", "higher",

@@ -92,10 +92,9 @@ def _cmd_datagen_demand(args: argparse.Namespace) -> int:
     # accelerator from a data-prep subprocess.
     import jax
 
-    try:
-        jax.config.update("jax_platforms", "cpu")
-    except RuntimeError:
-        pass  # backend already initialized by the calling process
+    # Ignored by jax once a backend is up in this process: a caller that
+    # goes on to use the chip runs this command in a child of its own.
+    jax.config.update("jax_platforms", "cpu")
 
     from ..datagen.demand import DemandConfig, generate_demand, write_demand_delta
 
@@ -183,8 +182,8 @@ def register_forecast(sub: argparse._SubParsersAction) -> None:
     )
     fc.add_argument(
         "--chunk-size", type=int, default=None,
-        help="groups per grid-fused launch (default: min(G, 1024), "
-        "rounded up to the mesh axis)",
+        help="groups per grid-fused launch (default: min(G, 64 per "
+        "device of the mesh), rounded up to the mesh axis)",
     )
     fc.add_argument("--max-evals", type=int, default=10,
                     help="TPE rounds (--search tpe only)")
@@ -1928,6 +1927,7 @@ def _cmd_serve_lm(args: argparse.Namespace) -> int:
     except ValueError as e:
         print(e)
         return 1
+    device_facts: dict = {}
     if args.stub:
         decoder = StubLMDecoder(
             vocab_size=args.vocab, step_ms=args.step_ms,
@@ -1947,13 +1947,23 @@ def _cmd_serve_lm(args: argparse.Namespace) -> int:
             attention=args.attention,
         )
         variables = model.init(
-            jax.random.PRNGKey(args.seed),
+            jax.random.key(args.seed),
             jnp.zeros((1, config.prefill_buckets[0]), jnp.int32),
         )
         decoder = TransformerDecoder(
             model, variables, slots=args.slots, max_len=args.max_len,
             buckets=config.prefill_buckets,
         )
+        from ..runtime.compile_cache import enable_compile_cache
+
+        # The boot line names the device the weights sit on, as JAX
+        # reports it, and where compiled programs are cached.
+        dev = jax.devices()[0]
+        device_facts = {
+            "device": {"platform": dev.platform, "kind": dev.device_kind,
+                       "count": len(jax.devices())},
+            "compile_cache_dir": enable_compile_cache(),
+        }
     # The tracker's journaled start event (pid + boot id) is what lets
     # `dsst runs doctor` classify a SIGKILL'd replica as INTERRUPTED —
     # the chaos drill's whole observability story.
@@ -1972,6 +1982,7 @@ def _cmd_serve_lm(args: argparse.Namespace) -> int:
         "prefill_buckets": list(config.prefill_buckets),
         "queue_depth": config.queue_depth,
         "deadline_ms": config.deadline_ms,
+        **device_facts,
     }), flush=True)
     try:
         while handle.thread.is_alive():
@@ -2139,8 +2150,8 @@ def register_runs(sub: argparse._SubParsersAction) -> None:
         help="after the sweep, re-execute the recorded dsst command of "
         "each interrupted run that has a resumable checkpoint (or a "
         "journaled HPO trial log), with --resume-auto ensured — "
-        "sequentially, newest run per checkpoint dir first; what "
-        "tpu_watchdog.sh runs so a recovered TPU VM re-enters training "
+        "sequentially, newest run per checkpoint dir first; what a "
+        "supervisor runs so a recovered TPU VM re-enters training "
         "instead of idling",
     )
     dr.set_defaults(fn=_cmd_runs_doctor)
@@ -2238,8 +2249,7 @@ def _doctor_resume(report: list[dict]) -> int:
     One relaunch per checkpoint dir (the newest run wins — older
     interrupted runs of the same dir are superseded by the resumed one);
     journal-only HPO runs resume once per experiment. Sequential on
-    purpose: on a freshly recovered TPU VM the device lease is single-
-    owner.
+    purpose: a chip belongs to one process at a time.
     """
     import subprocess
 
@@ -3292,6 +3302,15 @@ def _cmd_bench(args: argparse.Namespace) -> int:
         write_bench_baseline,
     )
 
+    in_process = (
+        getattr(args, "bench_cmd", None) == "profile" or args.in_process
+    )
+    if in_process:
+        # Only a process that measures inline touches jax; the isolating
+        # parent stays off it (its children hold the chip in turn).
+        from ..runtime.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
     try:
         if getattr(args, "bench_cmd", None) == "profile":
             from ..bench.profile import profile_scenario
@@ -3336,7 +3355,7 @@ def _cmd_bench(args: argparse.Namespace) -> int:
         )
         res = run_bench(
             scenarios, tier=args.tier, repetitions=args.repetitions,
-            baseline_path=baseline, isolation=not args.in_process,
+            baseline_path=baseline, isolation=not in_process,
             require_baseline=args.require_baseline,
         )
         if args.update_baseline:

@@ -5,8 +5,8 @@ overlap for free from torch DataLoader + CUDA streams; the first JAX
 port approximated it with *pull-driven* double buffering
 (``prefetch_to_mesh``): the training thread itself still sharded and
 enqueued every batch, so that host work — layout staging, sharding
-validation, ``device_put`` dispatch — serialized with step dispatch.
-``BENCH_r05.json`` put the cost at ~30% of step time on the CI box.
+validation, ``device_put`` dispatch — serialized with step dispatch
+(its share of step time is unmeasured on the chip).
 
 The fix is the tf.data shape (Murray et al., VLDB 2021): a dedicated
 **feeder thread per consumer**. The feeder pulls host batches from the

@@ -34,9 +34,12 @@ _load_error: str | None = None
 def _src_hash() -> str:
     """Cache key: source content + host ISA identity.
 
-    The .so is built with ``-march=native``; on a shared checkout (NFS,
-    baked image) a binary from a newer CPU would SIGILL on an older one,
-    so the host's cpu flags are part of the staleness key.
+    The .so is built with ``-march=native`` and is not tracked by git,
+    but a copy of the working tree (a baked image, the chip tool's copy
+    of the disk) carries it to other hosts, where a binary from a newer
+    CPU would SIGILL. So the host's CPU model and feature flags are part
+    of the staleness key: on any other CPU the stamp does not match and
+    ``_load`` rebuilds instead of loading.
     """
     import hashlib
     import platform
@@ -44,10 +47,14 @@ def _src_hash() -> str:
     isa = platform.machine()
     try:
         with open("/proc/cpuinfo") as f:
+            wanted = {"model name", "flags", "Features"}
             for line in f:
-                if line.startswith(("flags", "Features")):
+                key = line.split(":", 1)[0].strip()
+                if key in wanted:
                     isa += line
-                    break
+                    wanted.discard(key)
+                    if key != "model name":
+                        break
     except OSError:
         pass
     return hashlib.sha256(_SRC.read_bytes() + isa.encode()).hexdigest()

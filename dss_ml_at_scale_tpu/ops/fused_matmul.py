@@ -69,6 +69,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ._pallas import resolve_interpret
+
 __all__ = ["bn_relu_matmul"]
 
 # M-dimension tile: small enough that every site's VMEM working set
@@ -88,11 +90,6 @@ def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
     pad = [(0, 0)] * x.ndim
     pad[axis] = (0, mult - rem)
     return jnp.pad(x, pad)
-
-
-def _interpret_default() -> bool:
-    # Interpret mode on CPU hosts (tests, dryruns); compiled on TPU.
-    return jax.default_backend() == "cpu"
 
 
 def _n_tile(n: int) -> int:
@@ -387,8 +384,7 @@ def bn_relu_matmul(
     k, n = kernel.shape
     if y.shape[-1] != k:
         raise ValueError(f"y channels {y.shape[-1]} != kernel K {k}")
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = resolve_interpret(interpret)
 
     lead = y.shape[:-1]
     m = 1

@@ -251,11 +251,16 @@ def device_put_groups(tree, mesh, axis_name: str = "data"):
 
 # -- grid-fused group fit: chunk → shard → one launch per chunk --------------
 
-# Bound on groups per launch: caps live panel + fit-plane memory on
-# device (a chunk holds chunk_size x K simultaneous fits) and keeps the
-# launch family at ONE compiled shape — every chunk, including the
-# ragged tail, is padded to exactly this many rows.
-DEFAULT_GRID_CHUNK = 1024
+# Bound on groups per launch and per device: caps live panel + fit-plane
+# memory on device (a chunk holds chunk_size x K simultaneous fits) and
+# keeps the launch family at ONE compiled shape — every chunk, including
+# the ragged tail, is padded to exactly this many rows. Sized for the
+# 16 GB of one TPU v5e chip at `dsst forecast`'s default order bounds
+# (75 orders, state dim 25, ~157 weeks): compiled for that chip, 64
+# groups need 12.45 GiB of temporaries and 128 are refused (23.93G of
+# 15.75G hbm); the former 1024 was refused outright for one 44 GB
+# buffer, f32[1024,75,3,12,25,25].
+GRID_CHUNK_PER_DEVICE = 64
 
 
 class GridPanelResult(NamedTuple):
@@ -375,7 +380,7 @@ def grid_fit_panel(
             f"n_train {len(n_train)}, n_valid {len(n_valid)}"
         )
     n_shards = int(mesh.shape[axis_name]) if mesh is not None else 1
-    C = int(chunk_size or min(G, DEFAULT_GRID_CHUNK))
+    C = int(chunk_size or min(G, GRID_CHUNK_PER_DEVICE * n_shards))
     C = max(-(-C // n_shards) * n_shards, n_shards)
     order_grid = np.asarray(
         grid_orders(cfg) if orders is None else orders, np.int32

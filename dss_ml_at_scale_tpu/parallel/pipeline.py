@@ -43,6 +43,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = [
@@ -54,8 +55,6 @@ __all__ = [
     "stack_stage_params",
     "stage_sharding",
 ]
-
-from ._compat import shard_map_unchecked
 
 
 def check_same_mesh(task_mesh: Mesh, mesh: Mesh, what: str) -> None:
@@ -182,8 +181,9 @@ def spmd_pipeline(
             jax.tree_util.tree_map(lambda _: stage_spec, stacked),
             io_spec,
         )
-        fn = shard_map_unchecked(
-            local, mesh=mesh, in_specs=specs, out_specs=io_spec
+        fn = shard_map(
+            local, mesh=mesh, in_specs=specs, out_specs=io_spec,
+            check_vma=False,
         )
         return fn(stacked, xs)
 

@@ -39,9 +39,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ._compat import shard_map_unchecked
 
 _NEG_INF = -1e30
 
@@ -136,7 +135,8 @@ def ring_attention(
         raise ValueError("ring attention requires sq == sk (self-attention)")
     spec = P(None, None, axis_name, None)
     local = functools.partial(_ring_local, axis_name=axis_name, causal=causal)
-    fn = shard_map_unchecked(
-        local, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec
+    fn = shard_map(
+        local, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
     )
     return fn(q, k, v)

@@ -7,6 +7,7 @@ from .mesh import (  # noqa: F401
     replicated_sharding,
     shard_batch_to_mesh,
 )
+from .compile_cache import enable_compile_cache  # noqa: F401
 from .topology import Topology, local_topology  # noqa: F401
 from .distributed import initialize_distributed  # noqa: F401
 from .rpc import (  # noqa: F401

@@ -20,7 +20,8 @@ from __future__ import annotations
 import threading
 from typing import Sequence
 
-import jax
+# jax is imported where it is used: a launcher that only starts children
+# (dsst bench) imports telemetry and must stay off jax.
 
 
 def device_memory_stats(device) -> dict:
@@ -65,6 +66,8 @@ class DeviceMonitor:
             registry = get_registry()
         self.registry = registry
         self.interval_s = interval_s
+        import jax
+
         self.devices = (
             list(devices) if devices is not None else jax.local_devices()
         )
@@ -94,6 +97,8 @@ class DeviceMonitor:
     def _live_counts() -> dict:
         """Live jax.Array count per device (one pass over live arrays —
         cheap at sampling cadence; {} when the runtime can't say)."""
+        import jax
+
         counts: dict = {}
         try:
             for a in jax.live_arrays():

@@ -27,7 +27,8 @@ import contextlib
 import time
 from typing import Callable, Iterator
 
-import jax
+# jax is imported where it is used: telemetry imports this module, and a
+# launcher that only starts children (dsst bench) must stay off jax.
 
 
 @contextlib.contextmanager
@@ -38,15 +39,11 @@ def trace(logdir: str, *, host_tracer_level: int = 2) -> Iterator[None]:
     XProf and shows the XLA op timeline on device plus host-side Python
     activity — the diagnostic the reference's epoch print stood in for.
     """
-    # ProfileOptions is newer than some installed jaxlibs; fall back to a
-    # plain trace (default host tracer level) when it's absent.
-    options_cls = getattr(jax.profiler, "ProfileOptions", None)
-    if options_cls is not None:
-        options = options_cls()
-        options.host_tracer_level = host_tracer_level
-        jax.profiler.start_trace(logdir, profiler_options=options)
-    else:
-        jax.profiler.start_trace(logdir)
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.host_tracer_level = host_tracer_level
+    jax.profiler.start_trace(logdir, profiler_options=options)
     try:
         yield
     finally:
@@ -55,6 +52,8 @@ def trace(logdir: str, *, host_tracer_level: int = 2) -> Iterator[None]:
 
 def annotate(name: str):
     """Named trace span: ``with annotate("decode"): ...``."""
+    import jax
+
     return jax.profiler.TraceAnnotation(name)
 
 

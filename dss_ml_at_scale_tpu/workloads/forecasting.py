@@ -53,7 +53,7 @@ SEARCH_SPACE = {
 
 # The benchmark/audit geometry of the grid-fused group-fit chunk: the
 # `dsst bench` `group_fit` tier-1 gate, the audited
-# `sarimax.batched_fit` entrypoint, and BENCH_r05's group-child liveness
+# `sarimax.batched_fit` entrypoint, and bench.py's group-child liveness
 # config (32 groups x 40 weeks, reduced order bounds) all describe THIS
 # program, so the pinned FLOPs budget prices the measured launches.
 # bfgs_iter=0: the vmapped BFGS line search serializes the fit plane on

@@ -296,6 +296,9 @@ def make_server(predictor, host: str = "127.0.0.1",
         # clients reuse the connection instead of paying TCP setup per
         # request under load.
         protocol_version = "HTTP/1.1"
+        # Headers and body leave in two sends; with Nagle on, the body
+        # waits for the client's delayed ACK (~40 ms on loopback).
+        disable_nagle_algorithm = True
         # Keep-alive's tax: an idle connection parks a handler thread in
         # readline(). The socket timeout reaps it; without this a quiet
         # client would pin a thread forever.
@@ -649,6 +652,7 @@ def make_lm_server(engine, host: str = "127.0.0.1", port: int = 8008, *,
 
     class LMHandler(BaseHTTPRequestHandler):
         protocol_version = "HTTP/1.1"
+        disable_nagle_algorithm = True  # see Handler: token lines are small sends
         timeout = 60
 
         _trace_id = None

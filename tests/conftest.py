@@ -6,8 +6,8 @@ host-platform device multiplexing: 8 virtual CPU devices behave like an
 8-chip slice for sharding/collective semantics (not performance).
 
 This must run before any test triggers JAX backend init, hence conftest
-import time: XLA_FLAGS via env, platform via jax.config (the env var
-alone is overridden by preregistered PJRT plugins on some hosts).
+import time: XLA_FLAGS via env, platform via jax.config (so the suite is
+on the CPU even when the caller forgot ``JAX_PLATFORMS=cpu``).
 """
 
 import os
@@ -20,13 +20,13 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
-# NO persistent compilation cache here, deliberately: this jaxlib's CPU
-# backend crashes the whole process (SIGSEGV/SIGABRT, not an exception)
-# when it DEserializes a cached executable — the first in-process
-# cache hit (e.g. the second fit of a resume test compiling the
-# identical train_step) aborts the suite. Compile-time savings are not
-# worth a hard crash; re-enable only after verifying
-# serialize→deserialize round-trips on the installed jaxlib.
+# No persistent compilation cache under the suite:
+# runtime.enable_compile_cache is a no-op while jax_platforms is "cpu"
+# (tests/test_compile_cache.py). An older jaxlib's CPU backend crashed
+# on reading back a cached executable; jaxlib 0.9.0 does not (re-checked
+# in PR 22: a second process read its entries and ran them), but it logs
+# a machine-feature mismatch error per hit, and a cached CPU executable
+# is tied to the host CPU it was built on — nothing the suite wants.
 
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402

@@ -49,9 +49,9 @@ def main() -> None:
 
     import jax
 
-    # Env JAX_PLATFORMS is overridden by preregistered PJRT plugins on
-    # some hosts; force the CPU platform in-process (tests/conftest.py
-    # does the same).
+    # Force the CPU platform in-process, whatever the caller's
+    # environment says (tests/conftest.py does the same): these children
+    # never need a chip.
     jax.config.update("jax_platforms", "cpu")
 
     from dss_ml_at_scale_tpu.runtime import (

@@ -69,6 +69,18 @@ def _audit(builders: dict, rules: list[str], baseline=NO_BASELINE):
     )
 
 
+def test_program_hash_ignores_set_print_order():
+    """A set's repr follows the process's hash seed (shard_map prints
+    ``manual_axes=frozenset({...})``); the program hash must not."""
+    from dss_ml_at_scale_tpu.analysis.audit import core
+
+    a = "shard_map[manual_axes=frozenset({'pipe', 'data'}) x=1]"
+    b = "shard_map[manual_axes=frozenset({'data', 'pipe'}) x=1]"
+    assert a != b
+    assert core._SET_RE.sub(core._sorted_set, a) == b
+    assert core._SET_RE.sub(core._sorted_set, b) == b
+
+
 # -- the real gate: the live registry is clean against the baseline ----------
 
 
@@ -190,7 +202,7 @@ def test_host_interop_flags_callback_in_jit():
         {"fixture.host_interop.pos": fx.build_positive}, ["host-interop"]
     )
     assert [f.ident for f in res.findings] == [
-        "callback:debug_callback"
+        "callback:debug_print"
     ], [f.text() for f in res.findings]
 
 

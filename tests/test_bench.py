@@ -532,6 +532,34 @@ def test_cli_synthetic_regression_exits_nonzero(tmp_path, capsys):
     assert m["verdict"] == "regression"
 
 
+def test_cli_parent_never_imports_jax():
+    """The isolating parent of `dsst bench` only starts children (the
+    fingerprint child, then one per scenario): a parent that touched JAX
+    would hold the chip its children need."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys, json\n"
+        "from dss_ml_at_scale_tpu.config.cli import main\n"
+        "rc = main(['bench', '--scenarios', 'sanitizer_overhead',"
+        " '--repetitions', '1', '--json'])\n"
+        "print(json.dumps({'rc': rc, 'jax_in_parent': 'jax' in sys.modules}))\n"
+    )
+    proc = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=600, cwd=str(Path(__file__).parent.parent),
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    lines = proc.stdout.strip().splitlines()
+    verdict = json.loads(lines[-1])
+    report = json.loads("\n".join(lines[:-1]))
+    assert verdict["jax_in_parent"] is False
+    # ...while the children did run: a fingerprint and a measured record.
+    assert report["fingerprint"]["platform"] == "cpu"
+    assert "sanitizer_overhead" in report["results"]
+
+
 def test_cli_tier1_smoke_gate(capsys):
     """The CI gate: the full tier-1 subset runs in isolated children
     against the committed BENCH_BASELINE.json with registry coverage —
@@ -608,16 +636,39 @@ def test_mfu_gauges_priced_by_audit_pin():
     assert mfu.pinned_flops("no.such.entrypoint") is None
 
     block = mfu.publish_achieved(
-        "train_step.classifier", 10.0, device_kind="TPU v4",
+        "train_step.classifier", 10.0, device_kind="TPU v5 lite",
     )
     assert block["achieved_flops_per_sec"] == pytest.approx(flops * 10.0)
     assert block["utilization"] == pytest.approx(
-        flops * 10.0 / mfu.PEAK_BF16_FLOPS["TPU v4"]
+        flops * 10.0 / mfu.PEAK_BF16_FLOPS["TPU v5 lite"]
     )
     text = telemetry.render_prometheus()
     assert "entrypoint_achieved_flops_per_sec" in text
     assert "entrypoint_flops_utilization" in text
     assert mfu.publish_achieved("no.such.entrypoint", 10.0) is None
+
+
+@pytest.mark.parametrize("kind,peak_note", [
+    ("cpu", None),
+    ("TPU v4", "unknown device kind TPU v4"),
+    ("TPU v5e", "unknown device kind TPU v5e"),
+])
+def test_mfu_never_assumes_another_chips_peak(kind, peak_note):
+    """A device kind without a peak in the table gets no utilization and
+    says so by name (the CPU has none to report); the lookup itself
+    raises for anything but the CPU."""
+    from dss_ml_at_scale_tpu.bench import mfu
+
+    block = mfu.publish_achieved(
+        "train_step.classifier", 10.0, device_kind=kind,
+    )
+    assert block["utilization"] is None
+    assert block.get("peak") == peak_note
+    if peak_note is None:
+        assert mfu.peak_for(mfu.PEAK_BF16_FLOPS, kind) is None
+    else:
+        with pytest.raises(mfu.UnknownDeviceKind, match=kind):
+            mfu.peak_for(mfu.PEAK_HBM_BYTES, kind)
 
 
 def test_mfu_publish_from_trace(tmp_path):

@@ -313,20 +313,40 @@ def test_fmin_nonfinite_loss_is_isolated():
     assert sum(r["status"] == "fail" for r in trials.results) == 1
 
 
-def test_two_device_trials_smoke_logic(tmp_path, monkeypatch, devices8):
-    # The on-chip 2-device smoke's pass-path logic, driven on the
-    # simulated slice: two pinned trials must use distinct devices and
-    # genuinely overlap. On real hardware the driver runs the script via
-    # run_tpu_artifacts.sh with the cpu guard active.
-    monkeypatch.setenv("DSST_SMOKE_ALLOW_CPU", "1")
-    monkeypatch.chdir(tmp_path)
-    import smoke_two_device_trials as smoke
+def test_two_device_trials_smoke_logic(devices8):
+    # DeviceTrials' pinning/concurrency contract on the simulated slice:
+    # two pinned trials must use distinct devices and genuinely overlap.
+    import threading
+    import time
 
-    assert smoke.main() == 0
-    import json
+    import jax.numpy as jnp
 
-    out = json.loads((tmp_path / "TRIALS_2DEV.json").read_text())
-    assert out["passed"] is True
-    assert out["trials_ok"] == 8
-    assert len(out["distinct_devices_used"]) >= 2
-    assert out["max_concurrent"] >= 2
+    from dss_ml_at_scale_tpu.hpo import fmin, hp
+    from dss_ml_at_scale_tpu.parallel import DeviceTrials
+
+    seen: set[str] = set()
+    concurrent = {"now": 0, "max": 0}
+    lock = threading.Lock()
+
+    def objective(x):
+        with lock:
+            concurrent["now"] += 1
+            concurrent["max"] = max(concurrent["max"], concurrent["now"])
+        try:
+            arr = jnp.ones((256, 256)) * x
+            val = float(jnp.sum(arr * arr).block_until_ready())
+            with lock:
+                seen.add(str(next(iter(arr.devices()))))
+            time.sleep(0.3)  # hold the device so trials genuinely overlap
+            return {"loss": abs(val), "status": "ok"}
+        finally:
+            with lock:
+                concurrent["now"] -= 1
+
+    trials = DeviceTrials(devices=devices8[:2], parallelism=2)
+    fmin(objective, hp.uniform("x", -1, 1), max_evals=8, trials=trials,
+         rstate=np.random.default_rng(0), return_argmin=False)
+
+    assert sum(t["result"]["status"] == "ok" for t in trials.trials) == 8
+    assert len(seen) >= 2
+    assert concurrent["max"] >= 2
