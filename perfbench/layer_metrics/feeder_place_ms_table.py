@@ -1,0 +1,4 @@
+"""``feeder_place_ms`` in a cell fed from a table: the same reading, moving
+``train_samples_per_s.table`` (PERF.md, section 2: one bound a metric)."""
+
+from layer_metrics.feeder_place_ms import read  # noqa: F401

@@ -1,0 +1,4 @@
+"""``step_device_ms_train`` in a cell fed from a table: the same reading, moving
+``train_samples_per_s.table`` (PERF.md, section 2: one bound a metric)."""
+
+from layer_metrics.step_device_ms_train import read  # noqa: F401
