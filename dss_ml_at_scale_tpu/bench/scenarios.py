@@ -268,7 +268,7 @@ def _attribution_buckets(tail_path, since: float) -> dict[str, float]:
     trace attribution`` reads, so this cross-check and the CLI tool
     cannot drift apart."""
     from ..telemetry import flightrec
-    from ..telemetry.catalog import SPAN_ATTRIBUTION
+    from ..telemetry.catalog import SPAN_ATTRIBUTION, SPAN_NESTED
 
     complete, _opens = flightrec.reconstruct(
         flightrec.read_events(tail_path)
@@ -276,7 +276,8 @@ def _attribution_buckets(tail_path, since: float) -> dict[str, float]:
     buckets = {"data_wait": 0.0, "transfer": 0.0, "compute": 0.0,
                "host": 0.0}
     for e in complete:
-        if e.get("kind") != "step" or e.get("ts", 0.0) < since:
+        if (e.get("kind") != "step" or e.get("ts", 0.0) < since
+                or e.get("name") in SPAN_NESTED):
             continue
         buckets[SPAN_ATTRIBUTION.get(e.get("name"), "host")] += e.get(
             "dur", 0.0

@@ -3088,6 +3088,7 @@ def _cmd_trace_attribution(args: argparse.Namespace) -> int:
     # the telemetry package pulls jax, which every other subcommand's
     # startup must not pay.
     from ..telemetry.catalog import SPAN_ATTRIBUTION as _ATTRIBUTION
+    from ..telemetry.catalog import SPAN_NESTED
 
     path = _trace_source(args)
     if path is None:
@@ -3095,7 +3096,8 @@ def _cmd_trace_attribution(args: argparse.Namespace) -> int:
     complete, opens = flightrec.reconstruct(flightrec.read_events(path))
     by_trace: dict[str, list[dict]] = {}
     for e in complete:
-        if e.get("kind") == "step" and e.get("trace"):
+        if (e.get("kind") == "step" and e.get("trace")
+                and e.get("name") not in SPAN_NESTED):
             by_trace.setdefault(e["trace"], []).append(e)
     steps = []
     for trace_id, spans in by_trace.items():

@@ -37,7 +37,7 @@ import logging
 import queue
 import threading
 import time
-from typing import Iterator, Sequence
+from typing import Any, Callable, Iterator, NamedTuple, Sequence
 
 import numpy as np
 import pyarrow.parquet as pq
@@ -59,6 +59,19 @@ _SENTINEL = object()
 # Transient-read retry shape: two quick retries cover an NFS/object-store
 # blip without meaningfully delaying a genuinely failed epoch.
 _READ_RETRY = RetryPolicy(max_retries=2, base_delay=0.05, max_delay=0.5)
+
+
+class _ReaderTelemetry(NamedTuple):
+    """The reader's spans and series, bound once a reader (the import of
+    telemetry is lazy: see ``_telemetry_handles``)."""
+
+    span: Callable
+    queue_depth: Any
+    stall_total: Any
+    read_seconds: Any
+    decode_seconds: Any
+    rows_total: Any
+    workers: Any
 
 
 class _WorkerError:
@@ -172,15 +185,34 @@ class ParquetShardReader:
         if handles is None:
             from .. import telemetry
 
-            handles = self._telemetry = (
-                telemetry.gauge(
+            stage = telemetry.counter(
+                "reader_stage_seconds_total",
+                "cumulative time inside one stage of loading a row "
+                "group, summed over the threads that load",
+                labels=("stage",),
+            )
+            handles = self._telemetry = _ReaderTelemetry(
+                span=telemetry.span,
+                queue_depth=telemetry.gauge(
                     "reader_queue_depth",
                     "decoded row groups waiting in the results queue at "
                     "last consumer read",
                 ),
-                telemetry.counter(
+                stall_total=telemetry.counter(
                     "reader_stall_seconds_total",
                     "cumulative consumer wait on the decode queue",
+                ),
+                read_seconds=stage.labels(stage="read"),
+                decode_seconds=stage.labels(stage="decode"),
+                rows_total=telemetry.counter(
+                    "reader_rows_total",
+                    "rows read from row groups (before any are dropped "
+                    "as quarantined or corrupt)",
+                ),
+                workers=telemetry.gauge(
+                    "reader_workers",
+                    "threads loading row groups for the iteration in "
+                    "progress (1 for the inline pool)",
                 ),
             )
         return handles
@@ -232,16 +264,21 @@ class ParquetShardReader:
         # once per worker instead of once per row group, and handles are
         # never shared across threads (ParquetFile reads aren't
         # guaranteed thread-safe).
-        cache = self._local.__dict__.setdefault("files", {})
-        pf = cache.get(unit.path)
-        if pf is None:
-            pf = cache[unit.path] = pq.ParquetFile(unit.path)
-        table = pf.read_row_group(unit.row_group, columns=self.columns)
-        cols = {
-            name: _column_to_numpy(table.column(i))
-            for i, name in enumerate(table.column_names)
-        }
+        tel = self._telemetry_handles()
+        t_read = time.perf_counter()
+        with tel.span("reader.read", rows=unit.num_rows):
+            cache = self._local.__dict__.setdefault("files", {})
+            pf = cache.get(unit.path)
+            if pf is None:
+                pf = cache[unit.path] = pq.ParquetFile(unit.path)
+            table = pf.read_row_group(unit.row_group, columns=self.columns)
+            cols = {
+                name: _column_to_numpy(table.column(i))
+                for i, name in enumerate(table.column_names)
+            }
+        tel.read_seconds.inc(time.perf_counter() - t_read)
         num_rows = len(next(iter(cols.values()))) if cols else 0
+        tel.rows_total.inc(num_rows)
         orig_rows = np.arange(num_rows, dtype=np.int64)
         if self.quarantine is not None:
             mask = self.quarantine.keep_mask(
@@ -253,29 +290,43 @@ class ParquetShardReader:
         if fault_fires("sample.corrupt"):
             cols = _corrupt_first_sample(cols)
         if self.transform_spec is not None and len(orig_rows):
+            t_decode = time.perf_counter()
             try:
+                cols, orig_rows = self._transform(tel, unit, cols, orig_rows)
+            finally:
+                tel.decode_seconds.inc(time.perf_counter() - t_decode)
+        return cols, orig_rows
+
+    def _transform(
+        self, tel: _ReaderTelemetry, unit: RowGroupUnit, cols, orig_rows
+    ) -> tuple[dict[str, np.ndarray], np.ndarray]:
+        """The decode stage of one row group: the transform over all
+        its rows, or row by row where that is how corrupt ones are
+        isolated."""
+        try:
+            with tel.span(
+                "reader.decode", rows=len(orig_rows),
+                backend=getattr(self.transform_spec, "backend", None),
+            ):
                 cols = self.transform_spec(cols)
-            except Exception:
-                if self.on_corrupt != "quarantine":
-                    raise
-                cols, orig_rows = self._isolate_corrupt_rows(
-                    unit, cols, orig_rows
+        except Exception:
+            if self.on_corrupt != "quarantine":
+                raise
+            return self._isolate_corrupt_rows(unit, cols, orig_rows)
+        n_out = len(next(iter(cols.values()))) if cols else 0
+        if n_out != len(orig_rows):
+            if self.emit_provenance or self.quarantine is not None:
+                # Row-level provenance (and therefore quarantine
+                # exclusion) is only meaningful for row-preserving
+                # transforms; a filtering transform would silently
+                # misattribute rows.
+                raise ValueError(
+                    f"transform changed the row count "
+                    f"({len(orig_rows)} -> {n_out}) in {unit.path}"
+                    f"[rg={unit.row_group}]; provenance/quarantine "
+                    "require a row-preserving transform"
                 )
-            else:
-                n_out = len(next(iter(cols.values()))) if cols else 0
-                if n_out != len(orig_rows):
-                    if self.emit_provenance or self.quarantine is not None:
-                        # Row-level provenance (and therefore quarantine
-                        # exclusion) is only meaningful for row-preserving
-                        # transforms; a filtering transform would silently
-                        # misattribute rows.
-                        raise ValueError(
-                            f"transform changed the row count "
-                            f"({len(orig_rows)} -> {n_out}) in {unit.path}"
-                            f"[rg={unit.row_group}]; provenance/quarantine "
-                            "require a row-preserving transform"
-                        )
-                    orig_rows = np.arange(n_out, dtype=np.int64)
+            orig_rows = np.arange(n_out, dtype=np.int64)
         return cols, orig_rows
 
     def _isolate_corrupt_rows(
@@ -373,7 +424,9 @@ class ParquetShardReader:
         self,
     ) -> Iterator[tuple[dict[str, np.ndarray], np.ndarray]]:
         """Stream ``(cols, orig_rows)`` row groups, in arrival order."""
+        tel = self._telemetry_handles()
         if self.reader_pool_type == "dummy":
+            tel.workers.set(1)
             for unit in self._unit_stream():
                 if self._stop.is_set():
                     return
@@ -386,7 +439,6 @@ class ParquetShardReader:
         # Decode-pipeline health gauges: queue depth says whether workers
         # keep ahead of the consumer; stall time is the consumer-side
         # cost when they don't (the "is training input-bound?" number).
-        queue_gauge, stall_total = self._telemetry_handles()
         self._threads = [
             threading.Thread(
                 target=self._worker, args=(work, lock, results), daemon=True,
@@ -397,14 +449,16 @@ class ParquetShardReader:
         for t in self._threads:
             t.start()
         live = len(self._threads)
+        tel.workers.set(live)
         try:
             while live:
                 wait_t0 = time.perf_counter()
                 item = results.get()
-                stall_total.inc(time.perf_counter() - wait_t0)
-                queue_gauge.set(results.qsize())
+                tel.stall_total.inc(time.perf_counter() - wait_t0)
+                tel.queue_depth.set(results.qsize())
                 if item is _SENTINEL:
                     live -= 1
+                    tel.workers.set(live)
                     continue
                 if isinstance(item, _WorkerError):
                     raise RuntimeError(
@@ -417,6 +471,7 @@ class ParquetShardReader:
             # raised here is actionable (workers are daemon threads).
             try:
                 self.stop()
+                tel.workers.set(0)
             # dsst: ignore[bare-except] generator finalizer at interpreter shutdown: nothing raised here is actionable
             except BaseException:
                 pass
@@ -445,11 +500,26 @@ class ParquetShardReader:
             buf.append((group, unit.path, unit.row_group, orig_rows))
             buffered += _num_rows(group)
             while buffered >= self.batch_size:
-                batch, prov, buf, buffered = _take(buf, self.batch_size)
-                yield self._finish_batch(batch, prov)
+                batch, buf, buffered = self._assemble(buf, self.batch_size)
+                yield batch
         if buffered and not self.drop_last:
-            batch, prov, _, _ = _take(buf, buffered)
-            yield self._finish_batch(batch, prov)
+            batch, _, _ = self._assemble(buf, buffered)
+            yield batch
+
+    def _assemble(self, buf, n):
+        """One n-row batch off the buffered row groups: the serial copy
+        on the consumer's thread (the feeder's, under ``reader.next``)."""
+        need, groups = n, 0
+        for group, *_ in buf:
+            if need <= 0:
+                break
+            need -= _num_rows(group)
+            groups += 1
+        with self._telemetry_handles().span(
+            "reader.assemble", rows=n, groups=groups
+        ):
+            batch, prov, rest, buffered = _take(buf, n)
+            return self._finish_batch(batch, prov), rest, buffered
 
     def _finish_batch(self, batch, prov) -> dict[str, np.ndarray]:
         if self.emit_provenance:

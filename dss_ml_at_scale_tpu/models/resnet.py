@@ -19,6 +19,7 @@ import functools
 from typing import Any, Callable, Sequence
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 ModuleDef = Any
@@ -248,13 +249,17 @@ class ResNet(nn.Module):
             epsilon=1e-5,
             dtype=self.dtype,
         )
-        x = x.astype(self.dtype)
-        x = conv(self.num_filters, (7, 7), (2, 2), name="conv_init")(x)
-        x = _norm_relu(norm, self.act, self.fused_bn, x, name="norm_init")
-        x = nn.max_pool(
-            x, (3, 3), strides=(2, 2),
-            padding=((1, 1), (1, 1)) if self.torch_padding else "SAME",
-        )
+        # The named scopes put "stem", "stage1".., "head" into every
+        # operation's name in the compiled step (forward and backward),
+        # which is where a device trace can be read by layer.
+        with jax.named_scope("stem"):
+            x = x.astype(self.dtype)
+            x = conv(self.num_filters, (7, 7), (2, 2), name="conv_init")(x)
+            x = _norm_relu(norm, self.act, self.fused_bn, x, name="norm_init")
+            x = nn.max_pool(
+                x, (3, 3), strides=(2, 2),
+                padding=((1, 1), (1, 1)) if self.torch_padding else "SAME",
+            )
         for i, block_count in enumerate(self.stage_sizes):
             for j in range(block_count):
                 strides = 2 if i > 0 and j == 0 else 1
@@ -264,18 +269,20 @@ class ResNet(nn.Module):
                      "pallas_axis": self.pallas_axis}
                     if self.fused_bn == "pallas" else {}
                 )
-                x = self.block_cls(
-                    filters=self.num_filters * 2**i,
-                    strides=strides,
-                    conv=conv,
-                    norm=norm,
-                    act=self.act,
-                    fused=self.fused_bn,
-                    **block_kw,
-                )(x)
-        x = jnp.mean(x, axis=(1, 2))
-        x = nn.Dense(self.num_classes, dtype=jnp.float32)(x)
-        return x.astype(jnp.float32)
+                with jax.named_scope(f"stage{i + 1}"):
+                    x = self.block_cls(
+                        filters=self.num_filters * 2**i,
+                        strides=strides,
+                        conv=conv,
+                        norm=norm,
+                        act=self.act,
+                        fused=self.fused_bn,
+                        **block_kw,
+                    )(x)
+        with jax.named_scope("head"):
+            x = jnp.mean(x, axis=(1, 2))
+            x = nn.Dense(self.num_classes, dtype=jnp.float32)(x)
+            return x.astype(jnp.float32)
 
 
 ResNet18 = functools.partial(ResNet, stage_sizes=[2, 2, 2, 2], block_cls=ResNetBlock)

@@ -92,66 +92,78 @@ class TransformerBlock(nn.Module):
         b, s, dim = x.shape
         head_dim = dim // self.num_heads
 
-        h = RMSNorm(dtype=self.dtype)(x)
-        qkv = nn.Dense(3 * dim, use_bias=False, dtype=self.dtype, name="qkv")(h)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+        with jax.named_scope("attn"):
+            h = RMSNorm(dtype=self.dtype)(x)
+            qkv = nn.Dense(
+                3 * dim, use_bias=False, dtype=self.dtype, name="qkv"
+            )(h)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
 
-        def heads(t):  # [b, s, dim] -> [b, heads, s, head_dim]
-            return t.reshape(b, s, self.num_heads, head_dim).transpose(0, 2, 1, 3)
+            def heads(t):  # [b, s, dim] -> [b, heads, s, head_dim]
+                return t.reshape(
+                    b, s, self.num_heads, head_dim
+                ).transpose(0, 2, 1, 3)
 
-        if cache is not None:
-            # Both cached modes write this call's k/v into the cache
-            # slab at ``pos``; they differ only in how attn is computed.
-            k_cache = jax.lax.dynamic_update_slice_in_dim(
-                cache["k"], heads(k), pos, axis=2
-            )
-            v_cache = jax.lax.dynamic_update_slice_in_dim(
-                cache["v"], heads(v), pos, axis=2
-            )
-            new_cache = {"k": k_cache, "v": v_cache}
-            if s == 1:
-                # Decode step: attend the single query over the cache
-                # with a <= pos mask. Plain einsums — at q_len 1 there
-                # is nothing for a kernel to tile.
-                scores = jnp.einsum(
-                    "bhqd,bhkd->bhqk", heads(q), k_cache,
-                    preferred_element_type=jnp.float32,
-                ) / jnp.sqrt(head_dim).astype(jnp.float32)
-                mask = jnp.arange(k_cache.shape[2]) <= pos
-                scores = jnp.where(mask[None, None, None, :], scores, -1e30)
-                probs = jax.nn.softmax(scores, axis=-1)
-                attn = jnp.einsum(
-                    "bhqk,bhkd->bhqd", probs, v_cache.astype(jnp.float32)
-                ).astype(self.dtype)
+            if cache is not None:
+                # Both cached modes write this call's k/v into the cache
+                # slab at ``pos``; they differ only in how attn is computed.
+                k_cache = jax.lax.dynamic_update_slice_in_dim(
+                    cache["k"], heads(k), pos, axis=2
+                )
+                v_cache = jax.lax.dynamic_update_slice_in_dim(
+                    cache["v"], heads(v), pos, axis=2
+                )
+                new_cache = {"k": k_cache, "v": v_cache}
+                if s == 1:
+                    # Decode step: attend the single query over the cache
+                    # with a <= pos mask. Plain einsums — at q_len 1 there
+                    # is nothing for a kernel to tile.
+                    scores = jnp.einsum(
+                        "bhqd,bhkd->bhqk", heads(q), k_cache,
+                        preferred_element_type=jnp.float32,
+                    ) / jnp.sqrt(head_dim).astype(jnp.float32)
+                    mask = jnp.arange(k_cache.shape[2]) <= pos
+                    scores = jnp.where(
+                        mask[None, None, None, :], scores, -1e30
+                    )
+                    probs = jax.nn.softmax(scores, axis=-1)
+                    attn = jnp.einsum(
+                        "bhqk,bhkd->bhqd", probs, v_cache.astype(jnp.float32)
+                    ).astype(self.dtype)
+                else:
+                    # Prefill (pos == 0, enforced by TransformerLM): the
+                    # whole prompt in ONE causal parallel pass — the
+                    # training-shaped matmuls, nothing earlier to attend to.
+                    attn = self.attention_fn(heads(q), heads(k), heads(v))
             else:
-                # Prefill (pos == 0, enforced by TransformerLM): the
-                # whole prompt in ONE causal parallel pass — the
-                # training-shaped matmuls, nothing earlier to attend to.
                 attn = self.attention_fn(heads(q), heads(k), heads(v))
-        else:
-            attn = self.attention_fn(heads(q), heads(k), heads(v))
-            new_cache = None
-        attn = attn.transpose(0, 2, 1, 3).reshape(b, s, dim)
-        x = x + nn.Dense(dim, use_bias=False, dtype=self.dtype, name="proj")(attn)
+                new_cache = None
+            attn = attn.transpose(0, 2, 1, 3).reshape(b, s, dim)
+            x = x + nn.Dense(
+                dim, use_bias=False, dtype=self.dtype, name="proj"
+            )(attn)
 
-        h = RMSNorm(dtype=self.dtype)(x)
-        if self.ffn == "moe":
-            from .moe import MoEMLP
+        with jax.named_scope("mlp"):
+            h = RMSNorm(dtype=self.dtype)(x)
+            if self.ffn == "moe":
+                from .moe import MoEMLP
 
-            x = x + MoEMLP(
-                num_experts=self.num_experts,
-                mlp_ratio=self.mlp_ratio,
-                capacity_factor=self.capacity_factor,
-                dtype=self.dtype,
-                mesh=self.expert_mesh,
-                axis_name=self.expert_axis,
-                router_noise=self.router_noise,
-                name="moe",
-            )(h, deterministic=deterministic)
-        else:
-            h = nn.Dense(self.mlp_ratio * dim, dtype=self.dtype, name="mlp_up")(h)
-            h = nn.gelu(h)
-            x = x + nn.Dense(dim, dtype=self.dtype, name="mlp_down")(h)
+                x = x + MoEMLP(
+                    num_experts=self.num_experts,
+                    mlp_ratio=self.mlp_ratio,
+                    capacity_factor=self.capacity_factor,
+                    dtype=self.dtype,
+                    mesh=self.expert_mesh,
+                    axis_name=self.expert_axis,
+                    router_noise=self.router_noise,
+                    name="moe",
+                )(h, deterministic=deterministic)
+            else:
+                h = nn.Dense(
+                    self.mlp_ratio * dim, dtype=self.dtype, name="mlp_up"
+                )(h)
+                h = nn.gelu(h)
+                x = x + nn.Dense(dim, dtype=self.dtype, name="mlp_down")(h)
         return x if cache is None else (x, new_cache)
 
 
@@ -224,11 +236,12 @@ class TransformerLM(nn.Module):
             nn.initializers.normal(0.02),
             (self.max_seq, self.dim),
         )
-        if decoding:
-            pos_emb = jax.lax.dynamic_slice_in_dim(pos_table, pos, s)[None]
-        else:
-            pos_emb = pos_table[None, :s]
-        x = tok(tokens) + pos_emb.astype(self.dtype)
+        with jax.named_scope("embed"):
+            if decoding:
+                pos_emb = jax.lax.dynamic_slice_in_dim(pos_table, pos, s)[None]
+            else:
+                pos_emb = pos_table[None, :s]
+            x = tok(tokens) + pos_emb.astype(self.dtype)
         new_cache = []
         for i in range(self.num_layers):
             block = TransformerBlock(
@@ -251,11 +264,13 @@ class TransformerLM(nn.Module):
                 new_cache.append(layer_cache)
             else:
                 x = block(x, deterministic=deterministic)
-        x = RMSNorm(dtype=self.dtype)(x)
-        # Logits in f32 for a stable softmax cross-entropy.
-        logits = nn.Dense(
-            self.vocab_size, use_bias=False, dtype=jnp.float32, name="lm_head"
-        )(x)
+        with jax.named_scope("lm_head"):
+            x = RMSNorm(dtype=self.dtype)(x)
+            # Logits in f32 for a stable softmax cross-entropy.
+            logits = nn.Dense(
+                self.vocab_size, use_bias=False, dtype=jnp.float32,
+                name="lm_head",
+            )(x)
         if decoding:
             # Single-step callers get the one row; prefill callers get
             # the full [b, s, vocab] (the last row seeds sampling).

@@ -153,6 +153,7 @@ def _flash_forward(
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q, k, v)
 
 

@@ -189,14 +189,18 @@ class ClassifierTask:
                     {"params": params}, images, train=True
                 )
                 new_stats = state.batch_stats
-            loss = cross_entropy_loss(logits, labels)
+            with jax.named_scope("loss"):
+                loss = cross_entropy_loss(logits, labels)
             return loss, (logits, new_stats)
 
         (loss, (logits, new_stats)), grads = jax.value_and_grad(
             loss_fn, has_aux=True
         )(state.params)
-        updates, new_opt = self.tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = self.tx.update(
+                grads, state.opt_state, state.params
+            )
+            new_params = optax.apply_updates(state.params, updates)
         metrics = {
             "train_loss": loss,
             "train_acc": multiclass_accuracy(logits, labels),
@@ -281,13 +285,19 @@ class LMTask:
                     {"params": params}, tokens, mutable=["intermediates"]
                 )
                 aux = collect_aux_loss(inter["intermediates"])
-                return next_token_loss(logits, tokens) + self.aux_loss_weight * aux
+                with jax.named_scope("loss"):
+                    return (next_token_loss(logits, tokens)
+                            + self.aux_loss_weight * aux)
             logits = self.model.apply({"params": params}, tokens)
-            return next_token_loss(logits, tokens)
+            with jax.named_scope("loss"):
+                return next_token_loss(logits, tokens)
 
         loss, grads = jax.value_and_grad(loss_fn)(state.params)
-        updates, new_opt = self.tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = self.tx.update(
+                grads, state.opt_state, state.params
+            )
+            new_params = optax.apply_updates(state.params, updates)
         return (
             TrainState(
                 step=state.step + 1,

@@ -156,6 +156,27 @@ class Generation:
         self.cancelled = True
 
 
+def _epoch_offset() -> float:
+    """What to add to a ``perf_counter`` mark to put it on the span log's
+    clock (epoch seconds): one reading of both clocks."""
+    return time.time() - time.perf_counter()
+
+
+def _record_step_parts(t0: float, t1: float, t2: float, t3: float) -> None:
+    """The three parts of one decoder step as complete records: call to
+    dispatched, to the logits ready on the device, to the logits on the
+    host. A with-span each would cost a begin event and a
+    ``TraceAnnotation`` for intervals of microseconds; the enclosing
+    ``lm.step`` span is the one a flight recorder sees open."""
+    log, wall = telemetry.get_span_log(), _epoch_offset()
+    # dsst: ignore[span-discipline] three records a decode step inside the lm.step span (docstring)
+    log.record("lm.dispatch", wall + t0, t1 - t0)
+    # dsst: ignore[span-discipline] as lm.dispatch above
+    log.record("lm.wait", wall + t1, t2 - t1)
+    # dsst: ignore[span-discipline] as lm.dispatch above
+    log.record("lm.fetch", wall + t2, t3 - t2)
+
+
 class TransformerDecoder:
     """The real backend: audited slot-decode/prefill/scatter programs.
 
@@ -216,14 +237,25 @@ class TransformerDecoder:
         return np.asarray(row, np.float32)
 
     def step(self, tokens: np.ndarray, pos: np.ndarray) -> np.ndarray:
-        """One ``slot_decode`` over every slot; returns [slots, vocab]."""
+        """One ``slot_decode`` over every slot; returns [slots, vocab].
+
+        Three intervals of the engine's thread, each recorded: the
+        dispatch (two small host-to-device copies and the jitted call
+        returning), the wait for the device, the copy of the logits to
+        the host."""
         jnp = self._jnp
+        t0 = time.perf_counter()
         logits, self._arena = self._step_fn(
             self.model, self.variables,
             jnp.asarray(tokens, jnp.int32), self._arena,
             jnp.asarray(pos, jnp.int32),
         )
-        return np.asarray(logits, np.float32)
+        t1 = time.perf_counter()
+        logits.block_until_ready()
+        t2 = time.perf_counter()
+        out = np.asarray(logits, np.float32)
+        _record_step_parts(t0, t1, t2, time.perf_counter())
+        return out
 
 
 class StubLMDecoder:
@@ -260,10 +292,16 @@ class StubLMDecoder:
         return row
 
     def step(self, tokens: np.ndarray, pos: np.ndarray) -> np.ndarray:
+        # The same three intervals as the real backend records: nothing
+        # to dispatch, the sleep stands for the device, the one-hot rows
+        # for the copy of the logits.
+        t0 = t1 = time.perf_counter()
         time.sleep(self.step_ms / 1000.0)
+        t2 = time.perf_counter()
         out = np.zeros((self.slots, self.vocab_size), np.float32)
         for i in range(self.slots):
             out[i, self._next(tokens[i], pos[i])] = 1.0
+        _record_step_parts(t0, t1, t2, time.perf_counter())
         return out
 
 
@@ -324,12 +362,13 @@ class LMEngine:
             "generations retired, by reason",
             labels=("reason",),
         )
-        self._prefill_hist = telemetry.histogram(
-            "lm_prefill_seconds", "bucketed prefill latency (per admission)"
+        prefill_tokens = telemetry.counter(
+            "lm_prefill_tokens_total",
+            "prompt tokens prefilled: real, and padded to the bucket",
+            labels=("kind",),
         )
-        self._step_hist = telemetry.histogram(
-            "lm_decode_step_seconds", "slot_decode latency (per step)"
-        )
+        self._prefill_real = prefill_tokens.labels(kind="real")
+        self._prefill_padded = prefill_tokens.labels(kind="padded")
         self._ttft_window = telemetry.window(
             "lm_ttft_window_seconds",
             "live windowed time-to-first-token (admit -> first chunk)",
@@ -519,6 +558,8 @@ class LMEngine:
                     self._cond.wait(0.05)
                 if self._stopped:
                     return
+                t_admit = time.perf_counter()
+                scanned = len(self._waiting)
                 now = time.monotonic()
                 still_waiting = []
                 for gen in self._waiting:
@@ -543,6 +584,13 @@ class LMEngine:
                     error=DeadlineExceeded(
                         "deadline passed before a slot freed"
                     ),
+                )
+            if scanned:
+                # dsst: ignore[span-discipline] recorded only when the scan had something to look at, with counts known at its end
+                telemetry.get_span_log().record(
+                    "lm.admit", _epoch_offset() + t_admit,
+                    time.perf_counter() - t_admit,
+                    admitted=len(admitted), waiting=len(still_waiting),
                 )
             for gen, slot in admitted:
                 try:
@@ -574,11 +622,11 @@ class LMEngine:
         )
         padded = np.zeros((1, bucket), np.int32)
         padded[0, : len(prompt)] = prompt
-        t0 = time.perf_counter()
         with telemetry.span("lm.prefill", bucket=bucket,
                             prompt_tokens=len(prompt)):
             row = self.decoder.prefill(padded, len(prompt), slot)
-        self._prefill_hist.observe(time.perf_counter() - t0)
+        self._prefill_real.inc(len(prompt))
+        self._prefill_padded.inc(bucket)
         gen.n_past = len(prompt)
         token = gen.sample(row)
         now = time.monotonic()
@@ -607,19 +655,22 @@ class LMEngine:
         for slot, gen in active.items():
             tokens[slot] = gen.last_token
             pos[slot] = gen.n_past
-        t0 = time.perf_counter()
-        with telemetry.span("lm.step", active=len(active)):
+        with telemetry.span("lm.step", active=len(active),
+                            context_tokens=int(pos.sum())):
             logits = self.decoder.step(tokens, pos)
-        self._step_hist.observe(time.perf_counter() - t0)
+        t_sample = time.perf_counter()
         now = time.monotonic()
+        retired = 0
         for slot in sorted(active):
             gen = active[slot]
             gen.n_past += 1
             if gen.cancelled:
                 self._retire_slot(slot, gen, reason="cancelled")
+                retired += 1
                 continue
             if gen.deadline is not None and now > gen.deadline:
                 self._retire_slot(slot, gen, reason="deadline")
+                retired += 1
                 continue
             try:
                 token = gen.sample(logits[slot])
@@ -628,6 +679,7 @@ class LMEngine:
                 # retires this slot with an error event; the step loop
                 # and every other stream keep running.
                 self._retire_slot(slot, gen, reason="error", error=exc)
+                retired += 1
                 continue
             gap = now - (gen.t_last if gen.t_last is not None else now)
             gen.t_last = now
@@ -636,6 +688,13 @@ class LMEngine:
             self._slo.note_inter_token(gap, trace_id=gen.trace_id)
             if self._should_retire(gen, token):
                 self._retire_slot(slot, gen)
+                retired += 1
+        # dsst: ignore[span-discipline] the count of retired slots is known only at close; the loop is host-only work of well under a millisecond with no device call to be cut short in
+        telemetry.get_span_log().record(
+            "lm.sample", _epoch_offset() + t_sample,
+            time.perf_counter() - t_sample,
+            active=len(active), retired=retired,
+        )
 
     def _emit(self, gen: Generation, token: int) -> None:
         gen.last_token = token

@@ -49,7 +49,6 @@ KNOWN_METRICS: dict[str, str] = {
     "worker_readmitted_total": "counter",
     # -- tracing / flight recorder ----------------------------------------
     "flight_recorder_bytes_total": "counter",
-    "trace_spans_total": "counter",
     # -- device / compile --------------------------------------------------
     "device_hbm_bytes_in_use": "gauge",
     "device_hbm_bytes_limit": "gauge",
@@ -68,7 +67,10 @@ KNOWN_METRICS: dict[str, str] = {
     "ingest_bytes_total": "counter",
     "ingest_rows_total": "counter",
     "reader_queue_depth": "gauge",
+    "reader_rows_total": "counter",
+    "reader_stage_seconds_total": "counter",
     "reader_stall_seconds_total": "counter",
+    "reader_workers": "gauge",
     # -- training / HPO ----------------------------------------------------
     "hpo_trials_total": "counter",
     "skus_fitted_total": "counter",
@@ -89,9 +91,8 @@ KNOWN_METRICS: dict[str, str] = {
     "slo_alerts_firing": "gauge",
     "train_step_window_seconds": "window",
     # -- LM token serving --------------------------------------------------
-    "lm_decode_step_seconds": "histogram",
     "lm_inter_token_window_seconds": "window",
-    "lm_prefill_seconds": "histogram",
+    "lm_prefill_tokens_total": "counter",
     "lm_queue_depth": "gauge",
     "lm_retired_total": "counter",
     "lm_slots_active": "gauge",
@@ -131,6 +132,12 @@ KNOWN_SPANS: dict[str, str] = {
     "health_rollback": "restore-from-checkpoint on a health rollback",
     # -- input pipeline ----------------------------------------------------
     "reader.next": "feeder thread pulling one host batch from the reader",
+    "reader.read": "a loading thread reading one row group and turning "
+                   "its columns into numpy",
+    "reader.decode": "a loading thread running the transform (JPEG "
+                     "decode, resize, crop, normalise) over one row group",
+    "reader.assemble": "the consumer's thread copying buffered row "
+                       "groups into one batch (inside reader.next)",
     "feeder.place": "feeder thread staging + sharding one batch onto "
                     "devices",
     "mesh.plan": "MeshBatchPlacer building a placement plan for a new "
@@ -146,6 +153,14 @@ KNOWN_SPANS: dict[str, str] = {
                   "(admission into a free slot)",
     "lm.step": "one slot_decode dispatch over every slot (all active "
                "generations advance one token)",
+    "lm.dispatch": "inside lm.step: host-to-device copies of tokens and "
+                   "positions and the jitted call returning",
+    "lm.wait": "inside lm.step: until the logits are ready on the device",
+    "lm.fetch": "inside lm.step: the copy of the logits to a host array",
+    "lm.sample": "after lm.step: per-slot sampling, streaming, windows, "
+                 "SLO notes and retirement",
+    "lm.admit": "the admission scan over the waiting list and its "
+                "settlements, when there was anything to scan",
     # -- HPO ---------------------------------------------------------------
     "trial": "one HPO trial evaluation",
     "trial.submit": "driver-side proposal/submission of one trial",
@@ -199,6 +214,17 @@ SPAN_ATTRIBUTION: dict[str, str] = {
     "lm.prefill": "compute",
     "lm.step": "compute",
 }
+
+# Spans that lie inside another span of the same thread and trace:
+# reader.assemble runs on the feeder thread inside reader.next, the
+# three parts of a decode step inside lm.step. The two consumers of
+# SPAN_ATTRIBUTION sum durations a trace, so they leave these out: the
+# enclosing span already holds their wall time. (reader.read and
+# reader.decode run on the reader's own threads under no step's trace,
+# and so never reach a step's buckets.)
+SPAN_NESTED: frozenset[str] = frozenset({
+    "reader.assemble", "lm.dispatch", "lm.wait", "lm.fetch",
+})
 
 # Scenario name -> the exact metric keys its schema may emit
 # (``dsst bench``). The ``bench-registry`` lint rule reconciles the

@@ -224,6 +224,13 @@ def disable(path: str | os.PathLike | None = None) -> None:
     _recorder.disable(path)
 
 
+def armed() -> bool:
+    """Whether a tail file is enabled: the span log's test before it
+    builds a recorder event. Read without ``_io_lock`` (one attribute
+    load; a span that straddles ``enable`` goes unrecorded whole)."""
+    return _recorder._path is not None
+
+
 def emit(event: dict) -> None:
     _recorder.emit(event)
 

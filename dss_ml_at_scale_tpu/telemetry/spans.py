@@ -26,10 +26,12 @@ directly in ``ui.perfetto.dev`` or ``chrome://tracing`` — with
 "dsst-serve-batcher" instead of raw tids, and ``ph: "s"/"f"`` flow
 arrows stitching each trace id across its thread hops.
 
-Every span open also feeds the flight recorder
-(:mod:`~dss_ml_at_scale_tpu.telemetry.flightrec`) with a *begin* event,
-so in-flight spans survive a SIGKILL even though this log only records
-at close.
+While a flight recorder is armed
+(:mod:`~dss_ml_at_scale_tpu.telemetry.flightrec`, a tail file enabled)
+every span open also feeds it a *begin* event, so in-flight spans
+survive a SIGKILL even though this log only records at close. With no
+tail armed nothing reads the recorder, and a span costs one event dict
+and one ring append.
 """
 
 from __future__ import annotations
@@ -46,21 +48,6 @@ from typing import Iterable, Iterator, Mapping, Sequence
 from ..utils.jsonl import JsonlWriter
 from ..utils.profiling import annotate
 from . import tracecontext
-
-_spans_total_handle = None
-
-
-def _spans_total():
-    global _spans_total_handle
-    if _spans_total_handle is None:
-        # Local import: this module is imported by telemetry/__init__.
-        from . import counter
-
-        _spans_total_handle = counter(
-            "trace_spans_total", "spans opened on the process span log"
-        )
-    return _spans_total_handle
-
 
 class SpanLog:
     """Bounded in-memory span recorder with optional JSONL tee.
@@ -102,7 +89,8 @@ class SpanLog:
         self._append(event)
         from . import flightrec
 
-        flightrec.emit({**event, "ph": "X"})
+        if flightrec.armed():
+            flightrec.emit({**event, "ph": "X"})
         return event
 
     def _event(self, name: str, ts: float, dur: float,
@@ -142,9 +130,9 @@ class SpanLog:
         labels the region in any active ``jax.profiler`` trace.
 
         Under an active trace the span becomes the context for its
-        body (children point at it), and a *begin* event goes to the
-        flight recorder at open — so a span cut short by SIGKILL is
-        still reconstructible from the recorder tail.
+        body (children point at it), and, while a flight recorder is
+        armed, a *begin* event goes to it at open — so a span cut short
+        by SIGKILL is still reconstructible from the recorder tail.
         """
         from . import flightrec
 
@@ -155,9 +143,12 @@ class SpanLog:
             token = tracecontext._ctx.set(parent.child(span_id))
         t0 = time.time()
         p0 = time.perf_counter()
-        _spans_total().inc()
-        begin = self._event(name, t0, 0.0, parent, args, span_id=span_id)
-        flightrec.emit({**begin, "ph": "B"})
+        # Armed at open decides both events: a tail enabled mid-span
+        # gets no end without its begin.
+        armed = flightrec.armed()
+        if armed:
+            begin = self._event(name, t0, 0.0, parent, args, span_id=span_id)
+            flightrec.emit({**begin, "ph": "B"})
         try:
             with annotate(name):
                 yield
@@ -169,7 +160,8 @@ class SpanLog:
                 span_id=span_id,
             )
             self._append(event)
-            flightrec.emit({**event, "ph": "E"})
+            if armed:
+                flightrec.emit({**event, "ph": "E"})
 
     def events(self) -> list[dict]:
         with self._lock:
