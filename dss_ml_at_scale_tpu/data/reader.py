@@ -27,6 +27,13 @@ Semantics preserved:
 TPU-first notes: output batches are fixed-shape numpy dicts, so the jitted
 train step compiles once; partial trailing batches are dropped by default
 (``drop_last``) rather than triggering a recompile.
+
+A batch that spans row groups is copied into a host buffer the reader has
+used before (:class:`_BufferRing`): a fresh array of a training batch's size
+costs more in first-touch page faults than the copy itself. **A batch may be
+overwritten once every reference to it is dropped**: a consumer that wants
+to keep one keeps a reference to it (the array, the dict, or any view of
+it), as it would to keep any object alive; a raw pointer is not a reference.
 """
 
 from __future__ import annotations
@@ -35,6 +42,7 @@ import contextlib
 import itertools
 import logging
 import queue
+import sys
 import threading
 import time
 from typing import Any, Callable, Iterator, NamedTuple, Sequence
@@ -60,6 +68,15 @@ _SENTINEL = object()
 # blip without meaningfully delaying a genuinely failed epoch.
 _READ_RETRY = RetryPolicy(max_retries=2, base_delay=0.05, max_delay=0.5)
 
+# Most buffers a column's ring grows to. Reckoned from who holds a host
+# batch at once behind ``Trainer.fit``: the feeder's queue of placed batches
+# at its default depth (2; JAX may keep the host array for as long as the
+# placed one lives), the one in the feeder thread's hands, the one under the
+# trainer's step, the trainer's peek at the first batch (held for the whole
+# fit), and the one being filled. A consumer that holds more gets fresh
+# arrays beyond it, as before there was a ring.
+_RING_BOUND = 6
+
 
 class _ReaderTelemetry(NamedTuple):
     """The reader's spans and series, bound once a reader (the import of
@@ -72,6 +89,7 @@ class _ReaderTelemetry(NamedTuple):
     decode_seconds: Any
     rows_total: Any
     workers: Any
+    batch_buffers: Any
 
 
 class _WorkerError:
@@ -163,6 +181,7 @@ class ParquetShardReader:
         # like `queue` may already be torn down by then).
         self._empty_exc = queue.Empty
         self._local = threading.local()
+        self._ring = _BufferRing(batch_size, _RING_BOUND)
 
     # -- diagnostics ------------------------------------------------------
 
@@ -214,6 +233,14 @@ class ParquetShardReader:
                     "threads loading row groups for the iteration in "
                     "progress (1 for the inline pool)",
                 ),
+                batch_buffers=telemetry.counter(
+                    "reader_batch_buffers_total",
+                    "batches by the memory they were assembled into: a "
+                    "buffer used before (recycled), a new allocation "
+                    "(fresh), or none, the batch being a slice of one row "
+                    "group (view)",
+                    labels=("source",),
+                ),
             )
         return handles
 
@@ -221,14 +248,16 @@ class ParquetShardReader:
         """Worst-case host RAM of the decode pipeline, in bytes.
 
         The reference documents this as
-        workers × queue × rows-per-rowgroup × rowsize (``2...py:338``).
+        workers × queue × rows-per-rowgroup × rowsize (``2...py:338``);
+        beside it stand the buffers batches are assembled into, at most
+        ``_RING_BOUND`` of ``batch_size`` rows (a ring grows only as far
+        as its consumer holds batches).
         """
         rows_per_group = max(u.num_rows for u in self._units)
         return (
-            (self.workers_count + self.results_queue_size)
-            * rows_per_group
-            * row_size_bytes
-        )
+            (self.workers_count + self.results_queue_size) * rows_per_group
+            + _RING_BOUND * self.batch_size
+        ) * row_size_bytes
 
     # -- work generation --------------------------------------------------
 
@@ -502,6 +531,9 @@ class ParquetShardReader:
             while buffered >= self.batch_size:
                 batch, buf, buffered = self._assemble(buf, self.batch_size)
                 yield batch
+                # The ring takes a buffer again when nothing but the ring
+                # refers to it: not this frame either.
+                del batch
         if buffered and not self.drop_last:
             batch, _, _ = self._assemble(buf, buffered)
             yield batch
@@ -515,10 +547,10 @@ class ParquetShardReader:
                 break
             need -= _num_rows(group)
             groups += 1
-        with self._telemetry_handles().span(
-            "reader.assemble", rows=n, groups=groups
-        ):
-            batch, prov, rest, buffered = _take(buf, n)
+        tel = self._telemetry_handles()
+        with tel.span("reader.assemble", rows=n, groups=groups):
+            batch, prov, rest, buffered, source = _take(buf, n, self._ring)
+            tel.batch_buffers.labels(source=source).inc()
             return self._finish_batch(batch, prov), rest, buffered
 
     def _finish_batch(self, batch, prov) -> dict[str, np.ndarray]:
@@ -555,12 +587,72 @@ def _num_rows(group: dict[str, np.ndarray]) -> int:
     return len(next(iter(group.values())))
 
 
-def _take(buf, n):
+def _slot_refs(buffers: list, i: int) -> int:
+    return sys.getrefcount(buffers[i])
+
+
+# What ``_slot_refs`` reads of an object that only its list refers to,
+# on this interpreter.
+_SOLE_REFS = _slot_refs([object()], 0)
+
+
+class _BufferRing:
+    """Host buffers that multi-group batches are assembled into, a list
+    a column, each ``[batch_size, ...]`` and taken again only when the
+    ring alone refers to it.
+
+    That is observed, not assumed: the owning array's reference count is
+    back at the ring's own. A view a consumer keeps holds its base; JAX
+    holds the host array it was given until the host-to-device copy is
+    complete (on some paths and backends for as long as the device array
+    lives). With every buffer still referenced and the
+    bound reached, a batch gets a fresh array that the ring does not
+    keep: never a wait, and nothing that anything can still see is
+    written to.
+    """
+
+    def __init__(self, rows: int, bound: int):
+        self._rows, self._bound = rows, bound
+        self._buffers: dict[str, list[np.ndarray]] = {}
+
+    def concatenate(
+        self, name: str, parts: list[np.ndarray]
+    ) -> tuple[np.ndarray, str | None]:
+        """``np.concatenate(parts)`` and the memory it went into:
+        ``"recycled"``, ``"fresh"``, or None for a column the ring does
+        not hold (it holds the numeric ones whose parts share one dtype
+        and row shape, the same from batch to batch)."""
+        dtype, row_shape = parts[0].dtype, parts[0].shape[1:]
+        buffers = self._buffers.get(name, ())
+        if dtype.hasobject or any(
+            (a.dtype, a.shape[1:]) != (dtype, row_shape)
+            for a in (*parts, *buffers[:1])
+        ):
+            return np.concatenate(parts), None
+        for i in range(len(buffers)):
+            if _slot_refs(buffers, i) == _SOLE_REFS:
+                out, source = buffers[i], "recycled"
+                break
+        else:
+            if len(buffers) >= self._bound:
+                return np.concatenate(parts), "fresh"
+            out, source = np.empty((self._rows,) + row_shape, dtype), "fresh"
+            self._buffers.setdefault(name, []).append(out)
+        n = sum(len(p) for p in parts)
+        if n < self._rows:
+            out = out[:n]  # a short tail batch: a view, which holds its base
+        return np.concatenate(parts, out=out), source
+
+
+def _take(buf, n, ring: _BufferRing):
     """Split the buffered row groups into one n-row batch + remainder.
 
     Buffer entries are ``(cols, path, row_group, orig_rows)``; the
     returned ``prov`` mirrors the batch as ``(path, row_group,
     taken_rows)`` triples so provenance slices exactly with the data.
+    A batch that lies inside one row group is a slice of it (``source``
+    ``"view"``); one that spans groups is concatenated through ``ring``,
+    ``"recycled"`` unless some column needed a ``"fresh"`` array.
     """
     taken: dict[str, list[np.ndarray]] = {}
     prov: list[tuple[str, int, np.ndarray]] = []
@@ -581,8 +673,15 @@ def _take(buf, n):
                 path, row_group, orig_rows[use:],
             ))
         need -= use
-    batch = {k: np.concatenate(v) if len(v) > 1 else v[0] for k, v in taken.items()}
-    return batch, prov, rest, sum(_num_rows(g) for g, *_ in rest)
+    batch, memories = {}, set()
+    for k, v in taken.items():
+        if len(v) == 1:
+            batch[k] = v[0]
+            continue
+        batch[k], memory = ring.concatenate(k, v)
+        memories.add(memory)
+    source = next((m for m in ("fresh", "recycled") if m in memories), "view")
+    return batch, prov, rest, sum(_num_rows(g) for g, *_ in rest), source
 
 
 def _corrupt_first_sample(cols: dict[str, np.ndarray]) -> dict[str, np.ndarray]:

@@ -66,6 +66,7 @@ KNOWN_METRICS: dict[str, str] = {
     "feeder_stall_seconds_total": "counter",
     "ingest_bytes_total": "counter",
     "ingest_rows_total": "counter",
+    "reader_batch_buffers_total": "counter",
     "reader_queue_depth": "gauge",
     "reader_rows_total": "counter",
     "reader_stage_seconds_total": "counter",

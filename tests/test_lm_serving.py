@@ -423,8 +423,14 @@ def test_streamed_trace_matches_access_log(lm_server):
     assert done["done"] == "max_tokens"
     assert done["trace"] == injected
     assert len(tokens) == 4
-    rows = [json.loads(l) for l in log.read_text().splitlines()]
-    row = next(r for r in rows if r["request_id"] == injected)
+    # the handler writes the row after the stream's last byte: wait for it
+    deadline = time.monotonic() + 10
+    row = None
+    while row is None and time.monotonic() < deadline:
+        rows = [json.loads(l) for l in log.read_text().splitlines()]
+        row = next((r for r in rows if r["request_id"] == injected), None)
+        time.sleep(0.01)
+    assert row is not None
     assert row["trace_inherited"] is True
     assert row["status"] == 200
     assert row["tokens"] == 4

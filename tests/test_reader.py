@@ -156,8 +156,13 @@ def test_memory_estimate(dataset):
     reader = make_batch_reader(
         dataset, batch_size=4, workers_count=2, results_queue_size=20, num_epochs=1
     )
-    # (2 workers + 20 queue slots) × 16 rows/group × 100 B
-    assert reader.memory_estimate(row_size_bytes=100) == 22 * 16 * 100
+    # (2 workers + 20 queue slots) × 16 rows/group × 100 B, and the ring
+    # that batches are assembled into: its bound × 4 rows/batch × 100 B
+    from dss_ml_at_scale_tpu.data.reader import _RING_BOUND
+
+    assert reader.memory_estimate(row_size_bytes=100) == (
+        22 * 16 * 100 + _RING_BOUND * 4 * 100
+    )
 
 
 def test_stop_unblocks_workers_quickly(dataset):
@@ -325,3 +330,321 @@ def test_sample_corrupt_fault_site_truncates_bytes(tmp_path):
     # Row 0 of the first row group was truncated mid-payload and dropped.
     assert len(values) == 31 and 0.0 not in values
     assert len(q) == 1 and q.entries[0]["row_lo"] == 0
+
+
+# -- batches assembled into recycled host buffers (PR 25) ---------------------
+
+GROUP_ROWS = 16
+
+
+@pytest.fixture(scope="module")
+def ring_dataset(tmp_path_factory):
+    """4 files × 4 row groups × 16 rows = 256 rows: a numeric column, a
+    string column (object dtype once read), and through ``image_spec`` a
+    fixed-shape float32 ``image`` made from ``id``."""
+    root = tmp_path_factory.mktemp("ring")
+    for f in range(4):
+        ids = np.arange(f * 64, (f + 1) * 64)
+        pq.write_table(
+            pa.table({"id": pa.array(ids),
+                      "name": pa.array([f"row-{i}" for i in ids])}),
+            root / f"part-{f}.parquet", row_group_size=GROUP_ROWS,
+        )
+    return sorted(str(p) for p in root.glob("*.parquet"))
+
+
+def image_spec():
+    def decode(cols):
+        ids = cols["id"].astype(np.float32)
+        image = ids[:, None, None, None] + np.arange(
+            8 * 8 * 3, dtype=np.float32).reshape(8, 8, 3) / 1000
+        return {"image": image, "label": cols["id"].astype(np.int32),
+                "name": cols["name"]}
+
+    return TransformSpec(
+        func=decode,
+        fields=[Field("image", np.dtype(np.float32), (8, 8, 3)),
+                Field("label", np.dtype(np.int32), ()),
+                Field("name", np.dtype(object), ())],
+    )
+
+
+def ring_reader(paths, **kw):
+    kw = {"num_epochs": 1, "shuffle_row_groups": False,
+          "reader_pool_type": "dummy", "transform_spec": image_spec(), **kw}
+    return ParquetShardReader(paths, **kw)
+
+
+def buffer_counts():
+    from dss_ml_at_scale_tpu import telemetry
+
+    got = {"recycled": 0.0, "fresh": 0.0, "view": 0.0}
+    for m in telemetry.snapshot()["metrics"]:
+        if m["name"] == "reader_batch_buffers_total":
+            got[m["labels"]["source"]] = m["value"]
+    return got
+
+
+def counted(before):
+    return {k: v - before[k] for k, v in buffer_counts().items()}
+
+
+def spans_groups(batch_index, batch_size, rows=float("inf")):
+    """Whether that batch of a stream in file order lies in more than one
+    row group; the last of ``rows`` may be a short tail."""
+    lo = batch_index * batch_size
+    return lo // GROUP_ROWS != (min(lo + batch_size, rows) - 1) // GROUP_ROWS
+
+
+def _take_before_the_ring(buf, n):
+    """``reader._take`` as it stood before batches were assembled into
+    recycled buffers (PR 24), body unchanged: the oracle."""
+    taken = {}
+    prov = []
+    need = n
+    rest = []
+    for group, path, row_group, orig_rows in buf:
+        if need == 0:
+            rest.append((group, path, row_group, orig_rows))
+            continue
+        rows = len(next(iter(group.values())))
+        use = min(rows, need)
+        for k, v in group.items():
+            taken.setdefault(k, []).append(v[:use])
+        prov.append((path, row_group, orig_rows[:use]))
+        if use < rows:
+            rest.append((
+                {k: v[use:] for k, v in group.items()},
+                path, row_group, orig_rows[use:],
+            ))
+        need -= use
+    batch = {k: np.concatenate(v) if len(v) > 1 else v[0] for k, v in taken.items()}
+    return batch, prov, rest, sum(len(next(iter(g.values()))) for g, *_ in rest)
+
+
+def batches_before_the_ring(reader):
+    """The reader's own loop over its own row groups, with the oracle in
+    ``_take``'s place."""
+    buf, buffered, out = [], 0, []
+
+    def assemble(n):
+        nonlocal buf, buffered
+        batch, prov, buf, buffered = _take_before_the_ring(buf, n)
+        out.append(reader._finish_batch(batch, prov))
+
+    for (group, orig_rows), unit in reader._row_groups():
+        buf.append((group, unit.path, unit.row_group, orig_rows))
+        buffered += len(orig_rows)
+        while buffered >= reader.batch_size:
+            assemble(reader.batch_size)
+    if buffered and not reader.drop_last:
+        assemble(buffered)
+    return out
+
+
+def assert_same_batch(got, want):
+    assert list(got) == list(want)
+    for k in want:
+        if isinstance(want[k], np.ndarray):
+            assert got[k].dtype == want[k].dtype and got[k].shape == want[k].shape
+            np.testing.assert_array_equal(got[k], want[k])
+        else:
+            assert got[k] == want[k]          # provenance: RowRanges
+
+
+# 8: inside a group; 12: inside one or across two; 24: two; 40: three or
+# four; 100: seven, and a 56-row tail that spans four
+@pytest.mark.parametrize("drop_last", [True, False])
+@pytest.mark.parametrize("emit_provenance", [False, True])
+@pytest.mark.parametrize("batch_size", [8, 12, 24, 40, 100])
+def test_ring_batches_equal_the_old_takes(
+    ring_dataset, batch_size, emit_provenance, drop_last
+):
+    kw = dict(batch_size=batch_size, emit_provenance=emit_provenance,
+              drop_last=drop_last)
+    want = batches_before_the_ring(ring_reader(ring_dataset, **kw))
+    assert len(want) == (256 // batch_size if drop_last
+                         else -(-256 // batch_size))
+    before = buffer_counts()
+    n = 0
+    # each batch is dropped before the next is asked for, so buffers are
+    # taken again: a recycled batch reads what a fresh one read
+    for n, got in enumerate(ring_reader(ring_dataset, **kw), 1):
+        assert_same_batch(got, want[n - 1])
+    assert n == len(want)
+    multi = sum(spans_groups(i, batch_size, 256) for i in range(n))
+    assert (multi == 0) == (batch_size == 8)
+    # the loop's variable holds one batch while the next is assembled: two
+    # buffers, each new once
+    fresh = min(2, multi)
+    assert counted(before) == {
+        "recycled": multi - fresh, "fresh": fresh, "view": n - multi}
+
+
+def test_held_batches_are_never_overwritten(ring_dataset):
+    from dss_ml_at_scale_tpu.data.reader import _RING_BOUND
+
+    n = 3 * _RING_BOUND
+    before = buffer_counts()
+    reader = ring_reader(ring_dataset, batch_size=24, num_epochs=None)
+    held, copies = [], []
+    for batch in reader:
+        held.append(batch)
+        copies.append({k: np.array(v) for k, v in batch.items()})
+        if len(held) == n:
+            break
+    reader.stop()
+    for batch, copy in zip(held, copies):
+        assert_same_batch(batch, copy)
+    multi = sum(spans_groups(i, 24) for i in range(n))
+    # nothing came free, so nothing was recycled: the ring grew to its
+    # bound and the batches past it got arrays of their own
+    assert counted(before) == {"recycled": 0, "fresh": multi, "view": n - multi}
+    assert multi > 2 * _RING_BOUND
+    assert [len(v) for v in reader._ring._buffers.values()] == [_RING_BOUND] * 2
+    owners = {id(b) for v in reader._ring._buffers.values() for b in v}
+    pooled = [i for i, b in enumerate(held) if id(b["image"]) in owners]
+    assert len(pooled) == _RING_BOUND
+
+
+def test_dropped_batches_are_recycled(ring_dataset):
+    before = buffer_counts()
+    n = 0
+    for n, batch in enumerate(
+        ring_reader(ring_dataset, batch_size=24, num_epochs=3), 1
+    ):
+        assert batch["image"][0, 0, 0, 0] == batch["label"][0]
+    assert n == 32
+    multi = sum(spans_groups(i, 24) for i in range(n))
+    seen = counted(before)
+    # the loop's variable holds one batch while the next is assembled
+    assert seen["fresh"] == 2 and seen["view"] == n - multi
+    assert seen["recycled"] == multi - 2
+
+
+def test_a_view_a_consumer_keeps_holds_its_buffer(ring_dataset):
+    kept, copies = [], []
+    for batch in ring_reader(ring_dataset, batch_size=24, num_epochs=3):
+        kept.append(batch["image"][5:7, 0, 0])        # a view of a view
+        copies.append(kept[-1].copy())
+    for view, copy in zip(kept, copies):
+        np.testing.assert_array_equal(view, copy)
+
+
+def test_a_short_tail_batch_is_a_view_of_a_buffer(ring_dataset):
+    reader = ring_reader(ring_dataset, batch_size=100, drop_last=False)
+    batches = [{k: v for k, v in b.items()} for b in reader]
+    tail = batches[-1]
+    assert len(tail["label"]) == 56
+    owners = {id(b) for b in reader._ring._buffers["image"]}
+    assert id(tail["image"].base) in owners
+    assert tail["label"].tolist() == list(range(200, 256))
+
+
+def test_device_put_arrays_outlive_the_ring(ring_dataset):
+    import jax
+
+    from dss_ml_at_scale_tpu.data.reader import _RING_BOUND
+
+    assert jax.devices()[0].platform == "cpu"
+    placed, want = [], []
+    reader = ring_reader(ring_dataset, batch_size=24, num_epochs=None)
+    for batch in reader:
+        want.append(int(batch["label"][0]))
+        # the feeder's call: host arrays go in, the host batch is dropped
+        placed.append(jax.device_put(
+            {k: batch[k] for k in ("image", "label")}))
+        if len(placed) > 3 * _RING_BOUND:       # wrapped more than twice
+            break
+    reader.stop()
+    for first, on_device in zip(want, placed):
+        image, label = np.asarray(on_device["image"]), np.asarray(on_device["label"])
+        assert label.tolist() == [i % 256 for i in range(first, first + 24)]
+        np.testing.assert_array_equal(image[:, 0, 0, 0], label.astype(np.float32))
+
+
+def test_object_columns_and_one_group_batches_take_no_buffer(ring_dataset):
+    before = buffer_counts()
+    # strings only, every batch across two groups
+    reader = ParquetShardReader(
+        ring_dataset, batch_size=24, num_epochs=1, columns=["name"],
+        shuffle_row_groups=False, reader_pool_type="dummy")
+    names = [b["name"] for b in reader]
+    assert all(a.dtype == object and len(a) == 24 for a in names)
+    assert np.concatenate(names).tolist() == [f"row-{i}" for i in range(240)]
+    assert reader._ring._buffers == {}
+    # numeric, but each batch is one whole row group
+    reader = ring_reader(ring_dataset, batch_size=GROUP_ROWS)
+    assert len(list(reader)) == 16
+    assert reader._ring._buffers == {}
+    assert counted(before) == {"recycled": 0, "fresh": 0, "view": 26}
+
+
+def test_a_column_whose_row_shape_changes_is_not_pooled(ring_dataset):
+    from dss_ml_at_scale_tpu.data.reader import _BufferRing
+
+    ring = _BufferRing(4, 2)
+    a, b = np.ones((2, 3), np.float32), np.ones((2, 5), np.float32)
+    out, source = ring.concatenate("x", [a, a])
+    assert source == "fresh" and out.shape == (4, 3)
+    del out
+    out, source = ring.concatenate("x", [b, b])
+    assert source is None and out.shape == (4, 5)
+    out, source = ring.concatenate("x", [a, a.astype(np.float64)])
+    assert source is None and out.dtype == np.float64
+    out, source = ring.concatenate("x", [a, a[:1]])
+    assert source == "recycled" and out.shape == (3, 3) and out.base is not None
+
+
+def test_batches_held_by_another_thread_stay_whole(ring_dataset):
+    """The feeder's shape: one thread pulls batches, another holds each a
+    little longer than the next pull. Nothing held is written to, and the
+    ring still recycles."""
+    import queue
+    import sys
+    import threading
+    import time
+
+    handed: queue.Queue = queue.Queue(maxsize=3)
+    wrong: list = []
+
+    def hold_and_check():
+        while True:
+            item = handed.get()
+            if item is None:
+                return
+            batch, labels_then = item
+            time.sleep(0.0005)
+            if not (
+                np.array_equal(batch["label"], labels_then)
+                and np.array_equal(batch["image"][:, 0, 0, 0],
+                                   labels_then.astype(np.float32))
+            ):
+                wrong.append(labels_then[0])
+
+    before = buffer_counts()
+    checker = threading.Thread(target=hold_and_check, daemon=True)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        checker.start()
+        reader = ring_reader(
+            ring_dataset, batch_size=24, num_epochs=None,
+            reader_pool_type="thread", workers_count=4)
+        deadline = time.monotonic() + 20
+        for n, batch in enumerate(reader, 1):
+            handed.put((batch, batch["label"].copy()), timeout=10)
+            if n == 400 or time.monotonic() > deadline:
+                break
+        reader.stop()
+        handed.put(None, timeout=10)
+        checker.join(timeout=10)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not checker.is_alive() and n == 400
+    assert wrong == []
+    seen = counted(before)
+    # the queue, both threads' hands and the one being filled are about
+    # the bound: a checker that falls behind costs a fresh array, no more
+    assert seen["view"] == 0 and seen["recycled"] + seen["fresh"] == 400
+    assert seen["recycled"] > 200
