@@ -259,6 +259,26 @@ def decode_step_lm(mesh) -> ProgramSpec:
     )
 
 
+def _served_lm(mesh):
+    """The audit's LM at the dtype ``dsst serve-lm`` builds (bfloat16,
+    the class's own default) and the tree its ``TransformerDecoder``
+    holds: each leaf at the width the model multiplies it in, through
+    the same function the decoder calls — so the audited arguments are
+    the served ones."""
+    import jax
+    import jax.numpy as jnp
+
+    from ...models.transformer import serving_variables
+
+    model = _lm_task().model.clone(dtype=jnp.bfloat16)
+    variables = model.init(
+        jax.random.key(0), jnp.zeros((1, 8), jnp.int32)
+    )
+    return model, jax.device_put(
+        serving_variables(model, variables), _replicated(mesh)
+    )
+
+
 def slot_decode_lm(mesh) -> ProgramSpec:
     """The continuous-batching serving step: vmapped decode over the
     slot arena with a PER-SLOT position vector. The donation pin is the
@@ -270,14 +290,9 @@ def slot_decode_lm(mesh) -> ProgramSpec:
 
     from ...serving.lm import kvcache
 
-    task = _lm_task()
-    model = task.model
-    variables = model.init(
-        jax.random.key(0), jnp.zeros((1, 8), jnp.int32)
-    )
+    model, variables = _served_lm(mesh)
     replicated = _replicated(mesh)
     arena = jax.device_put(kvcache.make_arena(model, 4, 32), replicated)
-    variables = jax.device_put(variables, replicated)
     tokens = jax.device_put(jnp.zeros((4,), jnp.int32), replicated)
     pos = jax.device_put(jnp.zeros((4,), jnp.int32), replicated)
     return ProgramSpec(
@@ -305,14 +320,9 @@ def prefill_lm(mesh) -> ProgramSpec:
 
     from ...serving.lm import kvcache
 
-    task = _lm_task()
-    model = task.model
-    variables = model.init(
-        jax.random.key(0), jnp.zeros((1, 8), jnp.int32)
-    )
+    model, variables = _served_lm(mesh)
     replicated = _replicated(mesh)
     cache = jax.device_put(kvcache.make_arena(model, 1, 32), replicated)
-    variables = jax.device_put(variables, replicated)
     tokens = jax.device_put(jnp.zeros((1, 16), jnp.int32), replicated)
     return ProgramSpec(
         name="prefill.lm",

@@ -1946,12 +1946,15 @@ def _cmd_serve_lm(args: argparse.Namespace) -> int:
             num_layers=args.layers, max_seq=args.max_len,
             attention=args.attention,
         )
-        variables = model.init(
-            jax.random.key(args.seed),
-            jnp.zeros((1, config.prefill_buckets[0]), jnp.int32),
-        )
+        # The float32 tree gets no name here: the decoder keeps its own
+        # copy at the widths it multiplies in, and the wide one is freed.
         decoder = TransformerDecoder(
-            model, variables, slots=args.slots, max_len=args.max_len,
+            model,
+            model.init(
+                jax.random.key(args.seed),
+                jnp.zeros((1, config.prefill_buckets[0]), jnp.int32),
+            ),
+            slots=args.slots, max_len=args.max_len,
             buckets=config.prefill_buckets,
         )
         from ..runtime.compile_cache import enable_compile_cache

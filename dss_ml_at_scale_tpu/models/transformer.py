@@ -278,6 +278,42 @@ class TransformerLM(nn.Module):
         return logits
 
 
+# The leaves the model multiplies in float32 whatever its ``dtype``, by
+# the end of their path: every ``RMSNorm`` scale (``rms_norm`` takes
+# ``norm * scale`` in f32), the ``lm_head`` kernel (f32 logits) and the
+# MoE block's ``router`` kernel (f32 softmax over experts).
+_FLOAT32_LEAVES = (("scale",), ("lm_head", "kernel"), ("router", "kernel"))
+
+
+def serving_variables(model: TransformerLM, variables):
+    """``variables`` with each leaf at the width the model multiplies it in.
+
+    Every module of the model built with ``dtype=model.dtype`` converts
+    its float32 parameters on each call; a server that applies the same
+    tree for its whole life pays that convert (and reads the wide leaf)
+    in every program. This is the same rounding made once: every product
+    is the one it was. (On the CPU the logits are bitwise the given
+    tree's. On the TPU they are only under
+    ``--xla_allow_excess_precision=false``: by default XLA may skip a
+    rounding to bfloat16 inside a fusion, it fuses a program with narrow
+    arguments otherwise, and the logits differ by that rounding noise.)
+    Leaves consumed in float32 (``_FLOAT32_LEAVES``), and every leaf
+    already at its width (a float32 model's whole tree), come back as
+    the arrays they were; host (numpy) leaves are converted on the host
+    and placed narrow.
+    """
+    dtype = jnp.dtype(model.dtype)
+
+    def at_width(path, leaf):
+        names = tuple(str(getattr(k, "key", k)) for k in path)
+        wide = any(names[-len(end):] == end for end in _FLOAT32_LEAVES)
+        if wide or not jnp.issubdtype(leaf.dtype, jnp.floating):
+            return jnp.asarray(leaf)
+        return jnp.asarray(leaf, dtype)
+
+    return jax.tree_util.tree_map_with_path(at_width, variables)
+
+
 def init_kv_cache(model: TransformerLM, batch: int):
     """Zeroed per-layer K/V buffers sized [b, heads, max_seq, head_dim]."""
     head_dim = model.dim // model.num_heads

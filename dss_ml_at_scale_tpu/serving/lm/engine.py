@@ -40,6 +40,7 @@ import time
 import numpy as np
 
 from ... import telemetry
+from ...models.transformer import serving_variables
 from ..admission import AdmissionController, DeadlineExceeded, NotAccepting
 from . import kvcache
 
@@ -185,6 +186,11 @@ class TransformerDecoder:
     ``prefill_bucket`` executable per configured bucket length, and a
     donated ``write_slot`` scatter per admission. ``warmup()`` compiles
     all of them before the server reports ready.
+
+    The decoder holds its own tree, each leaf at the width the model
+    multiplies it in (``serving_variables``: cast once here, not inside
+    every program), and no reference to the wider originals: a caller
+    that drops its ``variables`` frees them.
     """
 
     def __init__(self, model, variables, *, slots, max_len, buckets):
@@ -193,7 +199,18 @@ class TransformerDecoder:
 
         self._jax, self._jnp = jax, jnp
         self.model = model
-        self.variables = variables
+        self.variables = serving_variables(model, variables)
+        held: dict[str, int] = {}
+        for leaf in jax.tree_util.tree_leaves(self.variables):
+            name = str(leaf.dtype)
+            held[name] = held.get(name, 0) + leaf.nbytes
+        weights_bytes = telemetry.gauge(
+            "lm_weights_bytes",
+            "bytes of the decoder's own weights, by the dtype held",
+            labels=("dtype",),
+        )
+        for name, nbytes in held.items():
+            weights_bytes.labels(dtype=name).set(nbytes)
         self.slots = int(slots)
         self.max_len = int(max_len)
         self.buckets = tuple(buckets)

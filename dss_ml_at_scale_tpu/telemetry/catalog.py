@@ -99,6 +99,7 @@ KNOWN_METRICS: dict[str, str] = {
     "lm_slots_active": "gauge",
     "lm_tokens_total": "counter",
     "lm_ttft_window_seconds": "window",
+    "lm_weights_bytes": "gauge",
     # -- serving -----------------------------------------------------------
     "predict_batch_seconds": "histogram",
     "predict_errors_total": "counter",
