@@ -84,7 +84,8 @@ class TrainSizes:
 
 @dataclasses.dataclass(frozen=True)
 class KernelSizes:
-    # flash_attention at bench.py's LM shape.
+    # flash_attention at a 2048-token LM shape (the head size the
+    # serving cell runs).
     flash: tuple = (8, 8, 2048, 128)      # batch, heads, seq, head_dim
     # bn_relu_matmul at the four ResNet-50 stage widths, batch 212.
     bn_batch: int = 212
@@ -708,6 +709,17 @@ def phase_forecast(workdir: Path, expect_platform: str = "tpu",
 # Phase: dp4 (--chips 4) — the data-parallel step and its one-device twin
 # ---------------------------------------------------------------------------
 
+def synthetic_image_batch(batch: int, image: int, num_classes: int,
+                          seed: int = 0) -> dict:
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    return {
+        "image": rng.normal(size=(batch, image, image, 3)).astype(np.float32),
+        "label": rng.integers(0, num_classes, batch).astype(np.int32),
+    }
+
+
 def place_batch(batch: dict, mesh):
     """The trainer's own batch placement (``runtime.shard_batch_to_mesh``,
     what the feeder calls); a seam so the test can show the "4 distinct
@@ -729,7 +741,6 @@ def phase_dp4(workdir: Path, expect_platform: str = "tpu",
     from dss_ml_at_scale_tpu.parallel import ClassifierTask
     from dss_ml_at_scale_tpu.parallel.trainer import make_train_step
     from dss_ml_at_scale_tpu.runtime import make_mesh
-    from dss_ml_at_scale_tpu.utils.benchlib import synthetic_image_batch
 
     n = sizes.devices
     check(len(jax.devices()) >= n,

@@ -26,8 +26,8 @@ the fact. This package closes the loop TSan-style, in process:
 
 Disarmed, nothing is patched: the declaring classes get plain
 ``threading`` objects and guarded attributes stay ordinary slots/dict
-entries — zero overhead on the hot path. Armed overhead is measured in
-``bench.py``.
+entries — zero overhead on the hot path. Armed overhead is what the
+``sanitizer_overhead`` scenario of ``dsst bench`` gates.
 
 Findings render through the same text/JSON + mandatory-reason
 suppression + content-addressed baseline idioms as ``dsst lint``

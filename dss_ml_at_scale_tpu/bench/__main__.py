@@ -1,9 +1,8 @@
 """Isolated scenario child: ``python -m dss_ml_at_scale_tpu.bench``.
 
 One scenario per process — a hung backend, an OOM, or a watchdog kill
-takes down this child, never the harness. Protocol (the bench.py child
-discipline, now framework-owned): exactly one JSON line on stdout
-(``{"scenario", "samples", "extra", "completed"}`` on success,
+takes down this child, never the harness. Protocol: exactly one JSON
+line on stdout (``{"scenario", "samples", "extra", "completed"}`` on success,
 ``{"scenario", "failed": true, "error"}`` on failure), per-repetition
 durable partials at ``--partial`` for parent-side salvage, exit 0
 either way — the parent judges the JSON, not the return code.

@@ -2,10 +2,9 @@
 
 The repo guards correctness three ways (``dsst lint`` / ``dsst audit`` /
 ``dsst sanitize``: committed content-addressed baselines, expire
-semantics, exit 0/1/2) but performance — the paper's actual thesis —
-had no gate: measurement lived in one monolithic ``bench.py`` with no
-committed numbers and no regression verdict. This module is the fourth
-tier, built on the same idioms:
+semantics, exit 0/1/2). This module is the fourth tier, a regression
+gate on CPU runs at toy sizes (speed on the chip is measured by
+``perfbench/``), built on the same idioms:
 
 - **Scenario registry** (:class:`Scenario`, mirroring the audit
   entrypoint registry): each scenario declares its measure function, a
@@ -26,9 +25,7 @@ tier, built on the same idioms:
 - **Child isolation + durable salvage**: each scenario runs in its own
   subprocess (a hung backend or an OOM kills one scenario, not the
   harness) and checkpoints per-repetition partials through
-  :func:`~dss_ml_at_scale_tpu.resilience.durability.durable_write_json`
-  — the framework owns what ``bench.py`` hand-rolled as
-  ``_save_partial``.
+  :func:`~dss_ml_at_scale_tpu.resilience.durability.durable_write_json`.
 """
 
 from __future__ import annotations
@@ -363,8 +360,7 @@ def measure_scenario(sc: Scenario, *, repetitions: int | None = None,
     Runs ``setup``, ``warmup + repetitions`` calls of ``measure``,
     discards the warmup, and — after every kept repetition — durably
     checkpoints the partial record so a watchdog kill salvages every
-    completed repetition (the bench.py lesson, now behind the
-    framework). Returns ``{"scenario", "env", "samples", "extra",
+    completed repetition. Returns ``{"scenario", "env", "samples", "extra",
     "completed"}``.
     """
     from ..resilience.durability import durable_write_json

@@ -1,7 +1,6 @@
 """Closed-loop load generator for the serving scheduler (library form).
 
-Ported from ``scripts/serve_loadgen.py`` (which remains as a thin CLI
-shim) so the bench harness can register serving load as a *scenario*:
+``scripts/serve_loadgen.py`` is a thin CLI shim over this module:
 ``threads`` clients each run a closed loop (send one single-image
 POST /predict, wait, repeat) for ``duration`` seconds — offered load
 scales with measured latency, so numbers compare run to run. Reports
@@ -11,12 +10,11 @@ delta (a shared server doesn't pollute the numbers).
 
 Two targets: any running ``dsst serve`` (``--url`` + ``--image``), or
 ``--selftest`` — a stub-scorer server in a SUBPROCESS loaded over real
-sockets. The stub path measures the SCHEDULER (admission, decode pool,
-cross-request batching, HTTP keep-alive), which is exactly what CI can
-pin; the subprocess split matters because an in-process server would
-share the client threads' GIL and inflate tail latency with scheduling
-artifacts. ``BENCH_serving.json`` is produced through the bench
-harness's ``serving`` scenario on top of this module.
+sockets. The stub path drives the SCHEDULER (admission, decode pool,
+cross-request batching, HTTP keep-alive) and fleets of such servers
+(``tests/test_federation.py``, ``scripts/check_fleet_smoke.py``); the
+subprocess split matters because an in-process server would share the
+client threads' GIL and inflate tail latency with scheduling artifacts.
 """
 
 from __future__ import annotations

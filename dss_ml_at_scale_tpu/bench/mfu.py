@@ -26,7 +26,7 @@ from pathlib import Path
 
 # Public per-chip peaks (Google Cloud documentation, "TPU v5e": 197
 # TFLOP/s bf16, 819 GB/s HBM), keyed by the exact ``device_kind`` string
-# JAX reports on that chip. The one table: bench.py's sweep reads it too.
+# JAX reports on that chip.
 PEAK_BF16_FLOPS = {"TPU v5 lite": 197e12}
 PEAK_HBM_BYTES = {"TPU v5 lite": 819e9}
 

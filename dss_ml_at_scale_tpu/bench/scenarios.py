@@ -1,12 +1,12 @@
-"""The registered scenarios: bench.py's stages, decomposed and gated.
+"""The registered scenarios of ``dsst bench``, a CPU regression gate at
+toy sizes.
 
-Each scenario isolates one seam of the system the ROADMAP's scale items
-need proven numbers for — decode, reader, device feeding, the compiled
-step, the observability layers' own overhead, and serving load. Where a
-scenario executes a compiled program it builds it through the **audit
-entrypoint registry** (the same builders ``dsst audit`` certifies), so
-the measured program and the pinned cost budget describe identical XLA
-— that is what makes the achieved-FLOPs/s gauges honest.
+Each scenario isolates one seam of the system — decode, reader, device
+feeding, the compiled step, the group fit, the observability layers'
+own overhead. Where a scenario executes a compiled program it builds it
+through the **audit entrypoint registry** (the same builders ``dsst
+audit`` certifies), so the measured program and the pinned cost budget
+describe identical XLA.
 
 Declarations here are reconciled against
 ``telemetry.catalog.KNOWN_BENCH_METRICS`` in both directions by the
@@ -40,7 +40,7 @@ _BATCH = 16
 
 def _tiny_jpegs(n: int, size: int, seed: int = 0) -> list[bytes]:
     """Blocky low-frequency JPEGs: realistic decode entropy (pure noise
-    inflates decode cost; flat color deflates it) — bench.py's recipe."""
+    inflates decode cost; flat color deflates it)."""
     import io
 
     import numpy as np
@@ -377,8 +377,8 @@ register_scenario(Scenario(
 
 
 def _group_panel(n_sku: int, weeks: int, seed: int = 0):
-    """Synthetic demand panel at bench.py's group-child recipe
-    (level + damped random walk + noise, weekly dates), built
+    """Synthetic demand panel (level + damped random walk + noise,
+    weekly dates), built
     vectorized so 10k-SKU setup is numpy-bound, not loop-bound."""
     import numpy as np
     import pandas as pd
@@ -783,190 +783,4 @@ register_scenario(Scenario(
     measure=_slo_overhead_measure,
     repetitions=5,
     timeout_s=120.0,
-))
-
-
-# -- serving loadgen ----------------------------------------------------------
-
-
-def _scrape_slo(port: int) -> dict:
-    """The stub server's /slo document (schema v1)."""
-    import http.client
-    import json
-
-    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=10)
-    try:
-        conn.request("GET", "/slo")
-        resp = conn.getresponse()
-        return json.loads(resp.read())
-    finally:
-        conn.close()
-
-
-def _serving_setup():
-    from . import loadgen
-
-    proc, port = loadgen.spawn_stub_server(
-        micro_batch=8, score_ms=5.0, batch_window_ms=5.0, queue_depth=64,
-    )
-    return {"proc": proc, "port": port}
-
-
-def _serving_teardown(ctx) -> None:
-    ctx["proc"].terminate()
-    ctx["proc"].wait(15)
-
-
-def _serving_measure(ctx) -> dict:
-    from . import loadgen
-
-    report = loadgen.run_load(
-        "127.0.0.1", ctx["port"], b"0", threads=8, duration_s=1.2,
-    )
-    fill = report["server"]["batch_fill"]["mean"]
-    # The live-vs-offline agreement check: the server's windowed p99
-    # (the SLO plane's serving_latency_p99 value, fed by the same
-    # requests the loadgen just timed) must agree with the loadgen's
-    # offline p99 — both route through telemetry.windows.quantile, so
-    # the only legitimate gaps are the sketch's bounded bucket error
-    # and the client's socket overhead. A wild disagreement means the
-    # live plane is measuring something other than what clients see.
-    status = _scrape_slo(ctx["port"])
-    lat = next(
-        (o for o in status.get("objectives", [])
-         if o["name"] == "serving_latency_p99"), {},
-    )
-    live_p99 = lat.get("value")
-    offline_p99 = report["latency_s"]["p99"]
-    if (
-        live_p99 and offline_p99
-        and report["requests"] >= 100
-        and not (0.2 <= live_p99 / offline_p99 <= 5.0)
-    ):
-        raise RuntimeError(
-            f"live windowed p99 {live_p99 * 1e3:.1f}ms disagrees with "
-            f"the loadgen's offline p99 {offline_p99 * 1e3:.1f}ms far "
-            "beyond sketch error + client overhead — the live SLO "
-            "plane is not measuring what clients experience"
-        )
-    return {
-        "serving_throughput_rps": report["throughput_rps"],
-        "serving_p50_ms": (report["latency_s"]["p50"] or 0.0) * 1e3,
-        "serving_p99_ms": (offline_p99 or 0.0) * 1e3,
-        "serving_batch_fill_mean": fill if fill is not None else 0.0,
-        "serving_live_p99_ms": (live_p99 or 0.0) * 1e3,
-        # The /slo snapshot rides the artifact so CI can gate on it
-        # after the bench: `dsst slo check --report <bench json>`.
-        "_extra": {"loadgen": report, "slo": status},
-    }
-
-
-register_scenario(Scenario(
-    name="serving",
-    description="closed-loop loadgen vs the stub-scorer scheduler "
-    "subprocess over real sockets (admission, decode pool, "
-    "cross-request batching) — the BENCH_serving.json producer",
-    tier="tier1",
-    metrics=(
-        Metric("serving_throughput_rps", "req/sec", "higher",
-               floor=0.6),
-        Metric("serving_p50_ms", "ms", "lower", floor=0.6),
-        Metric("serving_p99_ms", "ms", "lower", gate=False),
-        Metric("serving_batch_fill_mean", "images", "higher", gate=False),
-        Metric("serving_live_p99_ms", "ms", "lower", gate=False),
-    ),
-    setup=_serving_setup,
-    teardown=_serving_teardown,
-    measure=_serving_measure,
-    repetitions=3,
-    timeout_s=240.0,
-))
-
-
-# -- LM token serving ---------------------------------------------------------
-
-
-def _lm_serving_setup():
-    from . import loadgen
-
-    # Deadline + inter-token budget armed: the /slo snapshot riding the
-    # artifact must show ZERO firing objectives under this load (the
-    # acceptance gate `dsst slo check --strict --url` judges).
-    proc, port = loadgen.spawn_stub_lm_server(
-        slots=8, max_len=96, prefill_buckets="8,16", step_ms=3.0,
-        queue_depth=32, deadline_ms=2000.0, inter_token_budget_ms=250.0,
-    )
-    return {"proc": proc, "port": port}
-
-
-def _lm_serving_teardown(ctx) -> None:
-    ctx["proc"].terminate()
-    ctx["proc"].wait(15)
-
-
-def _lm_serving_measure(ctx) -> dict:
-    from . import loadgen
-
-    prompt = [1, 2, 3, 4]
-    # 8 concurrent streams vs ONE stream against the same engine: the
-    # stub decoder's per-STEP cost is independent of active slots, so
-    # the ratio isolates what continuous batching buys — the ISSUE's
-    # acceptance bar is >= 2x at 8 streams.
-    multi = loadgen.run_lm_load(
-        "127.0.0.1", ctx["port"], prompt=prompt, max_new_tokens=16,
-        streams=8, duration_s=1.2,
-    )
-    solo = loadgen.run_lm_load(
-        "127.0.0.1", ctx["port"], prompt=prompt, max_new_tokens=16,
-        streams=1, duration_s=0.8,
-    )
-    if multi["requests"] == 0 or solo["requests"] == 0:
-        raise RuntimeError(
-            f"lm loadgen starved: {multi['requests']} multi-stream / "
-            f"{solo['requests']} solo requests completed"
-        )
-    if multi["trace_propagated"] != multi["requests"]:
-        raise RuntimeError(
-            "trace propagation broken on /generate: "
-            f"{multi['trace_propagated']}/{multi['requests']} streams "
-            "echoed the injected trace id"
-        )
-    speedup = (
-        multi["tokens_per_sec"] / solo["tokens_per_sec"]
-        if solo["tokens_per_sec"] else 0.0
-    )
-    status = _scrape_slo(ctx["port"])
-    return {
-        "lm_tokens_per_sec": multi["tokens_per_sec"],
-        "lm_solo_tokens_per_sec": solo["tokens_per_sec"],
-        "lm_batching_speedup": round(speedup, 3),
-        "lm_ttft_p99_ms": (multi["ttft_s"]["p99"] or 0.0) * 1e3,
-        "lm_inter_token_p99_ms": (
-            multi["inter_token_s"]["p99"] or 0.0
-        ) * 1e3,
-        "_extra": {"loadgen": multi, "solo": solo, "slo": status},
-    }
-
-
-register_scenario(Scenario(
-    name="lm_serving",
-    description="closed-loop streamed-generation loadgen vs the "
-    "stub-decoder continuous-batching engine subprocess (slot "
-    "admission, bucketed prefill, chunked token streaming) — the "
-    "BENCH_lm_serving.json producer; gates tokens/sec and the "
-    ">=2x batching speedup at 8 streams",
-    tier="tier1",
-    metrics=(
-        Metric("lm_tokens_per_sec", "tokens/sec", "higher", floor=0.6),
-        Metric("lm_solo_tokens_per_sec", "tokens/sec", "higher",
-               gate=False),
-        Metric("lm_batching_speedup", "x", "higher", floor=0.6),
-        Metric("lm_ttft_p99_ms", "ms", "lower", gate=False),
-        Metric("lm_inter_token_p99_ms", "ms", "lower", gate=False),
-    ),
-    setup=_lm_serving_setup,
-    teardown=_lm_serving_teardown,
-    measure=_lm_serving_measure,
-    repetitions=3,
-    timeout_s=240.0,
 ))

@@ -3391,10 +3391,7 @@ def _add_slo_source_args(p: argparse.ArgumentParser) -> None:
     )
     p.add_argument(
         "--report", default=None, metavar="JSON",
-        help="judge a saved document instead of a live process: either "
-        "a raw /slo status JSON or a `dsst bench --json` artifact "
-        "whose serving scenario embedded one (results.serving.extra."
-        "slo) — what CI runs after the serving bench",
+        help="judge a saved /slo status JSON instead of a live process",
     )
 
 
@@ -3417,8 +3414,7 @@ def register_slo(sub: argparse._SubParsersAction) -> None:
     st.set_defaults(fn=_cmd_slo_status)
     ck = ssub.add_parser(
         "check", help="gate on the SLO plane: exit 1 if any objective "
-        "is firing (CI runs this after the serving bench so a TPU "
-        "claim can't ship while an SLO burns)",
+        "is firing",
     )
     _add_slo_source_args(ck)
     _add_fleet_args(ck)
@@ -3480,18 +3476,10 @@ def _slo_fetch_status(args: argparse.Namespace) -> dict | None:
             print(f"dsst slo: cannot read --report {args.report}: {e}",
                   file=sys.stderr)
             return None
-        if "objectives" not in doc:
-            # A dsst bench --json artifact: the serving scenario embeds
-            # the stub server's /slo snapshot in its extra block.
-            doc = (
-                doc.get("results", {}).get("serving", {})
-                .get("extra", {}).get("slo")
-            )
         if not isinstance(doc, dict) or "objectives" not in doc:
             print(
                 f"dsst slo: {args.report} carries no SLO status "
-                "document (expected a /slo JSON or a bench artifact "
-                "with results.serving.extra.slo)",
+                "document (expected a /slo JSON)",
                 file=sys.stderr,
             )
             return None

@@ -4,9 +4,9 @@ The image-track fixture generator (the counterpart of the demand panel,
 SURVEY.md §4.4 — the reference tests by generating its data in-cluster):
 each class is a distinct spatial-frequency/orientation grating whose
 phase, contrast, and noise vary per image, so a classifier must learn
-structure — a linear probe on mean color sits at chance. Used by the
-accuracy-proof harness (``bench_accuracy.py``) and ``dsst datagen
-images`` for quick-start training without an external dataset.
+structure — a linear probe on mean color sits at chance. Used by
+``dsst datagen images`` for quick-start training without an external
+dataset.
 """
 
 from __future__ import annotations

@@ -192,8 +192,8 @@ class ResNet(nn.Module):
     # torchvision-layout pretrained weights (models/pretrained.py).
     torch_padding: bool = False
     # Fused BN+relu(+residual) with a minimal-residual custom VJP
-    # (ops/fused_norm.py) — cuts the HBM bytes that cap v5e throughput
-    # (BASELINE.md). Parameter paths are IDENTICAL to the unfused model,
+    # (ops/fused_norm.py) — saves fewer activation-sized residuals for
+    # the backward pass. Parameter paths are IDENTICAL to the unfused model,
     # so checkpoints and pretrained weights port both ways.
     # "pallas" (bottleneck blocks only) additionally fuses the middle
     # BN's apply into the third 1x1 conv as a Pallas matmul prologue

@@ -1,10 +1,9 @@
 """Fused train-mode BatchNorm + activation (+ residual add) with a
 hand-written minimal-residual VJP.
 
-Why this exists (the round-3 measurement): ResNet-50 training on v5e is
-HBM-bound — XLA cost analysis counts ~327 MB of HBM traffic per image at
-batch 212 while the MXU idles at ~29% of bf16 peak (BASELINE.md). The
-FLOPs cannot be cut; the bytes can. The biggest avoidable byte source is
+Why this exists: the ResNet-50 train step's FLOPs cannot be cut; its
+HBM bytes can (what that buys in step time has not been measured on the
+chip: ROADMAP A4). The biggest avoidable byte source is
 autodiff's residual bloat around BatchNorm: reverse-mode AD of the
 ``normalize → scale/shift → (add) → relu`` chain saves intermediate
 activation-sized tensors (x̂, the pre-activation, relu masks) from the

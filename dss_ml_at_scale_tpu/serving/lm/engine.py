@@ -24,9 +24,9 @@ admitting, finish every in-flight slot.
 Two decoder backends satisfy the same five-method protocol
 (``prefill``/``step``/``warmup`` + ``slots``/``vocab_size``):
 :class:`TransformerDecoder` runs the real audited programs;
-:class:`StubLMDecoder` is the bench/CI stand-in whose per-STEP cost is
-independent of how many slots are active — exactly the property that
-makes continuous batching win, minus the model weights.
+:class:`StubLMDecoder` is the test double: no model, deterministic
+streams, and a per-STEP cost that does not depend on how many slots are
+active.
 """
 
 from __future__ import annotations
@@ -276,12 +276,12 @@ class TransformerDecoder:
 
 
 class StubLMDecoder:
-    """Model-free backend for bench/CI: fixed per-STEP cost.
+    """Model-free test double: fixed per-STEP cost.
 
     The next token is a pure function of (last token, position), so
     streams are deterministic; ``step()`` sleeps ``step_ms`` ONCE no
-    matter how many slots are active — the continuous-batching speedup
-    the ``lm_serving`` bench gates is therefore structural, not noise.
+    matter how many slots are active, so what a test reads off it is
+    structure (which requests shared a step), never a speed.
     Logits are one-hot so greedy sampling recovers the function exactly.
     """
 

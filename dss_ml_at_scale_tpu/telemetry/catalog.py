@@ -270,20 +270,6 @@ KNOWN_BENCH_METRICS: dict[str, tuple[str, ...]] = {
         "sanitizer_armed_acquire_us",
         "sanitizer_overhead_ratio",
     ),
-    "serving": (
-        "serving_throughput_rps",
-        "serving_p50_ms",
-        "serving_p99_ms",
-        "serving_batch_fill_mean",
-        "serving_live_p99_ms",
-    ),
-    "lm_serving": (
-        "lm_tokens_per_sec",
-        "lm_solo_tokens_per_sec",
-        "lm_batching_speedup",
-        "lm_ttft_p99_ms",
-        "lm_inter_token_p99_ms",
-    ),
     "slo_overhead": (
         "slo_sketch_observe_us",
         "slo_hist_observe_us",

@@ -52,10 +52,10 @@ SEARCH_SPACE = {
 }
 
 # The benchmark/audit geometry of the grid-fused group-fit chunk: the
-# `dsst bench` `group_fit` tier-1 gate, the audited
-# `sarimax.batched_fit` entrypoint, and bench.py's group-child liveness
-# config (32 groups x 40 weeks, reduced order bounds) all describe THIS
-# program, so the pinned FLOPs budget prices the measured launches.
+# `dsst bench` `group_fit` tier-1 gate and the audited
+# `sarimax.batched_fit` entrypoint (32 groups x 40 weeks, reduced order
+# bounds) both describe THIS program, so the pinned FLOPs budget prices
+# the measured launches.
 # bfgs_iter=0: the vmapped BFGS line search serializes the fit plane on
 # CPU hosts and the f64 polish is a host-side step (ops/polish.py), not
 # part of the batched launch.

@@ -1,10 +1,10 @@
 #!/usr/bin/env python
 """Two-replica fleet observability smoke (CI preflight).
 
-Spawns TWO stub-scorer serving subprocesses (the same
-``bench.loadgen.spawn_stub_server`` path the serving bench uses),
-drives a little real traffic with propagated trace headers at each,
-then judges the FLEET through the real CLI:
+Spawns TWO stub-scorer serving subprocesses
+(``bench.loadgen.spawn_stub_server``), drives a little real traffic
+with propagated trace headers at each, then judges the FLEET through
+the real CLI:
 
     dsst slo check --fleet 127.0.0.1:P1 127.0.0.1:P2
 

@@ -1,13 +1,12 @@
 #!/usr/bin/env python
-"""Closed-loop load generator for the serving scheduler (CLI shim).
+"""Closed-loop load generator for the serving scheduler (CLI shim over
+``dss_ml_at_scale_tpu.bench.loadgen``):
 
-The implementation moved to ``dss_ml_at_scale_tpu.bench.loadgen`` so
-the bench harness can register serving load as a scenario (``dsst
-bench --scenarios serving`` — the ``BENCH_serving.json`` producer);
-this shim keeps the historical entry point and flags:
+    python scripts/serve_loadgen.py --selftest --threads 16 --duration 3
+    python scripts/serve_loadgen.py --url http://127.0.0.1:8008 --image cat.jpg
 
-    python scripts/serve_loadgen.py --selftest --threads 16 \
-        --duration 3 --out BENCH_serving.json
+Against ``--selftest`` it loads a stub scorer whose step is a sleep: a
+check of the scheduler's mechanics, not a speed.
 """
 
 from __future__ import annotations

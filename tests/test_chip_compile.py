@@ -17,7 +17,7 @@ import os
 
 import pytest
 
-FLASH_SHAPE = (8, 8, 2048, 128)  # batch, heads, seq, head_dim (bench.py's LM)
+FLASH_SHAPE = (8, 8, 2048, 128)  # batch, heads, seq, head_dim (chip_smoke's)
 BN_BATCH = 212
 BN_STAGES = [(56, 64, 256), (28, 128, 512), (14, 256, 1024), (7, 512, 2048)]
 

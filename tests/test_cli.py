@@ -537,8 +537,8 @@ def test_datagen_images(tmp_path, capsys):
 
 def test_datagen_images_label_noise(tmp_path):
     # Same seed, with and without noise: images identical, a fraction of
-    # stored labels flipped — the pinned-accuracy-ceiling regime of
-    # bench_accuracy.py (ceiling = (1-p) + p/classes).
+    # stored labels flipped — a pinned accuracy ceiling of
+    # (1-p) + p/classes.
     from dss_ml_at_scale_tpu.config.commands import _read_delta_pandas
 
     clean, noisy = tmp_path / "clean", tmp_path / "noisy"

@@ -3,11 +3,10 @@ registry / SLO-source federation, the aggregator's straggler
 resilience, and the fleet CLI over real replica processes.
 
 The live tests spawn REAL stub-scorer serving subprocesses
-(``bench.loadgen.spawn_stub_server`` — the same path the serving bench
-uses), so the cross-process claims (one trace id across client →
-server → response header; fleet-merged p99 vs pooled offline quantile)
-are exercised over actual sockets and actual process boundaries, not
-in-process simulations.
+(``bench.loadgen.spawn_stub_server``), so the cross-process claims
+(one trace id across client → server → response header; fleet-merged
+p99 vs pooled offline quantile) are exercised over actual sockets and
+actual process boundaries, not in-process simulations.
 """
 
 import json

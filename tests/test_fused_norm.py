@@ -4,8 +4,8 @@ The fused path (ops/fused_norm.py) must be a drop-in: identical
 parameter trees (checkpoint/pretrained-converter compatibility),
 identical forward values, identical gradients, identical running-stat
 updates — in both train and eval mode. Gradient checks run in float32 so
-tolerances are tight; the byte-reduction claim itself is measured by
-bench.py on hardware, not here.
+tolerances are tight; what the fused path buys in time has not been
+measured on the chip (ROADMAP A4) and is not tested here.
 """
 
 import jax

@@ -243,6 +243,53 @@ def test_decoder_with_more_slots_than_config():
         engine.drain(5.0)
 
 
+class _CountingStub(StubLMDecoder):
+    def __init__(self, **kw):
+        super().__init__(**kw)
+        self.steps = 0
+
+    def step(self, tokens, pos):
+        self.steps += 1
+        return super().step(tokens, pos)
+
+
+def test_requests_submitted_together_share_decode_steps():
+    """8 requests of 16 tokens waiting when the loop starts ride the
+    same decode steps; one after another each pays its own. Counted
+    calls of ``step``, no clock."""
+    def engine():
+        return LMEngine(
+            _CountingStub(vocab_size=97, step_ms=0, slots=8, max_len=48,
+                          buckets=(8,)),
+            LMConfig(slots=8, max_len=48, prefill_buckets=(8,)),
+        )
+
+    prompts = [[i + 1, i + 2, i + 3] for i in range(8)]
+
+    together = engine()
+    gens = [together.submit(p, 16, seed=i) for i, p in enumerate(prompts)]
+    together.start()
+    try:
+        for prompt, gen in zip(prompts, gens):
+            tokens, terminal = _collect(gen)
+            assert terminal == ("done", "max_tokens")
+            assert tokens == _stub_expected(together.decoder, prompt, 16)
+    finally:
+        together.drain(5.0)
+
+    serial = engine().start()
+    try:
+        for i, prompt in enumerate(prompts):
+            tokens, _ = _collect(serial.submit(prompt, 16, seed=i))
+            assert len(tokens) == 16
+    finally:
+        serial.drain(5.0)
+
+    # The prefill yields each request's first token, 15 steps the rest.
+    assert 15 <= together.decoder.steps <= 16
+    assert serial.decoder.steps >= 8 * 15
+
+
 def test_deadline_retires_slot_and_frees_it():
     cfg = LMConfig(slots=1, max_len=64, prefill_buckets=(8,),
                    deadline_ms=150.0)

@@ -529,14 +529,6 @@ def test_cli_slo_check_report_modes(tmp_path, capsys):
     assert main(["slo", "check", "--report", str(raw)]) == 1
     assert "FAILING serving_error_rate" in capsys.readouterr().out
 
-    # The dsst bench artifact shape: results.serving.extra.slo.
-    bench = tmp_path / "bench.json"
-    bench.write_text(json.dumps({
-        "results": {"serving": {"extra": {"slo": firing_doc}}},
-    }))
-    assert main(["slo", "check", "--report", str(bench)]) == 1
-    capsys.readouterr()
-
     ok_doc = dict(firing_doc, firing=[], ok=True)
     ok_doc["objectives"] = [
         dict(firing_doc["objectives"][0], state="ok"),

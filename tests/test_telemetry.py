@@ -504,7 +504,8 @@ def test_per_step_instrumentation_under_50us():
     if is_armed():
         # A DSST_SANITIZE=1 session wraps every lock acquire with
         # bookkeeping — the budget below is the PRODUCTION (disarmed)
-        # contract, and bench.py measures the armed overhead instead.
+        # contract; the armed overhead is `dsst bench`'s
+        # sanitizer_overhead scenario.
         pytest.skip("sanitizer armed: per-op budget is a disarmed contract")
     r = MetricsRegistry()
     step_hist = r.histogram("step_s")
