@@ -281,10 +281,12 @@ def _served_lm(mesh):
 
 def slot_decode_lm(mesh) -> ProgramSpec:
     """The continuous-batching serving step: vmapped decode over the
-    slot arena with a PER-SLOT position vector. The donation pin is the
-    whole point — the engine holds ONE live arena for the life of the
-    server, and this rule certifies every step aliases it in-place
-    (zero per-token cache copies)."""
+    slot arena with a PER-SLOT position vector, as the engine calls it:
+    the tokens are the previous step's ids merged on the device with
+    the host's overrides, and the greedy ids come back beside the
+    logits. The donation pin is the whole point — the engine holds ONE
+    live arena for the life of the server, and this rule certifies
+    every step aliases it in-place (zero per-token cache copies)."""
     import jax
     import jax.numpy as jnp
 
@@ -295,10 +297,11 @@ def slot_decode_lm(mesh) -> ProgramSpec:
     arena = jax.device_put(kvcache.make_arena(model, 4, 32), replicated)
     tokens = jax.device_put(jnp.zeros((4,), jnp.int32), replicated)
     pos = jax.device_put(jnp.zeros((4,), jnp.int32), replicated)
+    override = jax.device_put(jnp.zeros((4,), jnp.int32), replicated)
     return ProgramSpec(
         name="slot_decode.lm",
         fn=kvcache.slot_decode,
-        args=(model, variables, tokens, arena, pos),
+        args=(model, variables, tokens, arena, pos, override),
         # out_shardings pinned for the same reason as decode_step.lm:
         # committed inputs + UNSPECIFIED outputs silently drop the
         # arena aliasing.

@@ -7,11 +7,22 @@ admitted INTO an in-flight batch. One engine thread alternates
     admit waiting requests into free slots
         (bucket-padded prefill, compiled once per bucket;
          aliased scatter into the slot arena; first token = TTFT)
-    one ``slot_decode`` step over ALL slots
-        (every active request advances one token per step)
+    dispatch one ``slot_decode`` step over ALL slots
+        (every active request advances one token per step; the
+         program picks each slot's greedy token on the device)
+    collect the step dispatched one turn earlier
+        (its ``[slots]`` ids; stream, note, retire while the device
+         runs the step just dispatched)
     per-slot retirement
         (EOS / max-token / deadline / cancel — the slot frees and the
          batch keeps running; nothing stops, nothing recompiles)
+
+One decode step is kept in flight: step n+1 takes its tokens from step
+n's ids, which never visit the host, so it is dispatched before step n
+is collected and the host's turn runs under the device's. A request
+that samples on the host (``temperature > 0``) needs its logits row
+before its next token is known; while one is active the same loop
+collects each step in the turn that dispatched it (depth 0).
 
 The HTTP layer talks to the engine through :meth:`LMEngine.submit`,
 which returns a :class:`Generation` whose event queue streams tokens
@@ -21,12 +32,12 @@ the image tier's, reused verbatim: a full queue raises
 raises :class:`~..admission.NotAccepting` (503), and drain = stop
 admitting, finish every in-flight slot.
 
-Two decoder backends satisfy the same five-method protocol
-(``prefill``/``step``/``warmup`` + ``slots``/``vocab_size``):
-:class:`TransformerDecoder` runs the real audited programs;
-:class:`StubLMDecoder` is the test double: no model, deterministic
-streams, and a per-STEP cost that does not depend on how many slots are
-active.
+Two decoder backends satisfy the same protocol
+(``prefill``/``dispatch``/``fetch``/``warmup`` + ``slots``/
+``vocab_size``): :class:`TransformerDecoder` runs the real audited
+programs; :class:`StubLMDecoder` is the test double: no model,
+deterministic streams, and a per-STEP cost that does not depend on how
+many slots are active.
 """
 
 from __future__ import annotations
@@ -84,8 +95,10 @@ class LMConfig:
 class Generation:
     """One streamed request: engine-side state + client-side queue.
 
-    The engine thread owns the decode state (``n_past``, ``last_token``,
-    ``emitted``); the HTTP thread only reads the event queue and may set
+    The engine thread owns the decode state (``n_past``: the cache
+    position its next dispatched step writes; ``last_token`` and
+    ``emitted``: what has been streamed); the HTTP thread only reads the
+    event queue and may set
     ``cancelled`` (a latch, safe without the engine lock). Events are
     ``("token", token, index)`` then exactly one terminal
     ``("done", reason)`` or ``("error", exc)`` — :meth:`settle_once` is
@@ -121,8 +134,15 @@ class Generation:
         self.emitted = 0
         self._rng = np.random.default_rng(seed)
 
+    @property
+    def greedy(self) -> bool:
+        """The next token is the argmax of the logits, which a decode
+        step picks on the device; any other request samples on the host
+        from its logits row with its own generator."""
+        return self.temperature <= 0.0
+
     def sample(self, logits_row: np.ndarray) -> int:
-        if self.temperature <= 0.0:
+        if self.greedy:
             return int(np.argmax(logits_row))
         scaled = logits_row.astype(np.float64) / self.temperature
         if self.top_k is not None:
@@ -163,19 +183,25 @@ def _epoch_offset() -> float:
     return time.time() - time.perf_counter()
 
 
-def _record_step_parts(t0: float, t1: float, t2: float, t3: float) -> None:
-    """The three parts of one decoder step as complete records: call to
-    dispatched, to the logits ready on the device, to the logits on the
-    host. A with-span each would cost a begin event and a
-    ``TraceAnnotation`` for intervals of microseconds; the enclosing
-    ``lm.step`` span is the one a flight recorder sees open."""
+def _record_dispatched(t0: float, t1: float) -> None:
+    """A decoder's dispatch, call to return, as a complete record. A
+    with-span would cost a begin event and a ``TraceAnnotation`` for an
+    interval of microseconds; the enclosing ``lm.step`` span is the one
+    a flight recorder sees open."""
+    # dsst: ignore[span-discipline] up to three records a decode step beside the lm.step span (docstring)
+    telemetry.get_span_log().record(
+        "lm.dispatch", _epoch_offset() + t0, t1 - t0
+    )
+
+
+def _record_fetched(t0: float, t1: float, t2: float) -> None:
+    """A decoder's fetch as two complete records: call to the step done
+    on the device, to its ids (and logits) on the host."""
     log, wall = telemetry.get_span_log(), _epoch_offset()
-    # dsst: ignore[span-discipline] three records a decode step inside the lm.step span (docstring)
-    log.record("lm.dispatch", wall + t0, t1 - t0)
     # dsst: ignore[span-discipline] as lm.dispatch above
-    log.record("lm.wait", wall + t1, t2 - t1)
+    log.record("lm.wait", wall + t0, t1 - t0)
     # dsst: ignore[span-discipline] as lm.dispatch above
-    log.record("lm.fetch", wall + t2, t3 - t2)
+    log.record("lm.fetch", wall + t1, t2 - t1)
 
 
 class TransformerDecoder:
@@ -216,6 +242,9 @@ class TransformerDecoder:
         self.buckets = tuple(buckets)
         self.vocab_size = model.vocab_size
         self._arena = kvcache.make_arena(model, self.slots, self.max_len)
+        # The last dispatched step's greedy ids, on the device: the next
+        # step's tokens wherever the host does not override them.
+        self._ids = jnp.zeros(self.slots, jnp.int32)
         # ONE prefill scratch cache, recycled: the returned (donated-in)
         # buffers become the next call's input. Stale rows past the real
         # prompt are never attended and are overwritten before the
@@ -233,9 +262,11 @@ class TransformerDecoder:
         """Compile every production shape before serving traffic."""
         for bucket in self.buckets:
             self.prefill(np.zeros((1, bucket), np.int32), 1, 0)
-        self.step(
-            np.zeros(self.slots, np.int32), np.zeros(self.slots, np.int32)
-        )
+        # Twice: the second step takes the first's ids, as every step
+        # after an engine's first does.
+        zeros = np.zeros(self.slots, np.int32)
+        for _ in range(2):
+            self.fetch(self.dispatch(zeros, zeros), logits=True)
 
     def prefill(self, tokens: np.ndarray, n_real: int, slot: int):
         """Prefill one bucket-padded prompt and scatter it into ``slot``.
@@ -253,25 +284,36 @@ class TransformerDecoder:
         row = logits[0] if logits.ndim == 2 else logits[0, n_real - 1]
         return np.asarray(row, np.float32)
 
-    def step(self, tokens: np.ndarray, pos: np.ndarray) -> np.ndarray:
-        """One ``slot_decode`` over every slot; returns [slots, vocab].
+    def dispatch(self, override: np.ndarray, pos: np.ndarray):
+        """Launch one ``slot_decode`` over every slot and return at once.
 
-        Three intervals of the engine's thread, each recorded: the
-        dispatch (two small host-to-device copies and the jitted call
-        returning), the wait for the device, the copy of the logits to
-        the host."""
-        jnp = self._jnp
+        A slot's token is ``override`` where that is not negative and
+        the previous dispatched step's greedy id otherwise, merged on
+        the device. Returns the step, for :meth:`fetch`. Recorded as
+        ``lm.dispatch``: two small host-to-device copies and the jitted
+        call returning."""
         t0 = time.perf_counter()
-        logits, self._arena = self._step_fn(
-            self.model, self.variables,
-            jnp.asarray(tokens, jnp.int32), self._arena,
-            jnp.asarray(pos, jnp.int32),
+        logits, ids, self._arena = self._step_fn(
+            self.model, self.variables, self._ids, self._arena,
+            np.asarray(pos, np.int32), np.asarray(override, np.int32),
         )
+        self._ids = ids
+        _record_dispatched(t0, time.perf_counter())
+        return ids, logits
+
+    def fetch(self, step, *, logits: bool = False):
+        """Wait for a dispatched step; its ids ``[slots]`` on the host
+        and, where asked, its logits ``[slots, vocab]`` (else None).
+        Recorded as ``lm.wait`` (until the device has finished the
+        step) and ``lm.fetch`` (the copies to the host)."""
+        ids, rows = step
+        t0 = time.perf_counter()
+        ids.block_until_ready()
         t1 = time.perf_counter()
-        logits.block_until_ready()
-        t2 = time.perf_counter()
-        out = np.asarray(logits, np.float32)
-        _record_step_parts(t0, t1, t2, time.perf_counter())
+        out = np.asarray(ids), (
+            np.asarray(rows, np.float32) if logits else None
+        )
+        _record_fetched(t0, t1, time.perf_counter())
         return out
 
 
@@ -279,10 +321,13 @@ class StubLMDecoder:
     """Model-free test double: fixed per-STEP cost.
 
     The next token is a pure function of (last token, position), so
-    streams are deterministic; ``step()`` sleeps ``step_ms`` ONCE no
-    matter how many slots are active, so what a test reads off it is
-    structure (which requests shared a step), never a speed.
-    Logits are one-hot so greedy sampling recovers the function exactly.
+    streams are deterministic. A step costs ``step_ms`` on a timeline
+    of the stub's own, one step after another from the moment each is
+    dispatched and no matter how many slots are active, as a device
+    queue would run them; ``fetch`` sleeps until its step's end. What a
+    test reads off it is structure (which requests shared a step, what
+    was dispatched before what was fetched), never a speed. Logits are
+    one-hot at the id.
     """
 
     def __init__(self, *, vocab_size=256, step_ms=2.0, prefill_ms=None,
@@ -295,6 +340,8 @@ class StubLMDecoder:
         self.slots = int(slots)
         self.max_len = int(max_len)
         self.buckets = tuple(buckets)
+        self._ids = np.zeros(self.slots, np.int32)
+        self._busy_until = 0.0
 
     def _next(self, tok: int, pos: int) -> int:
         return (int(tok) * 1103515245 + int(pos) * 12345 + 7) % self.vocab_size
@@ -308,18 +355,38 @@ class StubLMDecoder:
         row[self._next(tokens[0, n_real - 1], n_real - 1)] = 1.0
         return row
 
-    def step(self, tokens: np.ndarray, pos: np.ndarray) -> np.ndarray:
-        # The same three intervals as the real backend records: nothing
-        # to dispatch, the sleep stands for the device, the one-hot rows
-        # for the copy of the logits.
-        t0 = t1 = time.perf_counter()
-        time.sleep(self.step_ms / 1000.0)
-        t2 = time.perf_counter()
-        out = np.zeros((self.slots, self.vocab_size), np.float32)
-        for i in range(self.slots):
-            out[i, self._next(tokens[i], pos[i])] = 1.0
-        _record_step_parts(t0, t1, t2, time.perf_counter())
-        return out
+    def dispatch(self, override: np.ndarray, pos: np.ndarray):
+        t0 = time.perf_counter()
+        tokens = np.where(override >= 0, override, self._ids)
+        self._ids = np.array(
+            [self._next(t, p) for t, p in zip(tokens, pos)], np.int32
+        )
+        self._busy_until = (
+            max(t0, self._busy_until) + self.step_ms / 1000.0
+        )
+        _record_dispatched(t0, time.perf_counter())
+        return self._ids, self._busy_until
+
+    def fetch(self, step, *, logits: bool = False):
+        ids, ready_at = step
+        t0 = time.perf_counter()
+        time.sleep(max(0.0, ready_at - t0))
+        t1 = time.perf_counter()
+        rows = None
+        if logits:
+            rows = np.zeros((self.slots, self.vocab_size), np.float32)
+            rows[np.arange(self.slots), ids] = 1.0
+        _record_fetched(t0, t1, time.perf_counter())
+        return ids, rows
+
+
+@dataclasses.dataclass
+class _Step:
+    """One dispatched decode step the engine has not collected yet."""
+
+    handle: object  # the decoder's own, for its fetch()
+    active: dict  # slot -> the Generation whose token this step computes
+    logits: bool  # some generation in it samples from its logits row
 
 
 class LMEngine:
@@ -360,6 +427,9 @@ class LMEngine:
         self._stopped = False
         self._gen_seq = 0
         self._thread: threading.Thread | None = None
+        # Engine-thread-only: the decode step dispatched and not yet
+        # collected (its ids still on the device), or None.
+        self._in_flight: _Step | None = None
         self._slo = telemetry.slo.get_engine()
         self._admission = AdmissionController(
             self.cfg.queue_depth,
@@ -384,6 +454,14 @@ class LMEngine:
             "prompt tokens prefilled: real, and padded to the bucket",
             labels=("kind",),
         )
+        decode_steps = telemetry.counter(
+            "lm_decode_steps_total",
+            "decode steps dispatched: ahead of the step before them "
+            "being collected, or in lock-step with it",
+            labels=("mode",),
+        )
+        self._steps_ahead = decode_steps.labels(mode="ahead")
+        self._steps_lockstep = decode_steps.labels(mode="lockstep")
         self._prefill_real = prefill_tokens.labels(kind="real")
         self._prefill_padded = prefill_tokens.labels(kind="padded")
         self._ttft_window = telemetry.window(
@@ -571,6 +649,7 @@ class LMEngine:
                     not self._stopped
                     and not self._waiting
                     and not self._active
+                    and self._in_flight is None
                 ):
                     self._cond.wait(0.05)
                 if self._stopped:
@@ -660,27 +739,84 @@ class LMEngine:
             self._slots_gauge.set(len(self._active))
 
     def _step_once(self) -> None:
+        """One turn of the decode loop: dispatch the next step, collect
+        the one dispatched a turn earlier.
+
+        Step n+1 reads its tokens from step n's ids on the device, so
+        it goes out before step n is collected, and the collecting
+        (streaming, windows, SLO notes, retirement) runs under it. That
+        holds while every active generation is greedy. One that samples
+        on the host is not stepped past: its turn collects the step it
+        dispatched (depth 0 of the same loop), and a step already in
+        flight when it was admitted is collected first.
+        """
         with self._cond:
             active = dict(self._active)
-        if not active:
+        due = self._in_flight
+
+        def in_flight(slot, gen) -> bool:
+            return due is not None and due.active.get(slot) is gen
+
+        lockstep = not all(gen.greedy for gen in active.values())
+        stepping = {}
+        if due is None or not lockstep:
+            # max_new_tokens is known now: a slot whose last token the
+            # step in flight computes is not stepped again. EOS, a
+            # cancel and a deadline are learned at collection; such a
+            # slot's row of the step after is computed and dropped.
+            stepping = {
+                slot: gen for slot, gen in active.items()
+                if gen.emitted + in_flight(slot, gen) < gen.max_new_tokens
+            }
+        if not stepping:
+            self._in_flight = None
+            if due is not None:
+                # The last step of a run, or one a sampling request
+                # caught in flight: nothing goes out with it, so no
+                # lm.step span is open around its lm.wait and lm.fetch.
+                self._collect(due, self.decoder.fetch(
+                    due.handle, logits=due.logits))
             return
         # Sized to the DECODER's arena, not cfg.slots: both backends
         # iterate/vmap over decoder.slots, and the constructor allows a
-        # decoder with more slots than the config admits.
-        tokens = np.zeros(self.decoder.slots, np.int32)
+        # decoder with more slots than the config admits. An idle slot
+        # decodes token 0 at position 0 into a row nobody reads.
+        override = np.zeros(self.decoder.slots, np.int32)
         pos = np.zeros(self.decoder.slots, np.int32)
-        for slot, gen in active.items():
-            tokens[slot] = gen.last_token
+        for slot, gen in stepping.items():
+            # The token the step in flight computes for this generation
+            # stays on the device; any other the host streamed itself.
+            override[slot] = -1 if in_flight(slot, gen) else gen.last_token
             pos[slot] = gen.n_past
-        with telemetry.span("lm.step", active=len(active),
+            gen.n_past += 1
+        with telemetry.span("lm.step", active=len(stepping),
                             context_tokens=int(pos.sum())):
-            logits = self.decoder.step(tokens, pos)
+            step = _Step(self.decoder.dispatch(override, pos), stepping,
+                         logits=lockstep)
+            (self._steps_lockstep if due is None else self._steps_ahead).inc()
+            if due is None and lockstep:
+                due = step          # depth 0: collected in its own turn
+            self._in_flight = None if due is step else step
+            if due is not None:
+                fetched = self.decoder.fetch(due.handle, logits=due.logits)
+        if due is not None:
+            self._collect(due, fetched)
+
+    def _collect(self, step: _Step, fetched) -> None:
+        """Stream, note and retire for one decode step whose ids (and
+        logits, for a generation that samples) are on the host."""
+        ids, logits = fetched
         t_sample = time.perf_counter()
         now = time.monotonic()
+        with self._cond:
+            live = dict(self._active)
         retired = 0
-        for slot in sorted(active):
-            gen = active[slot]
-            gen.n_past += 1
+        for slot in sorted(step.active):
+            gen = step.active[slot]
+            if live.get(slot) is not gen:
+                # Retired (EOS, cancel, deadline, error) while this step
+                # was in flight: its row was computed for nobody.
+                continue
             if gen.cancelled:
                 self._retire_slot(slot, gen, reason="cancelled")
                 retired += 1
@@ -690,7 +826,10 @@ class LMEngine:
                 retired += 1
                 continue
             try:
-                token = gen.sample(logits[slot])
+                token = (
+                    int(ids[slot]) if gen.greedy
+                    else gen.sample(logits[slot])
+                )
             except Exception as exc:
                 # Per-generation blast radius: a sample() failure
                 # retires this slot with an error event; the step loop
@@ -706,11 +845,11 @@ class LMEngine:
             if self._should_retire(gen, token):
                 self._retire_slot(slot, gen)
                 retired += 1
-        # dsst: ignore[span-discipline] the count of retired slots is known only at close; the loop is host-only work of well under a millisecond with no device call to be cut short in
+        # dsst: ignore[span-discipline] the count of retired slots is known only at close; the loop is host-only work with no device call to be cut short in
         telemetry.get_span_log().record(
             "lm.sample", _epoch_offset() + t_sample,
             time.perf_counter() - t_sample,
-            active=len(active), retired=retired,
+            active=len(step.active), retired=retired,
         )
 
     def _emit(self, gen: Generation, token: int) -> None:

@@ -72,14 +72,29 @@ def make_arena(model: TransformerLM, slots: int, max_len: int) -> Arena:
     )
 
 
-def slot_decode(model, variables, tokens, arena, pos):
+def slot_decode(model, variables, tokens, arena, pos, override=None):
     """One decode step for every slot: the audited production program.
 
-    ``tokens`` ``[slots] int32`` (each slot's last sampled token),
-    ``pos`` ``[slots] int32`` (the cache position that token occupies).
-    Returns ``(logits [slots, vocab], new_arena)`` with the arena
-    aliased in-place when jitted with ``donate_argnums=(3,)``.
+    ``tokens`` ``[slots] int32`` (each slot's last token), ``pos``
+    ``[slots] int32`` (the cache position that token occupies).
+    ``override`` ``[slots] int32``, where given, replaces ``tokens``
+    wherever it is not negative: the engine hands the previous step's
+    ``ids`` back as ``tokens`` without ever reading them, and overrides
+    the slots whose token the host knows better (one admitted since,
+    whose first token came from its prefill).
+
+    Returns ``(logits [slots, vocab], ids [slots] int32, new_arena)``:
+    ``ids`` is the greedy choice of every slot, ``argmax`` of the
+    float32 logits with the first index winning a tie, as
+    ``np.argmax`` of the same row. The arena is aliased in-place when
+    jitted with ``donate_argnums=(3,)``.
+
+    A row's ``pos`` is not checked: ``dynamic_update_slice`` clamps a
+    write at or past ``max_len`` into the slot's own last row, so a row
+    the engine computes only to throw away cannot reach another slot.
     """
+    if override is not None:
+        tokens = jnp.where(override >= 0, override, tokens)
 
     def one(tok, slot_cache, p):
         cache1 = jax.tree_util.tree_map(lambda a: a[None], slot_cache)
@@ -88,7 +103,9 @@ def slot_decode(model, variables, tokens, arena, pos):
         )
         return logits[0], jax.tree_util.tree_map(lambda a: a[0], new_cache)
 
-    return jax.vmap(one, in_axes=(0, 0, 0))(tokens, arena, pos)
+    logits, arena = jax.vmap(one, in_axes=(0, 0, 0))(tokens, arena, pos)
+    ids = jnp.argmax(logits.astype(jnp.float32), axis=-1).astype(jnp.int32)
+    return logits, ids, arena
 
 
 def prefill_bucket(model, variables, tokens, cache):

@@ -92,6 +92,7 @@ KNOWN_METRICS: dict[str, str] = {
     "slo_alerts_firing": "gauge",
     "train_step_window_seconds": "window",
     # -- LM token serving --------------------------------------------------
+    "lm_decode_steps_total": "counter",
     "lm_inter_token_window_seconds": "window",
     "lm_prefill_tokens_total": "counter",
     "lm_queue_depth": "gauge",
@@ -153,14 +154,21 @@ KNOWN_SPANS: dict[str, str] = {
                       "final streamed chunk",
     "lm.prefill": "one bucket-padded prompt prefill + arena scatter "
                   "(admission into a free slot)",
-    "lm.step": "one slot_decode dispatch over every slot (all active "
-               "generations advance one token)",
-    "lm.dispatch": "inside lm.step: host-to-device copies of tokens and "
-                   "positions and the jitted call returning",
-    "lm.wait": "inside lm.step: until the logits are ready on the device",
-    "lm.fetch": "inside lm.step: the copy of the logits to a host array",
-    "lm.sample": "after lm.step: per-slot sampling, streaming, windows, "
-                 "SLO notes and retirement",
+    "lm.step": "one turn of the decode loop that dispatches a "
+               "slot_decode step (args active, context_tokens: that "
+               "step's) and collects the step dispatched a turn "
+               "earlier, or its own while a request samples on the host",
+    "lm.dispatch": "inside lm.step: host-to-device copies of the token "
+                   "overrides and positions and the jitted call returning",
+    "lm.wait": "until the step being collected (one dispatched a turn "
+               "earlier, while steps run ahead) is done on the device; "
+               "inside lm.step unless nothing was dispatched that turn",
+    "lm.fetch": "after lm.wait: the copy of that step's [slots] ids, and "
+                "of its logits while a request samples on the host, to "
+                "host arrays",
+    "lm.sample": "after lm.step: for the step collected, per-slot token "
+                 "(the device's id, or sampled from the logits row), "
+                 "streaming, windows, SLO notes and retirement",
     "lm.admit": "the admission scan over the waiting list and its "
                 "settlements, when there was anything to scan",
     # -- HPO ---------------------------------------------------------------
@@ -219,7 +227,9 @@ SPAN_ATTRIBUTION: dict[str, str] = {
 
 # Spans that lie inside another span of the same thread and trace:
 # reader.assemble runs on the feeder thread inside reader.next, the
-# three parts of a decode step inside lm.step. The two consumers of
+# three parts of a decode step inside lm.step (the wait and the fetch of
+# a run's last step, which dispatches nothing, lie outside one and are
+# left out all the same). The two consumers of
 # SPAN_ATTRIBUTION sum durations a trace, so they leave these out: the
 # enclosing span already holds their wall time. (reader.read and
 # reader.decode run on the reader's own threads under no step's trace,

@@ -81,7 +81,7 @@ def _slot_decode(model, variables):
         kvcache.make_arena(model, SLOTS, MAX_LEN))
     tokens = jnp.asarray(rng.integers(1, 64, SLOTS), jnp.int32)
     pos = jnp.asarray([5, 0, 17], jnp.int32)
-    logits, _ = jax.jit(kvcache.slot_decode, static_argnums=0)(
+    logits, _, _ = jax.jit(kvcache.slot_decode, static_argnums=0)(
         model, variables, tokens, arena, pos)
     return logits
 
@@ -196,9 +196,9 @@ def test_the_lowered_slot_decode_takes_no_wide_kernel_or_embedding():
                    if leaf.ndim == 2 and not _kept_float32(path)}
     assert (model.vocab_size, model.dim) in cast_shapes      # tok_embed
     assert (model.dim, 3 * model.dim) in cast_shapes         # qkv
+    vec = jnp.zeros(SLOTS, jnp.int32)
     lowered = decoder._step_fn.lower(
-        model, decoder.variables, jnp.zeros(SLOTS, jnp.int32),
-        decoder._arena, jnp.zeros(SLOTS, jnp.int32))
+        model, decoder.variables, vec, decoder._arena, vec, vec)
     args = jax.tree_util.tree_leaves(lowered.in_avals)
     float32_args = [a for a in args if a.dtype == jnp.float32]
     # What stays float32: the norms' scales and the head, nothing else.

@@ -1,7 +1,8 @@
 """The spans and counters inside the reader's threads and the LM engine's
 loop (PR 24): that they appear where the work happens, that their sums
 stay inside what the clock allows, and that the engine thread's time
-between two decode steps is cut into named intervals that do not overlap.
+between two decode steps is cut into named intervals that do not overlap,
+with one decode step in flight and in lock-step (PR 30).
 """
 
 from __future__ import annotations
@@ -35,6 +36,7 @@ ENGINE_SPANS = ("lm.dispatch", "lm.wait", "lm.fetch", "lm.sample", "lm.admit")
 NEW_METRICS = {
     "reader_stage_seconds_total": "counter", "reader_rows_total": "counter",
     "reader_workers": "gauge", "lm_prefill_tokens_total": "counter",
+    "lm_decode_steps_total": "counter",
 }
 REMOVED_METRICS = ("trace_spans_total", "lm_decode_step_seconds",
                    "lm_prefill_seconds")
@@ -151,32 +153,50 @@ class RecordingStub(StubLMDecoder):
         super().__init__(**kw)
         self.stepped: list = []
 
-    def step(self, tokens, pos):
+    def dispatch(self, override, pos):
         self.stepped.append(np.array(pos))
-        return super().step(tokens, pos)
+        return super().dispatch(override, pos)
 
 
 PROMPTS = [[(3 * i + j) % 97 for j in range(2 + i % 7)] for i in range(8)]
+DEPTHS = ["ahead", "lockstep"]
 
 
-@pytest.fixture(scope="module")
-def engine_run():
-    """8 streams over 3 slots, to the end; the span log afterwards."""
+@pytest.fixture(scope="module", params=DEPTHS)
+def engine_run(request):
+    """8 greedy streams over 3 slots, to the end; the span log
+    afterwards. At depth ``lockstep`` a request that samples on the host
+    holds a fourth slot from before the first to after the last, so the
+    engine collects every step in the turn that dispatched it."""
     telemetry.reset()
-    decoder = RecordingStub(vocab_size=97, step_ms=8.0, slots=3, max_len=48,
-                            buckets=(8, 16))
-    engine = LMEngine(decoder, LMConfig(slots=3, max_len=48,
+    parked = request.param == "lockstep"
+    slots = 3 + parked
+    decoder = RecordingStub(vocab_size=97, step_ms=8.0, slots=slots,
+                            max_len=512, buckets=(8, 16))
+    engine = LMEngine(decoder, LMConfig(slots=slots, max_len=512,
                                         prefill_buckets=(8, 16),
                                         queue_depth=16)).start()
     try:
+        sampler = None
+        if parked:
+            sampler = engine.submit([1], 500, temperature=1.0, seed=5)
+            assert sampler.next_event(timeout=30.0)[0] == "token"
         gens = [engine.submit(p, 6, seed=i) for i, p in enumerate(PROMPTS)]
         for gen in gens:
             while gen.next_event(timeout=30.0)[0] == "token":
                 pass
+        if parked:
+            assert not sampler.is_settled()
+            sampler.cancel()
+            while sampler.next_event(timeout=30.0)[0] == "token":
+                pass
     finally:
         engine.drain(5.0)
-    return {"decoder": decoder,
+    return {"depth": request.param, "decoder": decoder,
+            "requests": len(PROMPTS) + parked,
             "events": telemetry.get_span_log().events(),
+            "ahead": series("lm_decode_steps_total", mode="ahead"),
+            "lockstep": series("lm_decode_steps_total", mode="lockstep"),
             "real": series("lm_prefill_tokens_total", kind="real"),
             "padded": series("lm_prefill_tokens_total", kind="padded")}
 
@@ -202,45 +222,88 @@ def test_engine_intervals_do_not_overlap_and_cover_the_loop(engine_run):
     assert covered >= 0.95 * (hi - lo), covered / (hi - lo)
 
 
-@pytest.mark.parametrize("part", ["lm.dispatch", "lm.wait", "lm.fetch"])
-def test_the_parts_of_a_step_lie_inside_it(engine_run, part):
+def test_every_step_holds_its_dispatch_and_the_collection_of_one(engine_run):
+    """One ``lm.step`` a dispatched decode step, its ``lm.dispatch``
+    inside it; ``lm.wait`` then ``lm.fetch`` then ``lm.sample`` once a
+    collected step. In lock-step the step collected is the span's own;
+    a step ahead it is the one before, so a run of steps begins with a
+    span that collects nothing and ends with a collection under no
+    span."""
     events = engine_run["events"]
     steps = intervals(events, {"lm.step"})
-    parts = intervals(events, {part})
-    assert len(parts) == len(steps)
-    for (s0, s1, _), (p0, p1, _) in zip(steps, parts):
+    dispatches = intervals(events, {"lm.dispatch"})
+    assert len(dispatches) == len(steps)
+    for (s0, s1, _), (p0, p1, _) in zip(steps, dispatches):
         assert s0 - 50e-6 <= p0 and p1 <= s1 + 50e-6
-    if part == "lm.wait":       # the stub's sleep stands for the device
-        assert all(p1 - p0 >= 0.008 for p0, p1, _ in parts)
+    collected = intervals(events, {"lm.wait", "lm.fetch", "lm.sample"})
+    assert [name for _, _, name in collected] == [
+        "lm.wait", "lm.fetch", "lm.sample"] * (len(collected) // 3)
+    waits = [c for c in collected if c[2] == "lm.wait"]
+    fetches = [c for c in collected if c[2] == "lm.fetch"]
+    # every dispatched step is collected, and none twice
+    assert len(waits) == len(steps)
+
+    def inside(part):
+        return [k for k, (s0, s1, _) in enumerate(steps)
+                if s0 - 50e-6 <= part[0] and part[1] <= s1 + 50e-6]
+
+    homes = [inside(w) for w in waits]
+    assert homes == [inside(f) for f in fetches]
+    if engine_run["depth"] == "lockstep":
+        assert homes == [[k] for k in range(len(steps))]
+        # the stub's sleep stands for the device
+        assert all(w1 - w0 >= 0.008 for w0, w1, _ in waits)
+    else:
+        # the k-th collection lies in the span that dispatched step k+1,
+        # or, where the engine had nothing more to dispatch, in none
+        assert all(home in ([], [k + 1]) for k, home in enumerate(homes))
+        bare = sum(1 for home in homes if not home)
+        assert 1 <= bare == engine_run["lockstep"] < len(steps) / 3
+        # the device's time, less what the host spent since the dispatch
+        assert sum(w1 - w0 for w0, w1, _ in waits) >= 0.004 * len(waits)
+
+
+def test_decode_steps_are_counted_by_how_they_were_dispatched(engine_run):
+    steps = len(engine_run["decoder"].stepped)
+    assert engine_run["ahead"] + engine_run["lockstep"] == steps
+    if engine_run["depth"] == "lockstep":
+        assert engine_run["ahead"] == 0
+    else:
+        assert engine_run["ahead"] > 2 * engine_run["lockstep"] > 0
 
 
 def test_context_tokens_is_the_sum_of_the_positions_stepped(engine_run):
     steps = sorted((e for e in engine_run["events"]
                     if e["name"] == "lm.step"), key=lambda e: e["ts"])
+    slots = engine_run["decoder"].slots
     for event, pos in zip(steps, engine_run["decoder"].stepped):
         # idle slots are stepped at position 0
         assert event["args"]["context_tokens"] == int(pos.sum())
-        assert 1 <= event["args"]["active"] <= 3
+        assert 1 <= event["args"]["active"] <= slots
+        # a slot whose last token is in flight is left out of the step
+        assert event["args"]["active"] == int((pos > 0).sum())
 
 
 def test_the_sampler_interval_counts_what_it_retired(engine_run):
     samples = [e["args"] for e in engine_run["events"]
                if e["name"] == "lm.sample"]
-    assert sum(a["retired"] for a in samples) == len(PROMPTS)
-    assert all(a["retired"] <= a["active"] <= 3 for a in samples)
+    assert sum(a["retired"] for a in samples) == engine_run["requests"]
+    assert all(a["retired"] <= a["active"] <= engine_run["decoder"].slots
+               for a in samples)
     admits = [e["args"] for e in engine_run["events"]
               if e["name"] == "lm.admit"]
-    assert sum(a["admitted"] for a in admits) == len(PROMPTS)
+    assert sum(a["admitted"] for a in admits) == engine_run["requests"]
 
 
 def test_prefill_tokens_real_and_padded_match_the_prompts(engine_run):
-    assert engine_run["real"] == sum(len(p) for p in PROMPTS)
-    # prompts of 2..8 tokens all pad to the bucket of 8
-    assert engine_run["padded"] == 8 * len(PROMPTS)
+    parked = engine_run["requests"] - len(PROMPTS)   # its prompt: 1 token
+    assert engine_run["real"] == sum(len(p) for p in PROMPTS) + parked
+    # prompts of 1..8 tokens all pad to the bucket of 8
+    assert engine_run["padded"] == 8 * engine_run["requests"]
     prefills = [e["args"] for e in engine_run["events"]
                 if e["name"] == "lm.prefill"]
     assert sorted(a["prompt_tokens"] for a in prefills) == sorted(
-        len(p) for p in PROMPTS)
+        [len(p) for p in PROMPTS] + [1] * parked)
 
 
 # -- what a span costs, and what went ------------------------------------------
