@@ -340,6 +340,83 @@ def prefill_lm(mesh) -> ProgramSpec:
     )
 
 
+def _served_mla_moe(mesh):
+    """A small latent-attention expert model at the dtype ``dsst
+    serve-lm --model-config`` serves (bfloat16), holding experts 2..5 of
+    the 8 its router scores, with the tree its decoder holds."""
+    import jax
+
+    from ...models.mla_moe import MlaMoeLM
+
+    model = MlaMoeLM(
+        vocab_size=128, hidden_size=64, num_layers=2,
+        num_attention_heads=4, q_lora_rank=32, kv_lora_rank=16,
+        qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=16,
+        moe_intermediate_size=32, n_routed_experts=4, router_width=8,
+        expert_offset=2, num_experts_per_tok=2, rope_factor=4.0,
+        original_max_position_embeddings=16, mscale_all_dim=1.0,
+        llama_4_scaling_beta=0.1, attention="reference",
+    )
+    return model, jax.device_put(
+        model.init(jax.random.key(0)), _replicated(mesh)
+    )
+
+
+def slot_decode_mla_moe(mesh) -> ProgramSpec:
+    """The serving step of the latent-attention expert model: one
+    batched call over the latent slot arena (the expert layer routes
+    every slot's token together), the arena donated as in
+    ``slot_decode.lm``."""
+    import jax
+    import jax.numpy as jnp
+
+    from ...serving.lm import kvcache
+
+    model, variables = _served_mla_moe(mesh)
+    replicated = _replicated(mesh)
+    arena = jax.device_put(kvcache.make_arena(model, 4, 32), replicated)
+    zeros = jax.device_put(jnp.zeros((4,), jnp.int32), replicated)
+    return ProgramSpec(
+        name="slot_decode.mla_moe",
+        fn=kvcache.slot_decode,
+        args=(model, variables, zeros, arena, zeros, zeros),
+        jit_kwargs={
+            "static_argnums": 0,
+            "donate_argnums": (3,),
+            "out_shardings": replicated,
+        },
+        expect_donated=(3,),
+    )
+
+
+def prefill_mla_moe(mesh) -> ProgramSpec:
+    """One bucketed prefill of the latent-attention expert model
+    through the expanded path into a donated one-slot latent cache; the
+    count of real tokens is an argument and one logits row comes
+    back."""
+    import jax
+    import jax.numpy as jnp
+
+    from ...serving.lm import kvcache
+
+    model, variables = _served_mla_moe(mesh)
+    replicated = _replicated(mesh)
+    cache = jax.device_put(kvcache.make_arena(model, 1, 32), replicated)
+    tokens = jax.device_put(jnp.zeros((1, 16), jnp.int32), replicated)
+    n_real = jax.device_put(jnp.int32(9), replicated)
+    return ProgramSpec(
+        name="prefill.mla_moe",
+        fn=kvcache.prefill_bucket,
+        args=(model, variables, tokens, cache, n_real),
+        jit_kwargs={
+            "static_argnums": 0,
+            "donate_argnums": (3,),
+            "out_shardings": replicated,
+        },
+        expect_donated=(3,),
+    )
+
+
 def serving_score(mesh) -> ProgramSpec:
     import jax
     import numpy as np
@@ -520,6 +597,8 @@ _BUILDERS: dict[str, Callable] = {
     "decode_step.lm": decode_step_lm,
     "slot_decode.lm": slot_decode_lm,
     "prefill.lm": prefill_lm,
+    "slot_decode.mla_moe": slot_decode_mla_moe,
+    "prefill.mla_moe": prefill_mla_moe,
     "serving.score": serving_score,
     "ops.fused_matmul.grad": fused_matmul_grad,
     "ops.fused_norm.grad": fused_norm_grad,

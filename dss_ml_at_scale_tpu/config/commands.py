@@ -1893,6 +1893,15 @@ def register_serve_lm(sub: argparse._SubParsersAction) -> None:
     sv.add_argument("--layers", type=int, default=2)
     sv.add_argument("--attention", choices=["flash", "reference"],
                     default="reference")
+    sv.add_argument(
+        "--model-config", default=None, metavar="JSON",
+        help="architecture file of a latent-attention expert model "
+        "(models/mla_moe.py: the published config.json keys, with "
+        "num_layers, n_routed_experts and vocab_size as held here, "
+        "router_width and expert_offset beside them); served with "
+        "random weights through the same decoder and engine, in place "
+        "of the TransformerLM that --vocab/--dim/--heads/--layers size",
+    )
     sv.add_argument("--seed", type=int, default=0,
                     help="init seed for the random-weight TransformerLM "
                     "(no LM checkpoint format yet; serving a trained LM "
@@ -1938,31 +1947,39 @@ def _cmd_serve_lm(args: argparse.Namespace) -> int:
         import jax
         import jax.numpy as jnp
 
-        from ..models import TransformerLM
+        from ..models import MlaMoeLM, TransformerLM
         from ..serving.lm import TransformerDecoder
 
-        model = TransformerLM(
-            vocab_size=args.vocab, dim=args.dim, num_heads=args.heads,
-            num_layers=args.layers, max_seq=args.max_len,
-            attention=args.attention,
-        )
-        # The float32 tree gets no name here: the decoder keeps its own
-        # copy at the widths it multiplies in, and the wide one is freed.
-        decoder = TransformerDecoder(
-            model,
-            model.init(
+        # Neither tree gets a name here: the decoder keeps its own copy
+        # at the widths it multiplies in, and a wider one is freed.
+        if args.model_config:
+            model = MlaMoeLM.from_config(
+                args.model_config, attention=args.attention
+            )
+            variables = model.init(jax.random.key(args.seed))
+        else:
+            model = TransformerLM(
+                vocab_size=args.vocab, dim=args.dim, num_heads=args.heads,
+                num_layers=args.layers, max_seq=args.max_len,
+                attention=args.attention,
+            )
+            variables = model.init(
                 jax.random.key(args.seed),
                 jnp.zeros((1, config.prefill_buckets[0]), jnp.int32),
-            ),
+            )
+        decoder = TransformerDecoder(
+            model, variables,
             slots=args.slots, max_len=args.max_len,
             buckets=config.prefill_buckets,
         )
+        del variables
         from ..runtime.compile_cache import enable_compile_cache
 
         # The boot line names the device the weights sit on, as JAX
         # reports it, and where compiled programs are cached.
         dev = jax.devices()[0]
         device_facts = {
+            "model": type(model).__name__,
             "device": {"platform": dev.platform, "kind": dev.device_kind,
                        "count": len(jax.devices())},
             "compile_cache_dir": enable_compile_cache(),

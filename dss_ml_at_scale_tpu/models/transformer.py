@@ -277,6 +277,63 @@ class TransformerLM(nn.Module):
             return (logits[:, 0] if s == 1 else logits), tuple(new_cache)
         return logits
 
+    # -- what the serving decoder asks of a served model ---------------
+    # (:mod:`..serving.lm.kvcache`: the model, not the cache module,
+    # says what one slot's cache is and how a step reads it.)
+
+    cache_kind = "kv"
+
+    @nn.nowrap
+    def init_cache(self, slots: int, max_len: int):
+        """One ``[slots, heads, max_len, head_dim]`` k and v slab a layer.
+
+        ``max_len`` may be smaller than ``max_seq``: the attention mask
+        and the cache writes both derive their length from the cache's
+        own shape, so a short arena is a working (cheaper) cache.
+        """
+        if max_len > self.max_seq:
+            raise ValueError(
+                f"arena max_len {max_len} > model max_seq {self.max_seq}"
+            )
+        shape = (slots, self.num_heads, max_len, self.dim // self.num_heads)
+        return tuple(
+            {
+                "k": jnp.zeros(shape, dtype=self.dtype),
+                "v": jnp.zeros(shape, dtype=self.dtype),
+            }
+            for _ in range(self.num_layers)
+        )
+
+    @nn.nowrap
+    def serving_variables(self, variables):
+        return serving_variables(self, variables)
+
+    @nn.nowrap
+    def prefill_cache(self, variables, tokens, cache, n_real=None):
+        """One bucket-padded prompt ``[1, bucket]`` into a one-slot
+        cache. Returns every row's logits (``[1, bucket, vocab]``, or
+        ``[1, vocab]`` for a bucket of one token), no stats, the cache;
+        ``n_real`` is not read."""
+        logits, cache = self.apply(variables, tokens, cache=cache, pos=0)
+        return logits, None, cache
+
+    @nn.nowrap
+    def decode_slots(self, variables, tokens, cache, pos):
+        """One token for every slot: ``jax.vmap`` of the one-sequence
+        cached decode over the slot axis with a per-slot ``pos``.
+        Returns (logits ``[slots, vocab]``, no stats, cache)."""
+
+        def one(tok, slot_cache, p):
+            cache1 = jax.tree_util.tree_map(lambda a: a[None], slot_cache)
+            logits, new_cache = self.apply(
+                variables, tok[None, None], cache=cache1, pos=p
+            )
+            return logits[0], jax.tree_util.tree_map(
+                lambda a: a[0], new_cache)
+
+        logits, cache = jax.vmap(one, in_axes=(0, 0, 0))(tokens, cache, pos)
+        return logits, None, cache
+
 
 # The leaves the model multiplies in float32 whatever its ``dtype``, by
 # the end of their path: every ``RMSNorm`` scale (``rms_norm`` takes
@@ -316,12 +373,7 @@ def serving_variables(model: TransformerLM, variables):
 
 def init_kv_cache(model: TransformerLM, batch: int):
     """Zeroed per-layer K/V buffers sized [b, heads, max_seq, head_dim]."""
-    head_dim = model.dim // model.num_heads
-    shape = (batch, model.num_heads, model.max_seq, head_dim)
-    return tuple(
-        {"k": jnp.zeros(shape, model.dtype), "v": jnp.zeros(shape, model.dtype)}
-        for _ in range(model.num_layers)
-    )
+    return model.init_cache(batch, model.max_seq)
 
 
 def decode_step(model: TransformerLM, variables, tokens, cache, pos):

@@ -51,7 +51,6 @@ import time
 import numpy as np
 
 from ... import telemetry
-from ...models.transformer import serving_variables
 from ..admission import AdmissionController, DeadlineExceeded, NotAccepting
 from . import kvcache
 
@@ -213,10 +212,20 @@ class TransformerDecoder:
     donated ``write_slot`` scatter per admission. ``warmup()`` compiles
     all of them before the server reports ready.
 
+    The decoder is written against what a served model gives
+    (:mod:`.kvcache`): its cache for ``slots`` x ``max_len``, a prefill
+    of one padded prompt into a one-slot cache, a decode of all slots at
+    once with a per-slot ``pos``. It knows no layout of the cache.
+
     The decoder holds its own tree, each leaf at the width the model
-    multiplies it in (``serving_variables``: cast once here, not inside
-    every program), and no reference to the wider originals: a caller
-    that drops its ``variables`` frees them.
+    multiplies it in (the model's ``serving_variables``: cast once here,
+    not inside every program), and no reference to the wider originals:
+    a caller that drops its ``variables`` frees them.
+
+    A model whose programs return ``stats`` beside the ids (an expert
+    layer's routing counts, ``[held, absent, touched, per held
+    expert...]``) has them fetched with the ids and counted here, under
+    ``lm_moe_*``.
     """
 
     def __init__(self, model, variables, *, slots, max_len, buckets):
@@ -225,7 +234,7 @@ class TransformerDecoder:
 
         self._jax, self._jnp = jax, jnp
         self.model = model
-        self.variables = serving_variables(model, variables)
+        self.variables = model.serving_variables(variables)
         held: dict[str, int] = {}
         for leaf in jax.tree_util.tree_leaves(self.variables):
             name = str(leaf.dtype)
@@ -242,6 +251,13 @@ class TransformerDecoder:
         self.buckets = tuple(buckets)
         self.vocab_size = model.vocab_size
         self._arena = kvcache.make_arena(model, self.slots, self.max_len)
+        telemetry.gauge(
+            "lm_cache_bytes",
+            "bytes of the slot arena, by the kind of state a slot holds",
+            labels=("kind",),
+        ).labels(kind=model.cache_kind).set(
+            sum(a.nbytes for a in jax.tree_util.tree_leaves(self._arena))
+        )
         # The last dispatched step's greedy ids, on the device: the next
         # step's tokens wherever the host does not override them.
         self._ids = jnp.zeros(self.slots, jnp.int32)
@@ -257,6 +273,35 @@ class TransformerDecoder:
             kvcache.prefill_bucket, static_argnums=0, donate_argnums=(3,)
         )
         self._write_fn = jax.jit(kvcache.write_slot, donate_argnums=(0,))
+        assignments = telemetry.counter(
+            "lm_moe_assignments_total",
+            "routed token-expert pairs of active slots and real prompt "
+            "tokens: to an expert held here, or to an absent one",
+            labels=("where",),
+        )
+        self._moe_held = assignments.labels(where="held")
+        self._moe_absent = assignments.labels(where="absent")
+        self._moe_expert = telemetry.counter(
+            "lm_moe_expert_assignments_total",
+            "routed token-expert pairs by held expert, summed over layers",
+            labels=("expert",),
+        )
+        self._moe_touched = telemetry.counter(
+            "lm_moe_experts_touched_total",
+            "held experts that got at least one token, per step and "
+            "summed over layers",
+            labels=("program",),
+        )
+
+    def _count(self, stats, program: str) -> None:
+        """Feed the expert layer's counters from one program's stats."""
+        held, absent, touched = (int(n) for n in stats[:3])
+        self._moe_held.inc(held)
+        self._moe_absent.inc(absent)
+        self._moe_touched.labels(program=program).inc(touched)
+        for expert, n in enumerate(stats[3:]):
+            if n:
+                self._moe_expert.labels(expert=str(expert)).inc(int(n))
 
     def warmup(self) -> None:
         """Compile every production shape before serving traffic."""
@@ -275,13 +320,18 @@ class TransformerDecoder:
         numpy) — what the first sampled token comes from.
         """
         jnp = self._jnp
-        logits, cache = self._prefill_fn(
+        logits, stats, cache = self._prefill_fn(
             self.model, self.variables,
             jnp.asarray(tokens, jnp.int32), self._scratch,
+            np.int32(n_real),
         )
         self._arena = self._write_fn(self._arena, cache, jnp.int32(slot))
         self._scratch = cache
+        # A model gives every row of the bucket or the last real one.
         row = logits[0] if logits.ndim == 2 else logits[0, n_real - 1]
+        row, stats = self._jax.device_get((row, stats))
+        if stats is not None:
+            self._count(stats, "prefill")
         return np.asarray(row, np.float32)
 
     def dispatch(self, override: np.ndarray, pos: np.ndarray):
@@ -293,27 +343,29 @@ class TransformerDecoder:
         ``lm.dispatch``: two small host-to-device copies and the jitted
         call returning."""
         t0 = time.perf_counter()
-        logits, ids, self._arena = self._step_fn(
+        logits, ids, stats, self._arena = self._step_fn(
             self.model, self.variables, self._ids, self._arena,
             np.asarray(pos, np.int32), np.asarray(override, np.int32),
         )
         self._ids = ids
         _record_dispatched(t0, time.perf_counter())
-        return ids, logits
+        return ids, logits, stats
 
     def fetch(self, step, *, logits: bool = False):
         """Wait for a dispatched step; its ids ``[slots]`` on the host
         and, where asked, its logits ``[slots, vocab]`` (else None).
         Recorded as ``lm.wait`` (until the device has finished the
         step) and ``lm.fetch`` (the copies to the host)."""
-        ids, rows = step
+        ids, rows, stats = step
         t0 = time.perf_counter()
         ids.block_until_ready()
         t1 = time.perf_counter()
-        out = np.asarray(ids), (
-            np.asarray(rows, np.float32) if logits else None
-        )
+        # The stats ride in the copy that fetches the ids.
+        ids, stats = self._jax.device_get((ids, stats))
+        out = ids, (np.asarray(rows, np.float32) if logits else None)
         _record_fetched(t0, t1, time.perf_counter())
+        if stats is not None:
+            self._count(stats, "decode")
         return out
 
 

@@ -4,7 +4,9 @@ The image-serving tier batches fixed-shape requests through ONE
 compiled program; token serving cannot, because every request is at a
 different decode position. The classic answer (and the one the audit
 donation rule can certify) is a slot arena: a fixed
-``[slots, heads, max_len, head_dim]`` k/v slab per layer, allocated
+slab per layer whose leading axis is the slot (the model says what a
+slot holds: ``[slots, heads, max_len, head_dim]`` k and v, or one
+``[slots, max_len, width]`` latent row a token), allocated
 once at boot, DONATED through every decode step so XLA aliases it
 in-place — zero per-token cache copies, no per-request allocation, no
 shape churn, one compiled program for the life of the server.
@@ -14,9 +16,11 @@ entrypoints (donation + collective ceilings + program hashes pinned
 like the other production programs):
 
 ``slot_decode``
-    One token for EVERY slot at once — ``jax.vmap`` of the single-
-    sequence cached decode over the slot axis with a per-slot ``pos``
-    vector. Inactive slots decode garbage at position 0; the mask
+    One token for EVERY slot at once with a per-slot ``pos`` vector
+    (the model's ``decode_slots``: a ``jax.vmap`` of the one-sequence
+    cached decode, or one batched call where an expert layer has to see
+    every slot's token to route them). Inactive slots decode garbage
+    at position 0; the mask
     (``arange(max_len) <= pos``) never lets any slot read another
     slot's rows, and a freshly allocated slot is overwritten wholesale
     by ``write_slot`` before its first real step, so the garbage is
@@ -45,31 +49,26 @@ import threading
 import jax
 import jax.numpy as jnp
 
-from ...models.transformer import TransformerLM
+Arena = tuple  # per layer, a dict of slabs whose leading axis is the slot
 
-Arena = tuple  # tuple per layer of {"k": [slots,h,max_len,d], "v": ...}
+# What a served model gives (``models.transformer.TransformerLM``,
+# ``models.mla_moe.MlaMoeLM``): the model, not this module, says what
+# one slot's cache is and how a step reads it.
+#
+#   init_cache(slots, max_len)           the cache for slots x max_len
+#   prefill_cache(variables, tokens[1, bucket], cache, n_real)
+#                                        -> (logits, stats, cache)
+#   decode_slots(variables, tokens[slots], cache, pos[slots])
+#                                        -> (logits, stats, cache)
+#   serving_variables(variables)         each leaf at its served width
+#
+# ``stats`` is None or one small int32 array the model's own counters
+# are fed from.
 
 
-def make_arena(model: TransformerLM, slots: int, max_len: int) -> Arena:
-    """Allocate the slot arena: one k/v slab per layer.
-
-    ``max_len`` may be smaller than ``model.max_seq`` — the attention
-    mask and the cache writes both derive their length from the cache's
-    own shape, so a short arena is a working (cheaper) cache.
-    """
-    if max_len > model.max_seq:
-        raise ValueError(
-            f"arena max_len {max_len} > model max_seq {model.max_seq}"
-        )
-    head_dim = model.dim // model.num_heads
-    shape = (slots, model.num_heads, max_len, head_dim)
-    return tuple(
-        {
-            "k": jnp.zeros(shape, dtype=model.dtype),
-            "v": jnp.zeros(shape, dtype=model.dtype),
-        }
-        for _ in range(model.num_layers)
-    )
+def make_arena(model, slots: int, max_len: int) -> Arena:
+    """Allocate the slot arena: whatever the model keeps a slot a layer."""
+    return model.init_cache(slots, max_len)
 
 
 def slot_decode(model, variables, tokens, arena, pos, override=None):
@@ -83,11 +82,12 @@ def slot_decode(model, variables, tokens, arena, pos, override=None):
     the slots whose token the host knows better (one admitted since,
     whose first token came from its prefill).
 
-    Returns ``(logits [slots, vocab], ids [slots] int32, new_arena)``:
-    ``ids`` is the greedy choice of every slot, ``argmax`` of the
-    float32 logits with the first index winning a tie, as
-    ``np.argmax`` of the same row. The arena is aliased in-place when
-    jitted with ``donate_argnums=(3,)``.
+    Returns ``(logits [slots, vocab], ids [slots] int32, stats,
+    new_arena)``: ``ids`` is the greedy choice of every slot, ``argmax``
+    of the float32 logits with the first index winning a tie, as
+    ``np.argmax`` of the same row; ``stats`` is the model's (None where
+    it counts nothing). The arena is aliased in-place when jitted with
+    ``donate_argnums=(3,)``.
 
     A row's ``pos`` is not checked: ``dynamic_update_slice`` clamps a
     write at or past ``max_len`` into the slot's own last row, so a row
@@ -95,42 +95,37 @@ def slot_decode(model, variables, tokens, arena, pos, override=None):
     """
     if override is not None:
         tokens = jnp.where(override >= 0, override, tokens)
-
-    def one(tok, slot_cache, p):
-        cache1 = jax.tree_util.tree_map(lambda a: a[None], slot_cache)
-        logits, new_cache = model.apply(
-            variables, tok[None, None], cache=cache1, pos=p
-        )
-        return logits[0], jax.tree_util.tree_map(lambda a: a[0], new_cache)
-
-    logits, arena = jax.vmap(one, in_axes=(0, 0, 0))(tokens, arena, pos)
+    logits, stats, arena = model.decode_slots(variables, tokens, arena, pos)
     ids = jnp.argmax(logits.astype(jnp.float32), axis=-1).astype(jnp.int32)
-    return logits, ids, arena
+    return logits, ids, stats, arena
 
 
-def prefill_bucket(model, variables, tokens, cache):
+def prefill_bucket(model, variables, tokens, cache, n_real=None):
     """Prefill one bucket-padded prompt into a single-sequence cache.
 
     ``tokens`` is ``[1, bucket]`` int32; compiled once per bucket
-    length. Returns ``(logits, cache)`` where logits is
-    ``[1, bucket, vocab]`` (or ``[1, vocab]`` for the degenerate
-    1-token bucket). Positions past the real prompt hold padding k/v —
-    never attended (causal mask) and overwritten by later decode steps
-    before the position pointer passes them.
+    length. ``n_real`` (int32 scalar) is the count of real tokens, for
+    a model that returns the last real row only. Returns ``(logits,
+    stats, cache)`` where logits is the model's: every row
+    ``[1, bucket, vocab]`` (``[1, vocab]`` for the degenerate 1-token
+    bucket), or ``[1, vocab]`` of the last real one. Positions past the
+    real prompt hold padding rows, never attended (causal mask) and
+    overwritten by later decode steps before the position pointer
+    passes them.
     """
-    return model.apply(variables, tokens, cache=cache, pos=0)
+    return model.prefill_cache(variables, tokens, cache, n_real)
 
 
 def write_slot(arena, rows, slot):
     """Scatter a prefilled single-sequence cache into arena ``slot``.
 
-    ``rows`` leaves are ``[1, heads, len, head_dim]``; ``slot`` is an
+    ``rows`` is the arena's tree with ``[1, ...]`` leaves; ``slot`` is an
     int32 scalar. Donating ``arena`` makes this an in-place aliased
     update in the lowered program.
     """
     return jax.tree_util.tree_map(
         lambda a, r: jax.lax.dynamic_update_slice(
-            a, r.astype(a.dtype), (slot, 0, 0, 0)
+            a, r.astype(a.dtype), (slot,) + (0,) * (a.ndim - 1)
         ),
         arena,
         rows,

@@ -92,8 +92,12 @@ KNOWN_METRICS: dict[str, str] = {
     "slo_alerts_firing": "gauge",
     "train_step_window_seconds": "window",
     # -- LM token serving --------------------------------------------------
+    "lm_cache_bytes": "gauge",
     "lm_decode_steps_total": "counter",
     "lm_inter_token_window_seconds": "window",
+    "lm_moe_assignments_total": "counter",
+    "lm_moe_expert_assignments_total": "counter",
+    "lm_moe_experts_touched_total": "counter",
     "lm_prefill_tokens_total": "counter",
     "lm_queue_depth": "gauge",
     "lm_retired_total": "counter",

@@ -104,6 +104,24 @@ def test_flash_attention_prefill_lengths_compile(one_chip, no_compile_cache,
     )
 
 
+def test_flash_attention_compiles_at_the_document_cells_prefill(
+        one_chip, no_compile_cache):
+    """32 heads of 64 + 64 = 128 over the largest bucket of the
+    latent-attention model's cell (8,192): the expanded path's call."""
+    import jax
+    import jax.numpy as jnp
+
+    from dss_ml_at_scale_tpu.ops.flash_attention import flash_attention
+
+    x = jax.ShapeDtypeStruct((1, 32, 8192, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    _compile(
+        lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                        interpret=False),
+        x, x, x,
+    )
+
+
 @pytest.mark.parametrize("hw,k,n", BN_STAGES)
 @pytest.mark.parametrize("with_res", [False, True], ids=["plain", "residual"])
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])

@@ -795,7 +795,7 @@ def _decode_once(model, variables, tokens, pos, override=None, arena=None):
             jnp.asarray(pos, jnp.int32)]
     if override is not None:
         args.append(jnp.asarray(override, jnp.int32))
-    logits, ids, arena = jax.jit(kvcache.slot_decode, static_argnums=0)(
+    logits, ids, _, arena = jax.jit(kvcache.slot_decode, static_argnums=0)(
         model, variables, *args)
     return np.asarray(logits), np.asarray(ids), arena
 

@@ -81,7 +81,7 @@ def _slot_decode(model, variables):
         kvcache.make_arena(model, SLOTS, MAX_LEN))
     tokens = jnp.asarray(rng.integers(1, 64, SLOTS), jnp.int32)
     pos = jnp.asarray([5, 0, 17], jnp.int32)
-    logits, _, _ = jax.jit(kvcache.slot_decode, static_argnums=0)(
+    logits, _, _, _ = jax.jit(kvcache.slot_decode, static_argnums=0)(
         model, variables, tokens, arena, pos)
     return logits
 
@@ -91,7 +91,7 @@ def _prefill(bucket):
         tokens = jnp.asarray(
             np.random.default_rng(bucket).integers(1, 64, (1, bucket)),
             jnp.int32)
-        logits, _ = jax.jit(kvcache.prefill_bucket, static_argnums=0)(
+        logits, _, _ = jax.jit(kvcache.prefill_bucket, static_argnums=0)(
             model, variables, tokens, kvcache.make_arena(model, 1, MAX_LEN))
         return logits
     return run
