@@ -280,7 +280,7 @@ def _served_lm(mesh):
 
 
 def slot_decode_lm(mesh) -> ProgramSpec:
-    """The continuous-batching serving step: vmapped decode over the
+    """The continuous-batching serving step: one batched decode over the
     slot arena with a PER-SLOT position vector, as the engine calls it:
     the tokens are the previous step's ids merged on the device with
     the host's overrides, and the greedy ids come back beside the

@@ -13,7 +13,9 @@ shape):
   per-expert capacity ``C = ceil(tokens/E · capacity_factor)`` bounds the
   work per expert so every shape stays static. Tokens over capacity fall
   through the residual (their combine weight is zero) — standard Switch
-  semantics, never a runtime error.
+  semantics, never a runtime error. A one-token decode step keeps every
+  token (``keep_all``): capacity there is the batch, as it was 1 for a
+  sequence decoded alone.
 - Dispatch and combine are einsums against a ``[tokens, E, C]`` one-hot
   tensor. On an expert-sharded mesh the ``ecd`` operands are sharded on
   ``e`` while token operands are batch-sharded, so GSPMD lowers the two
@@ -67,13 +69,21 @@ class MoEMLP(nn.Module):
     router_noise: float = 0.0  # jitter std at train time (0 = deterministic)
 
     @nn.compact
-    def __call__(self, x, *, deterministic: bool = True):
+    def __call__(self, x, *, deterministic: bool = True,
+                 keep_all: bool = False):
+        """``keep_all``: room in every expert for every token, so none
+        is dropped and a token's output is its own whoever rides beside
+        it. A cached decode step asks for it: its tokens are one a
+        sequence (one a serving slot, idle slots among them), and a
+        queue over the batch axis would let a neighbour take a
+        sequence's place."""
         b, s, d = x.shape
         e = self.num_experts
         h = self.mlp_ratio * d
         tokens = x.reshape(b * s, d)
         t = tokens.shape[0]
-        capacity = max(1, math.ceil(t * self.capacity_factor / e))
+        capacity = t if keep_all else max(
+            1, math.ceil(t * self.capacity_factor / e))
 
         # -- router (f32: softmax over experts must not run in bf16) ------
         logits = nn.Dense(

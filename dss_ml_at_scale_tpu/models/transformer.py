@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from ..ops.decode_attention import decode_attention, rows_fetched
 from ..ops.flash_attention import attention_reference, flash_attention
 
 
@@ -106,30 +107,26 @@ class TransformerBlock(nn.Module):
 
             if cache is not None:
                 # Both cached modes write this call's k/v into the cache
-                # slab at ``pos``; they differ only in how attn is computed.
-                k_cache = jax.lax.dynamic_update_slice_in_dim(
-                    cache["k"], heads(k), pos, axis=2
-                )
-                v_cache = jax.lax.dynamic_update_slice_in_dim(
-                    cache["v"], heads(v), pos, axis=2
-                )
+                # slab at ``pos`` (one position for the batch, or one a
+                # slot); they differ only in how attn is computed.
+                def write(slab, rows):
+                    if jnp.ndim(pos) == 0:
+                        return jax.lax.dynamic_update_slice_in_dim(
+                            slab, rows, pos, axis=2)
+                    return jax.vmap(
+                        lambda a, r, p: jax.lax.dynamic_update_slice_in_dim(
+                            a, r, p, axis=1)
+                    )(slab, rows, pos)
+
+                k_cache = write(cache["k"], heads(k))
+                v_cache = write(cache["v"], heads(v))
                 new_cache = {"k": k_cache, "v": v_cache}
                 if s == 1:
-                    # Decode step: attend the single query over the cache
-                    # with a <= pos mask. Plain einsums — at q_len 1 there
-                    # is nothing for a kernel to tile.
-                    scores = jnp.einsum(
-                        "bhqd,bhkd->bhqk", heads(q), k_cache,
-                        preferred_element_type=jnp.float32,
-                    ) / jnp.sqrt(head_dim).astype(jnp.float32)
-                    mask = jnp.arange(k_cache.shape[2]) <= pos
-                    scores = jnp.where(
-                        mask[None, None, None, :], scores, -1e30
-                    )
-                    probs = jax.nn.softmax(scores, axis=-1)
-                    attn = jnp.einsum(
-                        "bhqk,bhkd->bhqd", probs, v_cache.astype(jnp.float32)
-                    ).astype(self.dtype)
+                    # Decode step: the one query over the slot's rows
+                    # ``0..pos``, the row just written among them.
+                    attn = decode_attention(
+                        heads(q)[:, :, 0], k_cache, v_cache, pos
+                    )[:, :, None]
                 else:
                     # Prefill (pos == 0, enforced by TransformerLM): the
                     # whole prompt in ONE causal parallel pass — the
@@ -157,7 +154,10 @@ class TransformerBlock(nn.Module):
                     axis_name=self.expert_axis,
                     router_noise=self.router_noise,
                     name="moe",
-                )(h, deterministic=deterministic)
+                )(h, deterministic=deterministic,
+                  # a decode step: one token a sequence, each routed as
+                  # if alone (no slot's neighbours decide its experts)
+                  keep_all=cache is not None and s == 1)
             else:
                 h = nn.Dense(
                     self.mlp_ratio * dim, dtype=self.dtype, name="mlp_up"
@@ -237,7 +237,10 @@ class TransformerLM(nn.Module):
             (self.max_seq, self.dim),
         )
         with jax.named_scope("embed"):
-            if decoding:
+            if decoding and jnp.ndim(pos):
+                # One position a slot, clamped as the slice below is.
+                pos_emb = pos_table.at[pos].get(mode="clip")[:, None]
+            elif decoding:
                 pos_emb = jax.lax.dynamic_slice_in_dim(pos_table, pos, s)[None]
             else:
                 pos_emb = pos_table[None, :s]
@@ -319,20 +322,24 @@ class TransformerLM(nn.Module):
 
     @nn.nowrap
     def decode_slots(self, variables, tokens, cache, pos):
-        """One token for every slot: ``jax.vmap`` of the one-sequence
-        cached decode over the slot axis with a per-slot ``pos``.
-        Returns (logits ``[slots, vocab]``, no stats, cache)."""
-
-        def one(tok, slot_cache, p):
-            cache1 = jax.tree_util.tree_map(lambda a: a[None], slot_cache)
-            logits, new_cache = self.apply(
-                variables, tok[None, None], cache=cache1, pos=p
-            )
-            return logits[0], jax.tree_util.tree_map(
-                lambda a: a[0], new_cache)
-
-        logits, cache = jax.vmap(one, in_axes=(0, 0, 0))(tokens, cache, pos)
+        """One token for every slot: one batched call of the cached
+        decode with the per-slot ``pos`` vector (each slot's k/v row
+        written at its own position, each slot's attention over its own
+        rows ``0..pos``). Returns (logits ``[slots, vocab]``, no stats,
+        cache)."""
+        logits, cache = self.apply(
+            variables, tokens[:, None], cache=cache, pos=pos
+        )
         return logits, None, cache
+
+    @nn.nowrap
+    def decode_rows_read(self, pos, cache) -> int:
+        """Cache rows a layer that one ``decode_slots`` over ``cache``
+        at ``pos`` (host ``[slots]``) fetches: what the engine's
+        ``lm_decode_cache_rows_total{kind="read"}`` counts. The slab's
+        own shape and dtype decide, as they do in the op."""
+        slab = cache[0]["k"]
+        return rows_fetched(pos, slab.shape, slab.dtype)
 
 
 # The leaves the model multiplies in float32 whatever its ``dtype``, by

@@ -4,8 +4,10 @@ TPU-native replacement for the statsmodels surface the reference
 exercises (SURVEY.md §2.2 X10): SARIMAX state-space ML fit, Holt-Winters
 exponential smoothing, ARMA sample generation, plus the vmappable
 Nelder-Mead optimizer that statsmodels' ``fit(method='nm')`` maps to.
-The deep-learning hot path adds the Pallas flash-attention kernel and
-the fused BN+act custom VJP (``fused_norm``) that cuts ResNet HBM bytes.
+The deep-learning hot path adds the Pallas flash-attention kernel, the
+length-aware decode attention over a slot arena (``decode_attention``,
+imported from its own module) and the fused BN+act custom VJP
+(``fused_norm``) that cuts ResNet HBM bytes.
 
 Everything here is pure JAX (``lax.scan`` / ``lax.while_loop``), built to
 ``vmap`` across thousands of SKU groups at once — one sharded batched fit

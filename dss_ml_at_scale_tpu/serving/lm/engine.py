@@ -258,6 +258,18 @@ class TransformerDecoder:
         ).labels(kind=model.cache_kind).set(
             sum(a.nbytes for a in jax.tree_util.tree_leaves(self._arena))
         )
+        # How far a length-aware decode engages: the rows a step's
+        # attention fetches a layer against the arena's, for a model that
+        # says what it fetches (``decode_rows_read``).
+        self._decode_rows_read = getattr(model, "decode_rows_read", None)
+        cache_rows = telemetry.counter(
+            "lm_decode_cache_rows_total",
+            "cache rows a layer over the decode steps dispatched: read by "
+            "the step's attention, and held by the arena",
+            labels=("kind",),
+        )
+        self._cache_rows_read = cache_rows.labels(kind="read")
+        self._cache_rows_arena = cache_rows.labels(kind="arena")
         # The last dispatched step's greedy ids, on the device: the next
         # step's tokens wherever the host does not override them.
         self._ids = jnp.zeros(self.slots, jnp.int32)
@@ -349,6 +361,10 @@ class TransformerDecoder:
         )
         self._ids = ids
         _record_dispatched(t0, time.perf_counter())
+        if self._decode_rows_read is not None:
+            self._cache_rows_read.inc(
+                self._decode_rows_read(pos, self._arena))
+            self._cache_rows_arena.inc(self.slots * self.max_len)
         return ids, logits, stats
 
     def fetch(self, step, *, logits: bool = False):

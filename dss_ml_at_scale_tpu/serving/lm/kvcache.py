@@ -17,14 +17,17 @@ like the other production programs):
 
 ``slot_decode``
     One token for EVERY slot at once with a per-slot ``pos`` vector
-    (the model's ``decode_slots``: a ``jax.vmap`` of the one-sequence
-    cached decode, or one batched call where an expert layer has to see
-    every slot's token to route them). Inactive slots decode garbage
-    at position 0; the mask
-    (``arange(max_len) <= pos``) never lets any slot read another
-    slot's rows, and a freshly allocated slot is overwritten wholesale
-    by ``write_slot`` before its first real step, so the garbage is
-    provably harmless (the bitwise-parity test in
+    (the model's ``decode_slots``: one batched call, each slot's new
+    k/v row written at its own position). A slot's attention reads its
+    own rows ``0..pos`` and nothing else: ``TransformerLM`` through
+    ``ops.decode_attention``, which fetches only the blocks of the
+    arena that hold those rows and masks the tail of the last one (the
+    whole slab under ``arange(max_len) <= pos`` where the shapes do not
+    tile), the latent model under that mask over its latent rows.
+    Inactive slots decode garbage at position 0; no slot ever reads
+    another slot's rows, and a freshly allocated slot is overwritten
+    wholesale by ``write_slot`` before its first real step, so the
+    garbage is provably harmless (the bitwise-parity test in
     ``tests/test_lm_serving.py`` holds the proof).
 
 ``prefill_bucket``
@@ -61,6 +64,8 @@ Arena = tuple  # per layer, a dict of slabs whose leading axis is the slot
 #   decode_slots(variables, tokens[slots], cache, pos[slots])
 #                                        -> (logits, stats, cache)
 #   serving_variables(variables)         each leaf at its served width
+#   decode_rows_read(pos[slots], cache) -> int     (optional) the cache
+#                                        rows a layer such a step fetches
 #
 # ``stats`` is None or one small int32 array the model's own counters
 # are fed from.

@@ -93,6 +93,7 @@ KNOWN_METRICS: dict[str, str] = {
     "train_step_window_seconds": "window",
     # -- LM token serving --------------------------------------------------
     "lm_cache_bytes": "gauge",
+    "lm_decode_cache_rows_total": "counter",
     "lm_decode_steps_total": "counter",
     "lm_inter_token_window_seconds": "window",
     "lm_moe_assignments_total": "counter",

@@ -1,4 +1,4 @@
-"""The two Pallas kernels compile for the real chip, at the real widths.
+"""The Pallas kernels compile for the real chip, at the real widths.
 
 The TPU's compiler is installed with jaxlib and compiles for a chip that
 is described, not attached: what it refuses here (a block not aligned to
@@ -18,6 +18,7 @@ import os
 import pytest
 
 FLASH_SHAPE = (8, 8, 2048, 128)  # batch, heads, seq, head_dim (chip_smoke's)
+DECODE_SLAB = (16, 16, 2048, 128)  # slots, heads, max_len, head_dim (chat cell)
 BN_BATCH = 212
 BN_STAGES = [(56, 64, 256), (28, 128, 512), (14, 256, 1024), (7, 512, 2048)]
 
@@ -165,3 +166,67 @@ def test_interpret_mode_is_refused_on_a_tpu_backend(monkeypatch):
     assert _pallas.resolve_interpret(False) is False
     with pytest.raises(ValueError, match="interpret mode"):
         _pallas.resolve_interpret(True)
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_decode_attention_compiles_at_the_chat_cells_arena(
+        one_chip, no_compile_cache, dtype):
+    """One layer's k and v slabs as the serving cell holds them, one query
+    a slot, a per-slot ``pos``: the kernel form, compiled (the function
+    itself would take interpret mode under this CPU backend)."""
+    import jax
+    import jax.numpy as jnp
+
+    from dss_ml_at_scale_tpu.ops import decode_attention as da
+
+    assert da.tiles(DECODE_SLAB, dtype)
+    slots, heads, _, head_dim = DECODE_SLAB
+    q = jax.ShapeDtypeStruct((slots, heads, head_dim), dtype,
+                             sharding=one_chip)
+    slab = jax.ShapeDtypeStruct(DECODE_SLAB, dtype, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    compiled = _compile(
+        lambda q, k, v, pos: da._blockwise(q, k, v, pos, interpret=False),
+        q, slab, slab, pos,
+    )
+    # No copy of a slab, no widened twin: the kernel reads the arena.
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_slot_decode_keeps_the_arena_in_place_around_the_kernel(
+        one_chip, no_compile_cache, monkeypatch):
+    """The whole decode program with the kernel in it: the donated arena
+    is aliased to the returned one, rows scattered in place, no slab
+    copied on the way into the custom call."""
+    import jax
+    import jax.numpy as jnp
+
+    from dss_ml_at_scale_tpu.models import TransformerLM
+    from dss_ml_at_scale_tpu.ops import decode_attention as da
+    from dss_ml_at_scale_tpu.serving.lm import kvcache
+
+    monkeypatch.setattr(da, "resolve_interpret", lambda _: False)
+    slots, max_len = 8, 4 * da.BLOCK
+    model = TransformerLM(vocab_size=512, dim=256, num_heads=2,
+                          num_layers=2, max_seq=max_len)
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    variables = described(jax.eval_shape(
+        lambda: model.serving_variables(model.init(
+            jax.random.key(0), jnp.zeros((1, 8), jnp.int32)))))
+    arena = described(jax.eval_shape(
+        lambda: kvcache.make_arena(model, slots, max_len)))
+    vec = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(
+        kvcache.slot_decode, static_argnums=0, donate_argnums=(3,)
+    ).lower(model, variables, vec, arena, vec, vec).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= model.num_layers
+    slabs = [a.size * a.dtype.itemsize
+             for a in jax.tree_util.tree_leaves(arena)]
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= sum(slabs)
+    assert memory.temp_size_in_bytes < min(slabs)

@@ -37,6 +37,7 @@ NEW_METRICS = {
     "reader_stage_seconds_total": "counter", "reader_rows_total": "counter",
     "reader_workers": "gauge", "lm_prefill_tokens_total": "counter",
     "lm_decode_steps_total": "counter",
+    "lm_decode_cache_rows_total": "counter",
 }
 REMOVED_METRICS = ("trace_spans_total", "lm_decode_step_seconds",
                    "lm_prefill_seconds")
