@@ -126,7 +126,8 @@ def test_flash_attention_compiles_at_the_serving_buckets(
     )
 
     block_q, block_k = blocks(seq, seq)
-    assert block_k == min(512, seq) and seq % block_q == 0
+    assert block_k == min(1024 if seq >= 4096 else 512, seq)
+    assert seq % block_q == 0
     plan = block_plan(seq, seq, block_q, block_k, causal=True)
     assert plan.masked <= plan.visited <= plan.grid
     x = jax.ShapeDtypeStruct((1, heads, seq, 128), jnp.bfloat16,

@@ -1,12 +1,13 @@
 """Flash attention kernel vs the XLA reference (Pallas interpret on CPU),
-and the causal block schedule (PR 35) vs the full-rectangle kernel it
-replaced, bit for bit."""
+and the causal block schedule and the row sub-tiles vs the
+full-rectangle kernel they replaced, bit for bit."""
 
 import functools
 import itertools
 import math
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -16,6 +17,7 @@ from jax.experimental.pallas import tpu as pltpu
 from dss_ml_at_scale_tpu.ops import attention_reference, flash_attention
 from dss_ml_at_scale_tpu.ops.flash_attention import (
     _NEG_INF,
+    TURN_Q,
     _flash_forward,
     block_plan,
     blocks,
@@ -238,6 +240,93 @@ def test_non_causal_path_is_unchanged(rng, ratio):
                          interpret=True)
     want = _rectangle(q, k, v, causal=False, block_q=bq, block_k=_BK)
     np.testing.assert_array_equal(got, want)
+
+
+# -- a q-block walked in row sub-tiles ------------------------------------------
+# A sub-tile is a quarter of the k-block's rows: 64 at ``block_k`` 256.
+
+
+@pytest.mark.parametrize("sq, sk, bq, causal", [
+    (1024, 1024, 512, True),      # a q-block of eight sub-tiles, one turn
+    (2048, 2048, 1024, True),     # two turns: the rolled loop
+    (512, 1024, 512, True),       # bottom-right offset
+    (512, 512, 512, False),       # every pair, nothing masked
+    (512, 512, 64, True),         # a q-block of exactly one sub-tile
+], ids=["causal", "two_turns", "bottom_right", "non_causal", "one_sub_tile"])
+def test_sub_tiles_match_reference_and_full_rectangle(rng, sq, sk, bq,
+                                                      causal):
+    q, k, v = _bits(rng, sq, sk, bh=1)
+    got = _flash_forward(q, k, v, causal=causal, block_q=bq, block_k=256,
+                         interpret=True)
+    np.testing.assert_allclose(
+        got, attention_reference(q, k, v, causal=causal), atol=2e-6)
+    want = _rectangle(q, k, v, causal=causal, block_q=bq, block_k=256)
+    np.testing.assert_array_equal(got, want)
+
+
+def test_sub_tiles_before_a_k_blocks_first_key_leave_their_rows(rng):
+    """q-blocks of eight sub-tiles over k-blocks of half their rows: on
+    the pair (i, 2i + 1) the q-block's first four sub-tiles lie wholly
+    before the k-block's first key, so their rows' update is alpha = 1,
+    p = 0."""
+    sq, bq, bk = 1024, 512, 256
+    plan = block_plan(sq, sq, bq, bk, causal=True)
+    dead = [(i, j) for i, j, st in zip(plan.q_block, plan.k_block,
+                                       plan.straddles)
+            if st and j * bk - i * bq >= bk // 4]
+    assert dead == [(i, 2 * i + 1) for i in range(sq // bq)]
+    q, k, v = _bits(rng, sq, sq)
+    got = _flash_forward(q, k, v, causal=True, block_q=bq, block_k=bk,
+                         interpret=True)
+    ref = attention_reference(q, k, v, causal=True)
+    for i in range(sq // bq):  # the rows of those sub-tiles
+        rows = slice(i * bq, i * bq + bk)
+        np.testing.assert_allclose(got[:, rows], ref[:, rows], atol=2e-6)
+    np.testing.assert_array_equal(
+        got, _rectangle(q, k, v, causal=True, block_q=bq, block_k=bk))
+
+
+def _sub_jaxprs(eqn):
+    for param in eqn.params.values():
+        for x in param if isinstance(param, (tuple, list)) else (param,):
+            if isinstance(x, jax.extend.core.ClosedJaxpr):
+                yield x.jaxpr
+            elif isinstance(x, jax.extend.core.Jaxpr):
+                yield x
+
+
+def _kernel_jaxpr(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            return eqn.params["jaxpr"]
+        for sub in _sub_jaxprs(eqn):
+            found = _kernel_jaxpr(sub)
+            if found is not None:
+                return found
+    return None
+
+
+def _equations(jaxpr):
+    return sum(1 + sum(_equations(s) for s in _sub_jaxprs(e))
+               for e in jaxpr.eqns)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_kernel_body_is_traced_once_whatever_the_sub_tile_count(causal):
+    """The walk over a q-block's sub-tiles is a loop, not a Python
+    unroll: the kernel's jaxpr holds as many equations at a q-block of
+    two turns as at one of eight, so set-up does not grow with the
+    q-block."""
+    x = jax.ShapeDtypeStruct((1, 2, 8 * TURN_Q, 128), jnp.bfloat16)
+
+    def size(bq):
+        traced = jax.make_jaxpr(lambda q, k, v: flash_attention(
+            q, k, v, causal=causal, block_q=bq))(x, x, x)
+        kernel = _kernel_jaxpr(traced.jaxpr)
+        assert kernel is not None
+        return _equations(kernel)
+
+    assert size(2 * TURN_Q) == size(8 * TURN_Q)
 
 
 def _brute_force(sq, sk, bq, bk, causal):
