@@ -417,6 +417,79 @@ def prefill_mla_moe(mesh) -> ProgramSpec:
     )
 
 
+def _served_jamba(mesh):
+    """A small hybrid state-space model at the dtype ``dsst serve-lm
+    --model-config`` serves (bfloat16): one period of four layers, the
+    third attention (one key/value head under four query heads), the
+    others Mamba, with the tree its decoder holds."""
+    import jax
+
+    from ...models.jamba import JambaLM
+
+    model = JambaLM(
+        vocab_size=128, hidden_size=64, num_layers=4,
+        num_attention_heads=4, num_key_value_heads=1, intermediate_size=96,
+        d_state=16, d_conv=4, expand=2, dt_rank=8, attn_layer_period=4,
+        attn_layer_offset=2, attention="reference",
+    )
+    return model, jax.device_put(
+        model.init(jax.random.key(0)), _replicated(mesh)
+    )
+
+
+def slot_decode_jamba(mesh) -> ProgramSpec:
+    """The serving step of the hybrid model: one batched call that
+    advances every slot's recurrent state and writes its key/value row,
+    the arena donated as in ``slot_decode.lm``."""
+    import jax
+    import jax.numpy as jnp
+
+    from ...serving.lm import kvcache
+
+    model, variables = _served_jamba(mesh)
+    replicated = _replicated(mesh)
+    arena = jax.device_put(kvcache.make_arena(model, 4, 32), replicated)
+    zeros = jax.device_put(jnp.zeros((4,), jnp.int32), replicated)
+    return ProgramSpec(
+        name="slot_decode.jamba",
+        fn=kvcache.slot_decode,
+        args=(model, variables, zeros, arena, zeros, zeros),
+        jit_kwargs={
+            "static_argnums": 0,
+            "donate_argnums": (3,),
+            "out_shardings": replicated,
+        },
+        expect_donated=(3,),
+    )
+
+
+def prefill_jamba(mesh) -> ProgramSpec:
+    """One bucketed prefill of the hybrid model into a donated one-slot
+    cache: the scan stops at the count of real tokens, an argument, and
+    one logits row comes back."""
+    import jax
+    import jax.numpy as jnp
+
+    from ...serving.lm import kvcache
+
+    model, variables = _served_jamba(mesh)
+    replicated = _replicated(mesh)
+    cache = jax.device_put(kvcache.make_arena(model, 1, 32), replicated)
+    tokens = jax.device_put(jnp.zeros((1, 16), jnp.int32), replicated)
+    n_real = jax.device_put(jnp.int32(9), replicated)
+    return ProgramSpec(
+        name="prefill.jamba",
+        fn=kvcache.prefill_bucket,
+        args=(model, variables, tokens, cache, n_real),
+        jit_kwargs={
+            "static_argnums": 0,
+            "donate_argnums": (3,),
+            "out_shardings": replicated,
+        },
+        expect_donated=(3,),
+    )
+
+
 def serving_score(mesh) -> ProgramSpec:
     import jax
     import numpy as np
@@ -599,6 +672,8 @@ _BUILDERS: dict[str, Callable] = {
     "prefill.lm": prefill_lm,
     "slot_decode.mla_moe": slot_decode_mla_moe,
     "prefill.mla_moe": prefill_mla_moe,
+    "slot_decode.jamba": slot_decode_jamba,
+    "prefill.jamba": prefill_jamba,
     "serving.score": serving_score,
     "ops.fused_matmul.grad": fused_matmul_grad,
     "ops.fused_norm.grad": fused_norm_grad,

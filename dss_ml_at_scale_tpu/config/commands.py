@@ -1895,12 +1895,14 @@ def register_serve_lm(sub: argparse._SubParsersAction) -> None:
                     default="reference")
     sv.add_argument(
         "--model-config", default=None, metavar="JSON",
-        help="architecture file of a latent-attention expert model "
-        "(models/mla_moe.py: the published config.json keys, with "
-        "num_layers, n_routed_experts and vocab_size as held here, "
-        "router_width and expert_offset beside them); served with "
-        "random weights through the same decoder and engine, in place "
-        "of the TransformerLM that --vocab/--dim/--heads/--layers size",
+        help="architecture file with the published config.json keys: "
+        "model_type jamba builds the hybrid state-space model "
+        "(models/jamba.py), any other a latent-attention expert model "
+        "(models/mla_moe.py, with num_layers, n_routed_experts and "
+        "vocab_size as held here, router_width and expert_offset beside "
+        "them); served with random weights through the same decoder and "
+        "engine, in place of the TransformerLM that "
+        "--vocab/--dim/--heads/--layers size",
     )
     sv.add_argument("--seed", type=int, default=0,
                     help="init seed for the random-weight TransformerLM "
@@ -1953,9 +1955,14 @@ def _cmd_serve_lm(args: argparse.Namespace) -> int:
         # Neither tree gets a name here: the decoder keeps its own copy
         # at the widths it multiplies in, and a wider one is freed.
         if args.model_config:
-            model = MlaMoeLM.from_config(
-                args.model_config, attention=args.attention
-            )
+            with open(args.model_config) as f:
+                arch = json.load(f)
+            if arch.get("model_type") == "jamba":
+                from ..models.jamba import JambaLM
+
+                model = JambaLM.from_config(arch, attention=args.attention)
+            else:
+                model = MlaMoeLM.from_config(arch, attention=args.attention)
             variables = model.init(jax.random.key(args.seed))
         else:
             model = TransformerLM(

@@ -231,6 +231,19 @@ def _record_fetched(t0: float, t1: float, t2: float) -> None:
     log.record("lm.fetch", wall + t1, t2 - t1)
 
 
+def cache_bytes_by_kind(model, arena) -> dict:
+    """Bytes of ``arena`` by the kind of state a slot holds: the model's
+    own split where it keeps several kinds, else all of it under its one
+    ``cache_kind``."""
+    import jax
+
+    split = getattr(model, "cache_bytes_by_kind", None)
+    if split is not None:
+        return split(arena)
+    return {model.cache_kind: sum(
+        a.nbytes for a in jax.tree_util.tree_leaves(arena))}
+
+
 class TransformerDecoder:
     """The real backend: audited slot-decode/prefill/scatter programs.
 
@@ -280,13 +293,13 @@ class TransformerDecoder:
         self.vocab_size = model.vocab_size
         self.waited_s = 0.0
         self._arena = kvcache.make_arena(model, self.slots, self.max_len)
-        telemetry.gauge(
+        cache_bytes = telemetry.gauge(
             "lm_cache_bytes",
             "bytes of the slot arena, by the kind of state a slot holds",
             labels=("kind",),
-        ).labels(kind=model.cache_kind).set(
-            sum(a.nbytes for a in jax.tree_util.tree_leaves(self._arena))
         )
+        for kind, nbytes in cache_bytes_by_kind(model, self._arena).items():
+            cache_bytes.labels(kind=kind).set(nbytes)
         # How far a length-aware decode engages: the rows a step's
         # attention fetches a layer against the arena's, for a model that
         # says what it fetches (``decode_rows_read``).
@@ -305,7 +318,9 @@ class TransformerDecoder:
         # ONE prefill scratch cache, recycled: the returned (donated-in)
         # buffers become the next call's input. Stale rows past the real
         # prompt are never attended and are overwritten before the
-        # position pointer reaches them, so no re-zeroing is needed.
+        # position pointer reaches them, so no re-zeroing is needed; a
+        # recurrent state is never read from it (the model's prefill
+        # starts it from zero).
         self._scratch = kvcache.make_arena(model, 1, self.max_len)
         self._step_fn = jax.jit(
             kvcache.slot_decode, static_argnums=0, donate_argnums=(3,)

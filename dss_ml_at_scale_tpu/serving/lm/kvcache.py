@@ -5,8 +5,8 @@ compiled program; token serving cannot, because every request is at a
 different decode position. The classic answer (and the one the audit
 donation rule can certify) is a slot arena: a fixed
 slab per layer whose leading axis is the slot (the model says what a
-slot holds: ``[slots, heads, max_len, head_dim]`` k and v, or one
-``[slots, max_len, width]`` latent row a token), allocated
+slot holds: ``[slots, heads, max_len, head_dim]`` k and v, a latent
+row a token, or a recurrent state of fixed size), allocated
 once at boot, DONATED through every decode step so XLA aliases it
 in-place — zero per-token cache copies, no per-request allocation, no
 shape churn, one compiled program for the life of the server.
@@ -55,8 +55,8 @@ import jax.numpy as jnp
 Arena = tuple  # per layer, a dict of slabs whose leading axis is the slot
 
 # What a served model gives (``models.transformer.TransformerLM``,
-# ``models.mla_moe.MlaMoeLM``): the model, not this module, says what
-# one slot's cache is and how a step reads it.
+# ``models.mla_moe.MlaMoeLM``, ``models.jamba.JambaLM``): the model, not
+# this module, says what one slot's cache is and how a step reads it.
 #
 #   init_cache(slots, max_len)           the cache for slots x max_len
 #   prefill_cache(variables, tokens[1, bucket], cache, n_real)
@@ -67,8 +67,8 @@ Arena = tuple  # per layer, a dict of slabs whose leading axis is the slot
 #   decode_rows_read(pos[slots], cache) -> int     (optional) the cache
 #                                        rows a layer such a step fetches
 #
-# ``stats`` is None or one small int32 array the model's own counters
-# are fed from.
+# ``stats`` is None or one small int32 array for the model's counters;
+# ``cache_kind``, or ``cache_bytes_by_kind(cache)``, names what it holds.
 
 
 def make_arena(model, slots: int, max_len: int) -> Arena:

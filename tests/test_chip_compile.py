@@ -144,6 +144,37 @@ def test_flash_attention_compiles_at_the_serving_buckets(
     assert f"bf16[{heads},{seq},128]" in call.split("custom-call(")[0]
 
 
+@pytest.mark.parametrize("positions", [256, 512, 1024, 2048, 100])
+def test_selective_scan_compiles_at_the_reason_cells_buckets(
+        one_chip, no_compile_cache, positions):
+    """The prefill's scan at Jamba2-3B's widths (5,120 channels, 16
+    states) at each bucket of the reasoning cell, and a length that is no
+    multiple of the chunk; the op's output shape is what the benchmark's
+    reader parses from the kernel's HLO text."""
+    import jax
+    import jax.numpy as jnp
+
+    from dss_ml_at_scale_tpu.ops.selective_scan import blocks, selective_scan
+
+    chunk, _ = blocks(positions, 5120)
+    rows = -(-positions // chunk) * chunk
+
+    def shaped(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = _compile(
+        lambda u, dt, a, b, c, d, n: selective_scan(
+            u, dt, a, b, c, d, n, interpret=False),
+        shaped(positions, 5120), shaped(positions, 5120), shaped(16, 5120),
+        shaped(positions, 16), shaped(positions, 16), shaped(5120),
+        shaped(dtype=jnp.int32),
+    )
+    call = next(line for line in compiled.as_text().splitlines()
+                if "tpu_custom_call" in line and "custom-call(" in line)
+    assert "ssm_scan" in call.split(" = ")[0]
+    assert f"f32[{rows},5120]" in call.split("custom-call(")[0]
+
+
 @pytest.mark.parametrize("hw,k,n", BN_STAGES)
 @pytest.mark.parametrize("with_res", [False, True], ids=["plain", "residual"])
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
