@@ -417,6 +417,36 @@ def prefill_mla_moe(mesh) -> ProgramSpec:
     )
 
 
+def prefill_chunk_mla_moe(mesh) -> ProgramSpec:
+    """The second chunk of a prompt of the latent-attention expert
+    model (``start`` 16, static), over the rows the first one wrote into
+    the donated one-slot cache: how the decoder prefills this model. The
+    production jit names ``start`` static (``static_argnames``); here it
+    is the sixth argument, static by number, which lowers the same
+    program."""
+    import jax
+    import jax.numpy as jnp
+
+    from ...serving.lm import kvcache
+
+    model, variables = _served_mla_moe(mesh)
+    replicated = _replicated(mesh)
+    cache = jax.device_put(kvcache.make_arena(model, 1, 32), replicated)
+    tokens = jax.device_put(jnp.zeros((1, 16), jnp.int32), replicated)
+    n_real = jax.device_put(jnp.int32(25), replicated)
+    return ProgramSpec(
+        name="prefill_chunk.mla_moe",
+        fn=kvcache.prefill_bucket,
+        args=(model, variables, tokens, cache, n_real, 16),
+        jit_kwargs={
+            "static_argnums": (0, 5),
+            "donate_argnums": (3,),
+            "out_shardings": replicated,
+        },
+        expect_donated=(3,),
+    )
+
+
 def _served_jamba(mesh):
     """A small hybrid state-space model at the dtype ``dsst serve-lm
     --model-config`` serves (bfloat16): one period of four layers, the
@@ -672,6 +702,7 @@ _BUILDERS: dict[str, Callable] = {
     "prefill.lm": prefill_lm,
     "slot_decode.mla_moe": slot_decode_mla_moe,
     "prefill.mla_moe": prefill_mla_moe,
+    "prefill_chunk.mla_moe": prefill_chunk_mla_moe,
     "slot_decode.jamba": slot_decode_jamba,
     "prefill.jamba": prefill_jamba,
     "serving.score": serving_score,

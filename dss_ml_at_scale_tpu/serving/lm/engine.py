@@ -32,10 +32,20 @@ the image tier's, reused verbatim: a full queue raises
 raises :class:`~..admission.NotAccepting` (503), and drain = stop
 admitting, finish every in-flight slot.
 
+A decoder whose ``chunk`` is set prefills a prompt in chunks of that
+many tokens: the engine admits in arrival order, and while any slot
+decodes it launches one chunk of the oldest admission a turn, just
+before the turn's decode step, so no stream's next token waits for more
+than one chunk; with no slot decoding the chunks run back to back. No
+launch waits for the device: the turn after a prompt's last chunk went
+out (its decode step already queued behind it) scatters the prompt into
+its slot and takes the first token from that chunk's logits row.
+
 Two decoder backends satisfy the same protocol
 (``prefill``/``dispatch``/``fetch``/``warmup`` + ``slots``/
 ``vocab_size``/``waited_s``, the seconds it has been blocked until the
-device finished a step or a prefill): :class:`TransformerDecoder` runs
+device finished a step or a prefill, and ``chunk`` with
+``prefill_chunk``/``finish_prefill``): :class:`TransformerDecoder` runs
 the real audited programs; :class:`StubLMDecoder` is the test double:
 no model, deterministic streams, and a per-STEP cost that does not
 depend on how many slots are active.
@@ -43,6 +53,7 @@ depend on how many slots are active.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import math
 import queue
@@ -231,6 +242,11 @@ def _record_fetched(t0: float, t1: float, t2: float) -> None:
     log.record("lm.fetch", wall + t1, t2 - t1)
 
 
+def chunk_rows(n: int, chunk: int) -> int:
+    """The rows of the whole chunks that hold ``n`` positions."""
+    return -(-n // chunk) * chunk
+
+
 def cache_bytes_by_kind(model, arena) -> dict:
     """Bytes of ``arena`` by the kind of state a slot holds: the model's
     own split where it keeps several kinds, else all of it under its one
@@ -267,6 +283,11 @@ class TransformerDecoder:
     layer's routing counts, ``[held, absent, touched, per held
     expert...]``) has them fetched with the ids and counted here, under
     ``lm_moe_*``.
+
+    A model that prefills a chunk at a start position
+    (``prefill_chunk``) is prefilled in chunks of the smallest bucket
+    (``chunk``), one ``prefill_bucket`` program a start from 0 to the
+    largest bucket, in place of one a bucket.
     """
 
     def __init__(self, model, variables, *, slots, max_len, buckets):
@@ -289,9 +310,22 @@ class TransformerDecoder:
             weights_bytes.labels(dtype=name).set(nbytes)
         self.slots = int(slots)
         self.max_len = int(max_len)
-        self.buckets = tuple(buckets)
+        self.buckets = tuple(sorted(buckets))
         self.vocab_size = model.vocab_size
         self.waited_s = 0.0
+        self.chunk = (self.buckets[0] if hasattr(model, "prefill_chunk")
+                      else None)
+        if self.chunk is not None and chunk_rows(
+                self.buckets[-1], self.chunk) > self.max_len:
+            raise ValueError(
+                f"chunks of {self.chunk} cover the largest bucket "
+                f"{self.buckets[-1]} in "
+                f"{chunk_rows(self.buckets[-1], self.chunk)} rows, more "
+                f"than max_len {self.max_len}")
+        # The stats of a prompt's chunks and its last chunk's logits, on
+        # the device until finish_prefill fetches them.
+        self._chunk_stats: list = []
+        self._chunk_logits = None
         self._arena = kvcache.make_arena(model, self.slots, self.max_len)
         cache_bytes = telemetry.gauge(
             "lm_cache_bytes",
@@ -326,7 +360,8 @@ class TransformerDecoder:
             kvcache.slot_decode, static_argnums=0, donate_argnums=(3,)
         )
         self._prefill_fn = jax.jit(
-            kvcache.prefill_bucket, static_argnums=0, donate_argnums=(3,)
+            kvcache.prefill_bucket, static_argnums=0,
+            static_argnames=("start",), donate_argnums=(3,)
         )
         self._write_fn = jax.jit(kvcache.write_slot, donate_argnums=(0,))
         assignments = telemetry.counter(
@@ -361,8 +396,14 @@ class TransformerDecoder:
 
     def warmup(self) -> None:
         """Compile every production shape before serving traffic."""
-        for bucket in self.buckets:
-            self.prefill(np.zeros((1, bucket), np.int32), 1, 0)
+        if self.chunk is None:
+            for bucket in self.buckets:
+                self.prefill(np.zeros((1, bucket), np.int32), 1, 0)
+        else:
+            for start in range(0, self.buckets[-1], self.chunk):
+                self.prefill_chunk(np.zeros((1, self.chunk), np.int32),
+                                   start, start + 1)
+                self.finish_prefill(0)
         # Twice: the second step takes the first's ids, as every step
         # after an engine's first does.
         zeros = np.zeros(self.slots, np.int32)
@@ -392,6 +433,45 @@ class TransformerDecoder:
         if stats is not None:
             self._count(stats, "prefill")
         return np.asarray(row, np.float32)
+
+    def prefill_chunk(self, tokens: np.ndarray, start: int,
+                      n_real: int) -> None:
+        """Launch the chunk ``tokens`` ``[1, chunk]`` of a prompt of
+        ``n_real`` tokens at position ``start`` into the scratch cache,
+        which holds the prompt's chunks before it, and return at once:
+        nothing waits for the device, and the chunk's stats (and, from
+        the chunk that holds the last real token, its logits row) stay
+        there until :meth:`finish_prefill`."""
+        jnp = self._jnp
+        if start == 0:
+            self._chunk_stats = []
+        logits, stats, self._scratch = self._prefill_fn(
+            self.model, self.variables,
+            jnp.asarray(tokens, jnp.int32), self._scratch,
+            np.int32(n_real), start=start,
+        )
+        if stats is not None:
+            self._chunk_stats.append(stats)
+        self._chunk_logits = logits
+
+    def finish_prefill(self, slot: int):
+        """After a prompt's last chunk: scatter the scratch cache into
+        ``slot`` and return the last real position's logits row (host
+        numpy), its stats and those of the chunks before it fetched with
+        it. Call it before the next prompt's first chunk, which reuses
+        the scratch."""
+        jnp = self._jnp
+        self._arena = self._write_fn(self._arena, self._scratch,
+                                     jnp.int32(slot))
+        t0 = time.perf_counter()
+        self._chunk_logits.block_until_ready()
+        self.waited_s += time.perf_counter() - t0
+        logits, stats = self._jax.device_get(
+            (self._chunk_logits, self._chunk_stats))
+        self._chunk_logits, self._chunk_stats = None, []
+        for chunk_stats in stats:
+            self._count(chunk_stats, "prefill")
+        return np.asarray(logits[0], np.float32)
 
     def dispatch(self, override: np.ndarray, pos: np.ndarray):
         """Launch one ``slot_decode`` over every slot and return at once.
@@ -442,13 +522,16 @@ class StubLMDecoder:
     dispatched and no matter how many slots are active, as a device
     queue would run them; ``fetch`` sleeps until its step's end and a
     prefill sleeps ``prefill_ms``, both counted as waits on the device.
-    What a test reads off it is structure (which requests shared a
-    step, what was dispatched before what was fetched), never a speed.
-    Logits are one-hot at the id.
+    With ``chunk`` set the stub prefills in chunks, as a model with
+    ``prefill_chunk`` does: a chunk takes ``prefill_ms`` on the same
+    timeline as the steps, and ``finish_prefill`` sleeps until the last
+    one's end. What a test reads off it is structure (which requests
+    shared a step, what was dispatched before what was fetched), never
+    a speed. Logits are one-hot at the id.
     """
 
     def __init__(self, *, vocab_size=256, step_ms=2.0, prefill_ms=None,
-                 slots=8, max_len=128, buckets=(16,)):
+                 slots=8, max_len=128, buckets=(16,), chunk=None):
         self.vocab_size = int(vocab_size)
         self.step_ms = float(step_ms)
         self.prefill_ms = float(
@@ -457,6 +540,8 @@ class StubLMDecoder:
         self.slots = int(slots)
         self.max_len = int(max_len)
         self.buckets = tuple(buckets)
+        self.chunk = chunk
+        self._chunk_row = None  # (logits row, end on the timeline)
         self.waited_s = 0.0
         self._ids = np.zeros(self.slots, np.int32)
         self._busy_until = 0.0
@@ -473,6 +558,25 @@ class StubLMDecoder:
         self.waited_s += time.perf_counter() - t0
         row = np.zeros(self.vocab_size, np.float32)
         row[self._next(tokens[0, n_real - 1], n_real - 1)] = 1.0
+        return row
+
+    def prefill_chunk(self, tokens: np.ndarray, start: int,
+                      n_real: int) -> None:
+        self._busy_until = (
+            max(time.perf_counter(), self._busy_until)
+            + self.prefill_ms / 1000.0
+        )
+        last = n_real - 1 - start
+        if last < tokens.shape[1]:
+            row = np.zeros(self.vocab_size, np.float32)
+            row[self._next(tokens[0, last], n_real - 1)] = 1.0
+            self._chunk_row = row, self._busy_until
+
+    def finish_prefill(self, slot: int):
+        (row, ready_at), self._chunk_row = self._chunk_row, None
+        t0 = time.perf_counter()
+        time.sleep(max(0.0, ready_at - t0))
+        self.waited_s += time.perf_counter() - t0
         return row
 
     def dispatch(self, override: np.ndarray, pos: np.ndarray):
@@ -508,6 +612,20 @@ class _Step:
     handle: object  # the decoder's own, for its fetch()
     active: dict  # slot -> the Generation whose token this step computes
     logits: bool  # some generation in it samples from its logits row
+
+
+@dataclasses.dataclass
+class _Prefill:
+    """An admission whose prompt is prefilled a chunk at a time: it holds
+    its slot, and the decoder's scratch cache from its first chunk to its
+    last."""
+
+    gen: Generation
+    slot: int
+    padded: np.ndarray  # [1, whole chunks] the prompt, zero-padded
+    start: int = 0  # the next chunk's first position
+    t0: float = 0.0  # perf_counter at the first chunk's launch
+    queued_s: float = 0.0  # that launch less the request's submit
 
 
 class LMEngine:
@@ -551,6 +669,11 @@ class LMEngine:
         # Engine-thread-only: the decode step dispatched and not yet
         # collected (its ids still on the device), or None.
         self._in_flight: _Step | None = None
+        # Engine-thread-only, for a decoder that prefills in chunks (its
+        # ``chunk``): the admissions whose prompts are being prefilled,
+        # oldest first.
+        self._chunk = getattr(decoder, "chunk", None)
+        self._prefills: collections.deque[_Prefill] = collections.deque()
         self._slo = telemetry.slo.get_engine()
         self._admission = AdmissionController(
             self.cfg.queue_depth,
@@ -595,19 +718,31 @@ class LMEngine:
         }
         self._prefill_real = prefill_tokens.labels(kind="real")
         self._prefill_padded = prefill_tokens.labels(kind="padded")
+        launches = telemetry.counter(
+            "lm_prefill_launches_total",
+            "prefill programs launched: a whole prompt, or one chunk of one",
+            labels=("kind",),
+        )
+        self._launches = {kind: launches.labels(kind=kind)
+                          for kind in ("whole", "chunk")}
         # How far the flash kernel's causal block schedule engages: the
-        # block pairs of one head-layer's call an admission (every layer
-        # and head of the call runs the same plan), for a model whose
-        # prefill attention is that kernel; its plan a bucket.
+        # block pairs of one head-layer's call a launch (every layer and
+        # head of the call runs the same plan), for a model whose prefill
+        # attention is that kernel; its plan by (queries, keys): a
+        # bucket's square, or a chunk's rows over its prefix and itself.
         model = getattr(decoder, "model", None)
+        buckets = self.cfg.prefill_buckets
+        shapes = ([(b, b) for b in buckets] if self._chunk is None else
+                  [(self._chunk, start + self._chunk)
+                   for start in range(0, buckets[-1], self._chunk)])
         self._attention_plans = {
-            b: block_plan(b, b, *flash_blocks(b, b), causal=True)
-            for b in self.cfg.prefill_buckets
+            (sq, sk): block_plan(sq, sk, *flash_blocks(sq, sk), causal=True)
+            for sq, sk in shapes
         } if getattr(model, "attention", None) == "flash" else {}
         attention_blocks = telemetry.counter(
             "lm_prefill_attention_blocks_total",
-            "flash-attention block pairs of one head-layer a prefill: the "
-            "full rectangle, those visited, and those masked",
+            "flash-attention block pairs of one head-layer a prefill "
+            "launch: the full rectangle, those visited, and those masked",
             labels=("kind",),
         )
         self._attention_blocks = {
@@ -838,6 +973,7 @@ class LMEngine:
                 and not self._waiting
                 and not self._active
                 and self._in_flight is None
+                and not self._prefills
             ):
                 t_wait = time.perf_counter()
                 self._cond.wait(0.05)
@@ -878,28 +1014,51 @@ class LMEngine:
                 time.perf_counter() - t_admit,
                 admitted=len(admitted), waiting=len(still_waiting),
             )
-        for gen, slot in admitted:
-            try:
-                self._admit_into_slot(gen, slot)
-            except Exception as exc:
-                # A poisoned generation (sampling state the door's
-                # validation could not foresee) retires ITSELF, not
-                # the shared loop: free its slot, settle it with an
-                # error event, keep serving everyone else.
-                with self._cond:
-                    self._active.pop(slot, None)
-                    self._slots_gauge.set(len(self._active))
-                if not gen.is_settled():
-                    self._alloc.free(slot)
-                    self._settle(gen, "error", error=exc)
-        if admitted:
+        if self._chunk is None:
+            for gen, slot in admitted:
+                try:
+                    self._admit_into_slot(gen, slot)
+                except Exception as exc:
+                    self._admission_failed(gen, slot, exc)
+            self._admitted([gen for gen, _ in admitted])
+        else:
+            for gen, slot in admitted:
+                padded = np.zeros(
+                    (1, chunk_rows(len(gen.prompt), self._chunk)), np.int32)
+                padded[0, : len(gen.prompt)] = gen.prompt
+                self._prefills.append(_Prefill(gen, slot, padded))
+            self._prefill_chunks()
+        self._step_once()
+        return True, idle
+
+    def _admission_failed(self, gen: Generation, slot: int,
+                          exc: Exception) -> None:
+        """A poisoned generation (sampling state the door's validation
+        could not foresee) retires ITSELF, not the shared loop: free its
+        slot, settle it with an error event, keep serving everyone
+        else."""
+        with self._cond:
+            self._active.pop(slot, None)
+            self._slots_gauge.set(len(self._active))
+        if not gen.is_settled():
+            self._alloc.free(slot)
+            self._settle(gen, "error", error=exc)
+
+    def _admitted(self, gens: list) -> None:
+        """``gens`` are out of the in-transit window: active or settled."""
+        if gens:
             with self._cond:
-                for gen, _ in admitted:
+                for gen in gens:
                     if gen in self._admitting:
                         self._admitting.remove(gen)
                 self._cond.notify_all()
-        self._step_once()
-        return True, idle
+
+    def _count_blocks(self, sq: int, sk: int) -> None:
+        plan = self._attention_plans.get((sq, sk))
+        if plan is not None:
+            self._attention_blocks["grid"].inc(plan.grid)
+            self._attention_blocks["visited"].inc(plan.visited)
+            self._attention_blocks["masked"].inc(plan.masked)
 
     def _admit_into_slot(self, gen: Generation, slot: int) -> None:
         """Bucketed prefill + scatter + first token (TTFT)."""
@@ -913,14 +1072,73 @@ class LMEngine:
                             prompt_tokens=len(prompt),
                             queued_s=time.monotonic() - gen.t_admit):
             row = self.decoder.prefill(padded, len(prompt), slot)
+        self._launches["whole"].inc()
         self._prefill_real.inc(len(prompt))
         self._prefill_padded.inc(bucket)
-        plan = self._attention_plans.get(bucket)
-        if plan is not None:
-            self._attention_blocks["grid"].inc(plan.grid)
-            self._attention_blocks["visited"].inc(plan.visited)
-            self._attention_blocks["masked"].inc(plan.masked)
-        gen.n_past = len(prompt)
+        self._count_blocks(bucket, bucket)
+        self._first_token(gen, slot, row)
+
+    def _prefill_chunks(self) -> None:
+        """This turn's prefill work, oldest admission first: the landing
+        of a prompt whose last chunk went out a turn ago, and one chunk
+        launch ahead of the turn's decode step while any slot decodes;
+        launches and landings back to back while none does."""
+        launched = False
+        while self._prefills:
+            if launched:
+                with self._cond:
+                    if self._active:
+                        return
+            p = self._prefills[0]
+            if p.start < p.padded.shape[1]:
+                self._launch_chunk(p)
+                launched = True
+            else:
+                self._land(p)
+
+    def _launch_chunk(self, p: _Prefill) -> None:
+        gen, start, c = p.gen, p.start, self._chunk
+        if start == 0:
+            p.t0, p.queued_s = time.perf_counter(), (
+                time.monotonic() - gen.t_admit)
+        try:
+            with telemetry.span("lm.prefill_chunk", start=start, rows=c):
+                self.decoder.prefill_chunk(p.padded[:, start:start + c],
+                                           start, len(gen.prompt))
+        except Exception as exc:
+            self._prefills.popleft()
+            self._admission_failed(gen, p.slot, exc)
+            self._admitted([gen])
+            return
+        self._launches["chunk"].inc()
+        self._prefill_padded.inc(c)
+        self._count_blocks(c, start + c)
+        p.start += c
+
+    def _land(self, p: _Prefill) -> None:
+        """A prompt whose chunks have all gone out: into its slot, and
+        its first token. ``lm.prefill`` covers the admission, from its
+        first chunk's launch to here."""
+        self._prefills.popleft()
+        gen = p.gen
+        try:
+            row = self.decoder.finish_prefill(p.slot)
+            # dsst: ignore[span-discipline] an admission's chunks run across several turns of the loop, between other spans; it is recorded at its first token
+            telemetry.get_span_log().record(
+                "lm.prefill", _epoch_offset() + p.t0,
+                time.perf_counter() - p.t0, bucket=p.start,
+                prompt_tokens=len(gen.prompt), queued_s=p.queued_s,
+            )
+            self._prefill_real.inc(len(gen.prompt))
+            self._first_token(gen, p.slot, row)
+        except Exception as exc:
+            self._admission_failed(gen, p.slot, exc)
+        self._admitted([gen])
+
+    def _first_token(self, gen: Generation, slot: int, row) -> None:
+        """The first token from the prefill's last logits row (TTFT);
+        then the slot decodes, or retires at once."""
+        gen.n_past = len(gen.prompt)
         token = gen.sample(row)
         now = time.monotonic()
         ttft = now - gen.t_admit

@@ -32,8 +32,10 @@ like the other production programs):
 
 ``prefill_bucket``
     The whole prompt through one causal pass into a single-sequence
-    cache, compiled once per configured bucket length. The cache
-    argument is donated too: the engine keeps ONE prefill scratch
+    cache, compiled once per configured bucket length; or, for a model
+    that prefills a chunk at a start position, one chunk of the smallest
+    bucket's length at a static ``start``, compiled once per start. The
+    cache argument is donated too: the engine keeps ONE prefill scratch
     cache and recycles the returned buffers.
 
 ``write_slot``
@@ -61,6 +63,10 @@ Arena = tuple  # per layer, a dict of slabs whose leading axis is the slot
 #   init_cache(slots, max_len)           the cache for slots x max_len
 #   prefill_cache(variables, tokens[1, bucket], cache, n_real)
 #                                        -> (logits, stats, cache)
+#   prefill_chunk(variables, tokens[1, C], cache, start, n_real)
+#                                        (optional) the same for positions
+#                                        start..start+C over the rows
+#                                        [0, start) the cache holds
 #   decode_slots(variables, tokens[slots], cache, pos[slots])
 #                                        -> (logits, stats, cache)
 #   serving_variables(variables)         each leaf at its served width
@@ -105,7 +111,8 @@ def slot_decode(model, variables, tokens, arena, pos, override=None):
     return logits, ids, stats, arena
 
 
-def prefill_bucket(model, variables, tokens, cache, n_real=None):
+def prefill_bucket(model, variables, tokens, cache, n_real=None,
+                   start=None):
     """Prefill one bucket-padded prompt into a single-sequence cache.
 
     ``tokens`` is ``[1, bucket]`` int32; compiled once per bucket
@@ -117,8 +124,15 @@ def prefill_bucket(model, variables, tokens, cache, n_real=None):
     real prompt hold padding rows, never attended (causal mask) and
     overwritten by later decode steps before the position pointer
     passes them.
+
+    With ``start`` (static: jit with ``static_argnames=("start",)``)
+    ``tokens`` is the chunk of the prompt at positions ``start ..
+    start + C``, through the model's ``prefill_chunk``; the cache holds
+    the chunks before it.
     """
-    return model.prefill_cache(variables, tokens, cache, n_real)
+    if start is None:
+        return model.prefill_cache(variables, tokens, cache, n_real)
+    return model.prefill_chunk(variables, tokens, cache, start, n_real)
 
 
 def write_slot(arena, rows, slot):

@@ -101,6 +101,7 @@ KNOWN_METRICS: dict[str, str] = {
     "lm_moe_expert_assignments_total": "counter",
     "lm_moe_experts_touched_total": "counter",
     "lm_prefill_attention_blocks_total": "counter",
+    "lm_prefill_launches_total": "counter",
     "lm_prefill_tokens_total": "counter",
     "lm_queue_depth": "gauge",
     "lm_retired_total": "counter",
@@ -159,8 +160,16 @@ KNOWN_SPANS: dict[str, str] = {
                     "arrays",
     "serve.score": "one request's share of a scored micro-batch",
     "lm.prefill": "one bucket-padded prompt prefill + arena scatter "
-                  "(admission into a free slot); arg queued_s: the "
+                  "(admission into a free slot); for a decoder that "
+                  "prefills in chunks, from the first chunk's launch to "
+                  "the first token, across the turns between (arg "
+                  "bucket: the rows launched); arg queued_s: the "
                   "prefill's start less the request's submit",
+    "lm.prefill_chunk": "the launch of one chunk of a prompt, ahead of "
+                        "a turn's decode step (args start, rows); the "
+                        "turn after the last one scatters the prompt into "
+                        "its slot and waits for that chunk's logits row, "
+                        "inside lm.prefill",
     "lm.step": "one turn of the decode loop that dispatches a "
                "slot_decode step (args active, context_tokens: that "
                "step's) and collects the step dispatched a turn "
@@ -233,7 +242,8 @@ SPAN_ATTRIBUTION: dict[str, str] = {
 }
 
 # Spans that lie inside another span of the same thread and trace:
-# reader.assemble runs on the feeder thread inside reader.next, the
+# reader.assemble runs on the feeder thread inside reader.next, a
+# prompt's chunks inside its lm.prefill, the
 # three parts of a decode step inside lm.step (the wait and the fetch of
 # a run's last step, which dispatches nothing, lie outside one and are
 # left out all the same). The two consumers of
@@ -243,6 +253,7 @@ SPAN_ATTRIBUTION: dict[str, str] = {
 # and so never reach a step's buckets.)
 SPAN_NESTED: frozenset[str] = frozenset({
     "reader.assemble", "lm.dispatch", "lm.wait", "lm.fetch",
+    "lm.prefill_chunk",
 })
 
 # Scenario name -> the exact metric keys its schema may emit

@@ -144,6 +144,31 @@ def test_flash_attention_compiles_at_the_serving_buckets(
     assert f"bf16[{heads},{seq},128]" in call.split("custom-call(")[0]
 
 
+@pytest.mark.parametrize("keys", [4096, 6144, 8192])
+def test_flash_attention_compiles_at_the_document_cells_chunks(
+        one_chip, no_compile_cache, keys):
+    """The document cell's prefill in chunks of 2,048: a chunk's queries
+    over its prefix and itself, the mask bottom-right; the benchmark's
+    reader finds the call's query length first in its HLO line."""
+    import jax
+    import jax.numpy as jnp
+
+    from dss_ml_at_scale_tpu.ops.flash_attention import flash_attention
+
+    q = jax.ShapeDtypeStruct((1, 32, 2048, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 32, keys, 128), jnp.bfloat16,
+                              sharding=one_chip)
+    compiled = _compile(
+        lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                        interpret=False),
+        q, kv, kv,
+    )
+    call = next(line for line in compiled.as_text().splitlines()
+                if "tpu_custom_call" in line and "custom-call(" in line)
+    assert "bf16[32,2048,128]" in call.split("custom-call(")[0]
+
+
 @pytest.mark.parametrize("positions", [256, 512, 1024, 2048, 100])
 def test_selective_scan_compiles_at_the_reason_cells_buckets(
         one_chip, no_compile_cache, positions):

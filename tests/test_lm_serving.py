@@ -254,6 +254,145 @@ def test_step_n_plus_1_is_dispatched_before_step_n_is_fetched():
     assert dispatches[3][2].tolist() == [5, 0]
 
 
+class _ChunkStub(StubLMDecoder):
+    """A stub that prefills in chunks of 8, recording its launches,
+    landings, dispatches and fetches in the order the engine made them,
+    and what each launch and landing waited on the device."""
+
+    def __init__(self, **kw):
+        super().__init__(chunk=8, **kw)
+        self.calls = []
+
+    def prefill_chunk(self, tokens, start, n_real):
+        waited = self.waited_s
+        super().prefill_chunk(tokens, start, n_real)
+        self.calls.append(("chunk", n_real, start, self.waited_s - waited))
+
+    def finish_prefill(self, slot):
+        waited = self.waited_s
+        row = super().finish_prefill(slot)
+        self.calls.append(("finish", slot, self.waited_s - waited))
+        return row
+
+    def dispatch(self, override, pos):
+        self.calls.append(("dispatch",))
+        return super().dispatch(override, pos)
+
+    def fetch(self, step, *, logits=False):
+        self.calls.append(("fetch",))
+        return super().fetch(step, logits=logits)
+
+
+def series(name, **labels):
+    for metric in telemetry.snapshot()["metrics"]:
+        if metric["name"] == name and metric["labels"] == labels:
+            return metric["value"]
+    return None
+
+
+def _chunk_engine(**kw):
+    decoder = _ChunkStub(vocab_size=97, step_ms=1.0, prefill_ms=2.0,
+                         slots=2, max_len=64, buckets=(8, 32), **kw)
+    engine = LMEngine(decoder, LMConfig(slots=2, max_len=64,
+                                        prefill_buckets=(8, 32)))
+    return decoder, engine
+
+
+def _launches(calls, n_real):
+    """Each of one prompt's chunk launches: (its place among the calls,
+    its start)."""
+    return [(i, c[2]) for i, c in enumerate(calls)
+            if c[0] == "chunk" and c[1] == n_real]
+
+
+def test_while_a_slot_decodes_a_turn_carries_one_chunk():
+    """A prompt of 30 tokens, four chunks of 8, admitted while another
+    request streams: between two of its chunks the engine dispatches a
+    decode step, so no stream waits for more than one chunk; the streams
+    are the stub's, as one whole prefill gives them."""
+    telemetry.reset()
+    decoder, engine = _chunk_engine()
+    engine.start()
+    try:
+        streaming_prompt = [1, 2, 3]
+        streaming = engine.submit(streaming_prompt, 40)
+        assert streaming.next_event(timeout=30.0)[0] == "token"
+        doc_prompt = [(7 * i) % 97 for i in range(30)]
+        doc = engine.submit(doc_prompt, 3)
+        assert _collect(doc) == (_stub_expected(decoder, doc_prompt, 3),
+                                 ("done", "max_tokens"))
+        assert _collect(streaming) == (
+            _stub_expected(decoder, streaming_prompt, 40)[1:],
+            ("done", "max_tokens"))
+    finally:
+        engine.drain(5.0)
+    launched = _launches(decoder.calls, 30)
+    assert [start for _, start in launched] == [0, 8, 16, 24]
+    for (a, _), (b, _) in zip(launched, launched[1:]):
+        between = [c[0] for c in decoder.calls[a + 1:b]]
+        assert between.count("dispatch") == 1, between
+    # it lands the turn after its last chunk, behind that turn's step
+    last = launched[-1][0]
+    landing = next(i for i, c in enumerate(decoder.calls)
+                   if i > last and c[0] == "finish")
+    assert [c[0] for c in decoder.calls[last + 1:landing]].count(
+        "dispatch") == 1
+    # the admission's one lm.prefill and its four lm.prefill_chunk spans
+    events = telemetry.get_span_log().events()
+    prefill = [e["args"] for e in events if e["name"] == "lm.prefill"
+               and e["args"]["prompt_tokens"] == 30]
+    assert prefill == [{"bucket": 32, "prompt_tokens": 30,
+                        "queued_s": prefill[0]["queued_s"]}]
+    chunks = [e["args"] for e in events if e["name"] == "lm.prefill_chunk"]
+    assert {"start": 24, "rows": 8} in chunks
+    assert series("lm_prefill_launches_total", kind="chunk") == 1 + 4
+    assert series("lm_prefill_launches_total", kind="whole") == 0
+    assert series("lm_prefill_tokens_total", kind="padded") == 8 * (1 + 4)
+    assert series("lm_prefill_tokens_total", kind="real") == 3 + 30
+
+
+def test_with_every_slot_empty_the_chunks_run_back_to_back():
+    """Two prompts waiting when the loop starts, with no slot decoding:
+    the first one's chunks go out one after another, before any decode
+    step; once its slot decodes, the second one's ride one a turn."""
+    decoder, engine = _chunk_engine()
+    first, second = [5] * 20, [6] * 17
+    gens = [engine.submit(first, 4), engine.submit(second, 4)]
+    engine.start()
+    try:
+        for gen, prompt in zip(gens, (first, second)):
+            assert _collect(gen) == (_stub_expected(decoder, prompt, 4),
+                                     ("done", "max_tokens"))
+    finally:
+        engine.drain(5.0)
+    assert [c[:3] for c in decoder.calls[:3]] == [
+        ("chunk", 20, 0), ("chunk", 20, 8), ("chunk", 20, 16)]
+    launched = _launches(decoder.calls, 17)
+    assert [start for _, start in launched] == [0, 8, 16]
+    for (a, _), (b, _) in zip(launched, launched[1:]):
+        assert [c[0] for c in decoder.calls[a + 1:b]].count("dispatch") == 1
+
+
+def test_a_chunk_launch_waits_for_nothing():
+    """No chunk's launch waits on the device, the last one's included
+    (the decoder's ``waited_s`` does not move); the landing waits for
+    the last chunk's logits row. With no slot decoding it comes at once,
+    so it waits out all four chunks, 2 ms each."""
+    decoder, engine = _chunk_engine()
+    prompt = [9] * 25
+    gen = engine.submit(prompt, 2)
+    engine.start()
+    try:
+        assert _collect(gen)[1] == ("done", "max_tokens")
+    finally:
+        engine.drain(5.0)
+    waits = [c[3] for c in decoder.calls if c[0] == "chunk"]
+    assert waits == [0.0, 0.0, 0.0, 0.0]
+    landings = [c for c in decoder.calls if c[0] == "finish"]
+    assert len(landings) == 1 and landings[0][2] >= 0.007
+    assert decoder.calls.index(landings[0]) == 4     # before any step
+
+
 def _tiny_lm():
     import jax
     import jax.numpy as jnp

@@ -160,3 +160,127 @@ def test_moe_parity_churn_vs_solo_vs_generate(devices8):
             assert tokens == want
     finally:
         churn.drain(10.0)
+
+
+# -- prefill in chunks ---------------------------------------------------------
+#
+# A model that prefills a chunk at a start position (``MlaMoeLM``) is
+# prefilled in chunks of the smallest bucket; the others keep one program a
+# bucket. Tiny models with the weights their ``init`` makes, float32.
+
+MLA_CFG = {
+    "vocab_size": 64, "hidden_size": 32, "num_layers": 2,
+    "num_attention_heads": 2, "q_lora_rank": 16, "kv_lora_rank": 16,
+    "qk_nope_head_dim": 8, "qk_rope_head_dim": 8, "v_head_dim": 16,
+    "moe_intermediate_size": 16, "n_routed_experts": 4, "router_width": 4,
+    "num_experts_per_tok": 2,
+}
+JAMBA_CFG = {
+    "model_type": "jamba", "vocab_size": 64, "hidden_size": 32,
+    "num_hidden_layers": 2, "num_attention_heads": 2,
+    "num_key_value_heads": 1, "intermediate_size": 48, "mamba_d_state": 8,
+    "mamba_d_conv": 4, "mamba_expand": 2, "mamba_dt_rank": 4,
+    "attn_layer_period": 2, "attn_layer_offset": 1, "num_experts": 1,
+    "tie_word_embeddings": True,
+}
+CHUNK_BUCKETS = (8, 32)
+
+
+def _served(kind):
+    """(model, variables) of one of the three served families."""
+    import jax
+    import jax.numpy as jnp
+
+    if kind == "moe":
+        return _moe_lm()
+    if kind == "mla":
+        from dss_ml_at_scale_tpu.models.mla_moe import MlaMoeLM
+
+        model = MlaMoeLM.from_config(MLA_CFG, attention="reference",
+                                     dtype=jnp.float32)
+        return model, model.init(jax.random.key(4))
+    from dss_ml_at_scale_tpu.models.jamba import JambaLM
+
+    model = JambaLM.from_config(JAMBA_CFG, dtype=jnp.float32,
+                                attention="reference")
+    return model, model.init(jax.random.key(4))
+
+
+class _CompileCount:
+    """Backend compiles in this process while ``on``."""
+
+    def __init__(self):
+        import jax
+
+        self.on, self.n = False, 0
+
+        def count(event, seconds, **_):
+            if self.on and event == "/jax/core/compile/backend_compile_duration":
+                self.n += 1
+
+        jax.monitoring.register_event_duration_secs_listener(count)
+
+
+@pytest.mark.parametrize("kind, chunk, programs", [
+    # one program a bucket, the whole prompt, as before
+    ("moe", None, [(8, None), (32, None)]),
+    ("jamba", None, [(8, None), (32, None)]),
+    # one a start, a chunk of the smallest bucket each
+    ("mla", 8, [(8, 0), (8, 8), (8, 16), (8, 24)]),
+])
+def test_warmup_compiles_the_programs_the_model_prefills_with(
+        devices8, kind, chunk, programs):
+    """The prefill programs warm-up runs, by their static shapes: the
+    tokens' length and ``start``."""
+    from dss_ml_at_scale_tpu.serving.lm import TransformerDecoder
+
+    model, variables = _served(kind)
+    decoder = TransformerDecoder(model, variables, slots=4, max_len=MAX_LEN,
+                                 buckets=CHUNK_BUCKETS)
+    assert decoder.chunk == chunk
+    ran, prefill = [], decoder._prefill_fn
+
+    def recorded(model, variables, tokens, cache, n_real, start=None):
+        ran.append((tokens.shape[1], start))
+        if start is None:
+            return prefill(model, variables, tokens, cache, n_real)
+        return prefill(model, variables, tokens, cache, n_real, start=start)
+
+    decoder._prefill_fn = recorded
+    decoder.warmup()
+    assert ran == programs
+
+
+def test_a_chunked_engine_streams_the_whole_prompt_engines_tokens(devices8):
+    """Greedy streams of the latent-attention model through the engine,
+    its prompts prefilled in chunks of 8 (1 to 4 of them), are the
+    streams of the same engine prefilling each prompt in one program;
+    and nothing compiles once the engine has warmed up."""
+    from dss_ml_at_scale_tpu.serving.lm import TransformerDecoder
+
+    model, variables = _served("mla")
+    rng = np.random.default_rng(8)
+    prompts = [[int(t) for t in rng.integers(1, 64, n)]
+               for n in (3, 8, 9, 17, 30, 32, 12)]
+    compiles = _CompileCount()
+
+    def streams(chunked):
+        decoder = TransformerDecoder(model, variables, slots=4,
+                                     max_len=MAX_LEN, buckets=CHUNK_BUCKETS)
+        if not chunked:          # the test steers the decoder, no option
+            decoder.chunk = None
+        engine = LMEngine(decoder, LMConfig(
+            slots=4, max_len=MAX_LEN, prefill_buckets=CHUNK_BUCKETS)).start()
+        compiles.n, compiles.on = 0, True
+        try:
+            gens = [engine.submit(p, 6) for p in prompts]
+            out = [_collect(gen) for gen in gens]
+        finally:
+            compiles.on = False
+            engine.drain(10.0)
+        assert compiles.n == 0
+        return out
+
+    chunked = streams(True)
+    assert all(end == ("done", "max_tokens") for _, end in chunked)
+    assert chunked == streams(False)

@@ -34,7 +34,7 @@ import numpy as np
 import pytest
 
 from dss_ml_at_scale_tpu import telemetry
-from dss_ml_at_scale_tpu.models.mla_moe import MlaMoeLM
+from dss_ml_at_scale_tpu.models.mla_moe import MlaMoeLM, _expert_pass
 from dss_ml_at_scale_tpu.serving.lm import (
     LMConfig,
     LMEngine,
@@ -189,6 +189,53 @@ def test_prefill_then_decode_through_the_latent_arena(bench):
     assert max(pos.values()) > 32 > min(prompts.values())   # past 2 x 16
 
 
+@pytest.mark.parametrize("attention", ["reference", "flash"])
+@pytest.mark.parametrize("n, chunk", [
+    (32, 16),        # 2 chunks, no padding
+    (40, 16),        # 3, the last 8 rows padding
+    (30, 8),         # 4, the last 2 rows padding
+])
+def test_a_prompt_in_chunks_is_the_prompt_in_one(bench, attention, n,
+                                                 chunk):
+    """``prefill_chunk`` chunk after chunk, each at its start over the
+    rows the ones before it wrote, against ``prefill_cache`` of the same
+    padded prompt: the cache's real rows and the last real row's logits
+    agree to ``F32_TOL`` (the flash kernel's causal mask is bottom-right
+    aligned for a chunk's rows over its prefix); the routed pairs add up
+    to the whole prefill's, expert by expert, padding routed nowhere; a
+    chunk that does not hold the last token returns no logits."""
+    model = MlaMoeLM.from_config(CFG, attention=attention, dtype=jnp.float32)
+    variables = variables_of(bench)
+    rows = -(-n // chunk) * chunk
+    padded = np.zeros((1, rows), np.int32)
+    padded[0, :n] = tokens_of(n, 7)
+    prefill = jax.jit(kvcache.prefill_bucket, static_argnums=0,
+                      static_argnames=("start",))
+    whole, whole_stats, whole_cache = prefill(
+        model, variables, jnp.asarray(padded), kvcache.make_arena(model, 1, 64),
+        jnp.int32(n))
+    cache, stats = kvcache.make_arena(model, 1, 64), []
+    for start in range(0, rows, chunk):
+        logits, chunk_stats, cache = prefill(
+            model, variables, jnp.asarray(padded[:, start:start + chunk]),
+            cache, jnp.int32(n), start=start)
+        stats.append(np.asarray(chunk_stats))
+        if start + chunk < n:
+            assert not np.any(np.asarray(logits))
+    assert len(stats) == rows // chunk >= 2
+    assert float(jnp.max(jnp.abs(logits - whole))) < F32_TOL
+    for mine, theirs in zip(cache, whole_cache):
+        for name in ("c_kv", "k_r"):
+            assert float(jnp.max(jnp.abs(
+                mine[name][0, :n] - theirs[name][0, :n]))) < F32_TOL
+    summed, whole_stats = sum(stats), np.asarray(whole_stats)
+    # held, absent and each held expert's pairs; touched is per launch
+    assert summed[[0, 1]].tolist() == whole_stats[[0, 1]].tolist()
+    assert summed[3:].tolist() == whole_stats[3:].tolist()
+    assert summed[2] >= whole_stats[2]
+    assert int(summed[0] + summed[1]) == n * 2 * CFG["num_layers"]
+
+
 # -- the expert layer ---------------------------------------------------------
 
 
@@ -245,6 +292,31 @@ def test_every_token_to_one_expert_and_none_dropped(bench):
             flat[f"expert_{e}/up/kernel"], flat[f"expert_{e}/down/kernel"],
             reference._exact)
     assert float(jnp.max(jnp.abs(got - want))) < F32_TOL
+
+
+def test_an_expert_pass_is_traced_once_a_shape(bench, monkeypatch):
+    """The expert layer's pass is one jitted function: a program of two
+    layers holding four experts each traces the gated expert three times
+    (the shared expert of each layer, and the pass once), not ten, and
+    the chunk program at a second start traces the pass no more."""
+    traced = []
+    gated = MlaMoeLM._gated
+
+    def counted(self, *args):
+        traced.append(args[0].shape)
+        return gated(self, *args)
+
+    monkeypatch.setattr(MlaMoeLM, "_gated", counted)
+    _expert_pass.clear_cache()       # traced by the tests before this one
+    model, variables = model_of(), variables_of(bench)
+    cache = kvcache.make_arena(model, 1, 64)
+    for start in (0, 16):
+        jax.make_jaxpr(model.prefill_chunk, static_argnums=(3,))(
+            variables, jnp.zeros((1, 16), jnp.int32), cache, start,
+            jnp.int32(20))
+    # each program: the chunk's 16 rows through two layers' shared
+    # experts; the pass (16 rows: min(tile, T)) traced in the first only
+    assert traced == [(16, 64)] * (2 + 1 + 2)
 
 
 @pytest.mark.parametrize("chips", [2, 4])
