@@ -520,6 +520,86 @@ def prefill_jamba(mesh) -> ProgramSpec:
     )
 
 
+def _served_nemotron_h(mesh):
+    """A small Mamba-2 / latent-expert / attention model at the dtype
+    ``dsst serve-lm --model-config`` serves (bfloat16): the pattern
+    ``MEM*E`` (two Mamba-2 mixers of 8 heads in 2 groups, two expert
+    layers holding experts 4..11 of the 16 their sigmoid router scores,
+    attention of 4 query heads over 2 key/value heads), with the tree its
+    decoder holds (the experts stacked)."""
+    import jax
+
+    from ...models.nemotron_h import NemotronHLM
+
+    model = NemotronHLM(
+        vocab_size=128, hidden_size=64, pattern="MEM*E",
+        num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+        mamba_num_heads=8, mamba_head_dim=8, ssm_state_size=16,
+        n_groups=2, moe_intermediate_size=24, moe_latent_size=32,
+        moe_shared_expert_intermediate_size=48, n_routed_experts=8,
+        router_width=16, expert_offset=4, num_experts_per_tok=4,
+        routed_scaling_factor=2.5, chunk_size=8, attention="reference",
+    )
+    variables = model.serving_variables(model.init(jax.random.key(0)))
+    return model, jax.device_put(variables, _replicated(mesh))
+
+
+def slot_decode_nemotron_h(mesh) -> ProgramSpec:
+    """The serving step of the Mamba-2 / latent-expert model: one
+    batched call that advances every slot's Mamba-2 state, routes every
+    slot's token together and writes its key/value row, the arena
+    donated as in ``slot_decode.lm``."""
+    import jax
+    import jax.numpy as jnp
+
+    from ...serving.lm import kvcache
+
+    model, variables = _served_nemotron_h(mesh)
+    replicated = _replicated(mesh)
+    arena = jax.device_put(kvcache.make_arena(model, 4, 32), replicated)
+    zeros = jax.device_put(jnp.zeros((4,), jnp.int32), replicated)
+    return ProgramSpec(
+        name="slot_decode.nemotron_h",
+        fn=kvcache.slot_decode,
+        args=(model, variables, zeros, arena, zeros, zeros),
+        jit_kwargs={
+            "static_argnums": 0,
+            "donate_argnums": (3,),
+            "out_shardings": replicated,
+        },
+        expect_donated=(3,),
+    )
+
+
+def prefill_nemotron_h(mesh) -> ProgramSpec:
+    """How the decoder prefills the Mamba-2 / latent-expert model: the
+    second chunk of a prompt (``start`` 16, static, the sixth argument
+    here as ``prefill_chunk.mla_moe``), resuming from the state, conv
+    inputs and key/value rows the first chunk left in the donated
+    one-slot cache."""
+    import jax
+    import jax.numpy as jnp
+
+    from ...serving.lm import kvcache
+
+    model, variables = _served_nemotron_h(mesh)
+    replicated = _replicated(mesh)
+    cache = jax.device_put(kvcache.make_arena(model, 1, 32), replicated)
+    tokens = jax.device_put(jnp.zeros((1, 16), jnp.int32), replicated)
+    n_real = jax.device_put(jnp.int32(25), replicated)
+    return ProgramSpec(
+        name="prefill.nemotron_h",
+        fn=kvcache.prefill_bucket,
+        args=(model, variables, tokens, cache, n_real, 16),
+        jit_kwargs={
+            "static_argnums": (0, 5),
+            "donate_argnums": (3,),
+            "out_shardings": replicated,
+        },
+        expect_donated=(3,),
+    )
+
+
 def serving_score(mesh) -> ProgramSpec:
     import jax
     import numpy as np
@@ -705,6 +785,8 @@ _BUILDERS: dict[str, Callable] = {
     "prefill_chunk.mla_moe": prefill_chunk_mla_moe,
     "slot_decode.jamba": slot_decode_jamba,
     "prefill.jamba": prefill_jamba,
+    "slot_decode.nemotron_h": slot_decode_nemotron_h,
+    "prefill.nemotron_h": prefill_nemotron_h,
     "serving.score": serving_score,
     "ops.fused_matmul.grad": fused_matmul_grad,
     "ops.fused_norm.grad": fused_norm_grad,

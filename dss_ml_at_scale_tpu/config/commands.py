@@ -1897,7 +1897,10 @@ def register_serve_lm(sub: argparse._SubParsersAction) -> None:
         "--model-config", default=None, metavar="JSON",
         help="architecture file with the published config.json keys: "
         "model_type jamba builds the hybrid state-space model "
-        "(models/jamba.py), any other a latent-attention expert model "
+        "(models/jamba.py), nemotron_h the hybrid Mamba-2 / latent-expert "
+        "model (models/nemotron_h.py, with num_layers, n_routed_experts "
+        "and vocab_size as held here), any other a latent-attention "
+        "expert model "
         "(models/mla_moe.py, with num_layers, n_routed_experts and "
         "vocab_size as held here, router_width and expert_offset beside "
         "them); served with random weights through the same decoder and "
@@ -1961,6 +1964,11 @@ def _cmd_serve_lm(args: argparse.Namespace) -> int:
                 from ..models.jamba import JambaLM
 
                 model = JambaLM.from_config(arch, attention=args.attention)
+            elif arch.get("model_type") == "nemotron_h":
+                from ..models.nemotron_h import NemotronHLM
+
+                model = NemotronHLM.from_config(
+                    arch, attention=args.attention)
             else:
                 model = MlaMoeLM.from_config(arch, attention=args.attention)
             variables = model.init(jax.random.key(args.seed))

@@ -53,6 +53,7 @@ import numpy as np
 from jax import lax
 
 from ..ops.flash_attention import attention_reference, flash_attention
+from .routing import expert_tile, route
 from .transformer import rms_norm
 
 # Leaves multiplied in float32 whatever the model's dtype: norm gains
@@ -452,40 +453,20 @@ class MlaMoeLM:
         stats)."""
         t, k = x.shape[0], self.num_experts_per_tok
         held = self.n_routed_experts
-        with jax.named_scope("moe_router"):
-            gates = jnp.matmul(
-                x32, w["router"]["kernel"], precision=lax.Precision.HIGHEST)
-            top_p, top_i = lax.top_k(jax.nn.softmax(gates, axis=-1), k)
-            if self.norm_topk_prob:
-                top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
-            weight = (top_p * self.routed_scaling_factor).reshape(-1)
-            local = top_i - self.expert_offset
-            here = (local >= 0) & (local < held) & valid[:, None]
-            # Assignments sorted by held expert; those of absent experts
-            # (and of rows that are not valid) sort past the last one.
-            expert = jnp.where(here, local, held).reshape(-1)
-            order = _cast(jnp.argsort(expert, stable=True), jnp.int32)
-            counts = jnp.sum(
-                expert[:, None] == jnp.arange(held)[None, :], axis=0,
-                dtype=jnp.int32)
-            starts = jnp.cumsum(counts, dtype=jnp.int32) - counts
-            n_valid = jnp.sum(valid, dtype=jnp.int32) * k
-            n_held = jnp.sum(counts, dtype=jnp.int32)
-            stats = jnp.concatenate([
-                jnp.stack([n_held, n_valid - n_held,
-                           jnp.sum(counts > 0, dtype=jnp.int32)]),
-                counts])
+        r = route(x32, w["router"]["kernel"], valid, k=k, held=held,
+                  offset=self.expert_offset, score="softmax",
+                  norm_topk=self.norm_topk_prob,
+                  scale=self.routed_scaling_factor)
+        order, weight, counts, starts = r.order, r.weight, r.counts, r.starts
         with jax.named_scope("moe_experts"):
-            # A token is in one expert's segment at most once, so one
-            # pass of min(T, tile) rows holds no token twice. A pass reads
-            # the expert's weights whatever its rows, and computes all of
-            # them: it takes at most twice a held expert's share of the
-            # T x k assignments, in whole 128-row tiles. A prefill chunk
-            # of 2,048 tokens, top-4 of 128 experts, gives a held expert
-            # 64: one pass of 128 rows, not of 256 (on one v5e chip the
-            # document cell served 7.6% more tokens a second with it).
-            tile = min(self.expert_tile, t,
-                       -(-2 * t * k // self.router_width // 128) * 128)
+            # A pass reads the expert's weights whatever its rows, and
+            # computes all of them: it takes at most twice a held expert's
+            # share of the T x k assignments, in whole 128-row tiles. A
+            # prefill chunk of 2,048 tokens, top-4 of 128 experts, gives a
+            # held expert 64: one pass of 128 rows, not of 256 (on one
+            # v5e chip the document cell served 7.6% more tokens a second
+            # with it).
+            tile = expert_tile(t, k, self.router_width, self.expert_tile)
             step = np.int32(tile)
             passes = (counts + (step - 1)) // step
             order = jnp.concatenate([order, jnp.zeros(tile, jnp.int32)])
@@ -500,7 +481,7 @@ class MlaMoeLM:
                         ew["down"]["kernel"])
 
                 out = lax.fori_loop(np.int32(0), passes[e], one_pass, out)
-        return out, stats
+        return out, r.stats
 
     def _ffn(self, x, valid, w):
         hid32 = rms_norm(x, w["ffn_norm"]["scale"], self.rms_norm_eps)

@@ -1,0 +1,100 @@
+"""Routing of an expert layer that holds a share of the experts.
+
+What the served expert models (``mla_moe.MlaMoeLM``, ``nemotron_h.
+NemotronHLM``) share: a float32 router over all ``router_width`` experts,
+the ``k`` best of each token, their weights, and then what this chip does
+with them. The layer is told which experts it holds (``offset``,
+``held``); an assignment to any other is left out (nothing stands in for
+it), and so is every assignment of a row that is not valid (an idle slot,
+a prompt's padding). The held ones are sorted by expert, so that each
+held expert can run over its own segment of the assignments.
+
+Two scores:
+
+- ``"softmax"``: ``p = softmax(x W)``; the ``k`` largest ``p``.
+- ``"sigmoid"``: ``s = sigmoid(x W)``; the ``k`` largest ``s + bias`` (the
+  selection bias, DeepSeek-V3's ``e_score_correction_bias``), weighted by
+  ``s`` itself.
+
+With ``norm_topk`` the ``k`` weights are divided by their sum; then all
+are multiplied by ``scale``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+
+@dataclasses.dataclass(frozen=True)
+class Routing:
+    """One expert layer's assignments of ``T`` rows, ``k`` each.
+
+    ``weight`` ``[T * k]`` float32 (row-major: assignment ``i`` is row
+    ``i // k``'s); ``order`` ``[T * k]`` int32, the assignments sorted by
+    held expert, those of absent experts and of rows that are not valid
+    past the last; ``counts`` and ``starts`` ``[held]`` int32, each held
+    expert's segment of ``order``; ``stats`` int32 ``[held assignments,
+    absent assignments, experts touched, per held expert...]``."""
+
+    weight: jax.Array
+    order: jax.Array
+    counts: jax.Array
+    starts: jax.Array
+    stats: jax.Array
+
+
+def route(x32, kernel, valid, *, k: int, held: int, offset: int = 0,
+          score: str = "softmax", bias=None, norm_topk: bool = True,
+          scale: float = 1.0) -> Routing:
+    """Route ``x32`` ``[T, d]`` (float32) through the router ``kernel``
+    ``[d, router_width]``; ``valid`` ``[T]`` bool. See the module
+    docstring."""
+    with jax.named_scope("moe_router"):
+        gates = jnp.matmul(x32, kernel, precision=lax.Precision.HIGHEST)
+        if score == "softmax":
+            top_p, top_i = lax.top_k(jax.nn.softmax(gates, axis=-1), k)
+        elif score == "sigmoid":
+            s = jax.nn.sigmoid(gates)
+            _, top_i = lax.top_k(s + bias, k)
+            top_p = jnp.take_along_axis(s, top_i, axis=-1)
+        else:
+            raise ValueError(f"unknown router score {score!r}")
+        if norm_topk:
+            total = jnp.sum(top_p, axis=-1, keepdims=True)
+            if score == "sigmoid":
+                total = total + 1e-20
+            top_p = top_p / total
+        weight = (top_p * scale).reshape(-1)
+        local = top_i - offset
+        here = (local >= 0) & (local < held) & valid[:, None]
+        # Assignments sorted by held expert; those of absent experts (and
+        # of rows that are not valid) sort past the last one.
+        expert = jnp.where(here, local, held).reshape(-1)
+        order = _int32(jnp.argsort(expert, stable=True))
+        counts = jnp.sum(expert[:, None] == jnp.arange(held)[None, :],
+                         axis=0, dtype=jnp.int32)
+        starts = jnp.cumsum(counts, dtype=jnp.int32) - counts
+        n_valid = jnp.sum(valid, dtype=jnp.int32) * k
+        n_held = jnp.sum(counts, dtype=jnp.int32)
+        stats = jnp.concatenate([
+            jnp.stack([n_held, n_valid - n_held,
+                       jnp.sum(counts > 0, dtype=jnp.int32)]),
+            counts])
+    return Routing(weight, order, counts, starts, stats)
+
+
+def expert_tile(t: int, k: int, router_width: int, most: int) -> int:
+    """Rows of one pass over a held expert's segment, for ``t`` rows of
+    ``k`` assignments each: at most ``most`` and ``t``, and at most
+    twice a held expert's expected share of the ``t x k`` assignments,
+    in whole 128-row tiles. A token is in one expert's segment at most
+    once, so a pass of at most ``t`` rows holds no token twice."""
+    return min(most, t, -(-2 * t * k // router_width // 128) * 128)
+
+
+def _int32(x):
+    return x if x.dtype == jnp.int32 else x.astype(jnp.int32)

@@ -200,6 +200,96 @@ def test_selective_scan_compiles_at_the_reason_cells_buckets(
     assert f"f32[{rows},5120]" in call.split("custom-call(")[0]
 
 
+@pytest.mark.parametrize("positions", [1024, 100])
+def test_ssd_scan_compiles_at_the_agent_cells_chunk(
+        one_chip, no_compile_cache, positions):
+    """The prefill's SSD kernel at Nemotron-3-Super's widths (128 heads
+    of 64, 8 groups of a 128 state) over a chunk of the agent cell, and a
+    length that is no multiple of the block; the op's first output shape
+    is what the benchmark's reader parses from the kernel's HLO text."""
+    import jax
+    import jax.numpy as jnp
+
+    from dss_ml_at_scale_tpu.ops.ssd_scan import ssd_scan
+
+    rows = -(-positions // 128) * 128
+
+    def shaped(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    compiled = _compile(
+        lambda x, dt, a, b, c, d, s: ssd_scan(x, dt, a, b, c, d, s,
+                                              interpret=False),
+        shaped(positions, 128, 64), shaped(positions, 128), shaped(128),
+        shaped(positions, 8, 128), shaped(positions, 8, 128), shaped(128),
+        shaped(128, 64, 128),
+    )
+    call = next(line for line in compiled.as_text().splitlines()
+                if "tpu_custom_call" in line and "custom-call(" in line)
+    assert "ssd_scan" in call.split(" = ")[0]
+    assert f"f32[128,64,{rows}]" in call.split("custom-call(")[0]
+
+
+@pytest.mark.parametrize("program", ["decode", "last_chunk"])
+def test_the_agent_cells_programs_compile_whole(one_chip, no_compile_cache,
+                                                monkeypatch, program):
+    """The agent cell's own programs at its sizes, all eleven layers:
+    the decode step over 32 slots of 9,216 positions, and the last chunk
+    of an 8,192-token prompt (start 7,168, the flash and SSD kernels in
+    it). The donated cache comes back aliased, and what the program needs
+    beside its arguments stays small."""
+    import importlib
+    import json
+    from pathlib import Path
+
+    import jax
+    import jax.numpy as jnp
+
+    from dss_ml_at_scale_tpu.models.nemotron_h import NemotronHLM
+    from dss_ml_at_scale_tpu.serving.lm import kvcache
+
+    for name in ("flash_attention", "ssd_scan"):
+        monkeypatch.setattr(
+            importlib.import_module(f"dss_ml_at_scale_tpu.ops.{name}"),
+            "resolve_interpret", lambda _: False)
+    cfg = json.loads((Path(__file__).resolve().parents[1] / "perfbench"
+                      / "configs" / "nemotron3_super_120b_ep8.json"
+                      ).read_text())
+    model = NemotronHLM.from_config(cfg)
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    variables = described(jax.eval_shape(
+        lambda: model.serving_variables(model.init(jax.random.key(0)))))
+    if program == "decode":
+        cache = described(jax.eval_shape(
+            lambda: kvcache.make_arena(model, 32, 9216)))
+        vec = jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip)
+        compiled = jax.jit(
+            kvcache.slot_decode, static_argnums=0, donate_argnums=(3,)
+        ).lower(model, variables, vec, cache, vec, vec).compile()
+    else:
+        cache = described(jax.eval_shape(
+            lambda: kvcache.make_arena(model, 1, 9216)))
+        tokens = jax.ShapeDtypeStruct((1, 1024), jnp.int32,
+                                      sharding=one_chip)
+        n_real = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+        compiled = jax.jit(
+            kvcache.prefill_bucket, static_argnums=0,
+            static_argnames=("start",), donate_argnums=(3,)
+        ).lower(model, variables, tokens, cache, n_real,
+                start=7168).compile()
+        assert "ssd_scan" in compiled.as_text()
+    slabs = sum(a.size * a.dtype.itemsize
+                for a in jax.tree_util.tree_leaves(cache))
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= slabs
+    assert memory.temp_size_in_bytes < 1 << 30
+
+
 @pytest.mark.parametrize("hw,k,n", BN_STAGES)
 @pytest.mark.parametrize("with_res", [False, True], ids=["plain", "residual"])
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])

@@ -451,6 +451,34 @@ def test_experts_touched_counts_the_held_experts_that_got_a_token(bench):
     assert all(n <= CFG["num_layers"] for n in per)
 
 
+def test_the_shared_routing_leaves_the_outputs_and_stats_as_they_were():
+    """The routing (held-range test, sort, counts, stats) moved into
+    ``models/routing.py``, shared with the Mamba-2 expert model; this
+    model's programs compute what they computed before it moved: the
+    numbers below were read from the expanded path before the move, on
+    the CPU in float32 (the audit's pinned program hashes hold the
+    decode and prefill programs too)."""
+    model = MlaMoeLM(
+        vocab_size=128, hidden_size=64, num_layers=2, num_attention_heads=4,
+        q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=8,
+        qk_rope_head_dim=8, v_head_dim=16, moe_intermediate_size=32,
+        n_routed_experts=4, router_width=8, expert_offset=2,
+        num_experts_per_tok=2, routed_scaling_factor=1.5,
+        attention="reference", dtype=jnp.float32)
+    variables = model.init(jax.random.key(3))
+    toks = jnp.asarray(np.random.default_rng(4).integers(0, 128, 24),
+                       jnp.int32)
+    logits, stats = jax.jit(model.logits)(variables, toks)
+    assert np.asarray(stats).tolist() == [42, 54, 7, 6, 23, 5, 8]
+    np.testing.assert_allclose(np.asarray(logits)[[0, 11, 23], :4], [
+        [-0.9319356083869934, -0.838961124420166, 0.36065036058425903,
+         -0.7280142307281494],
+        [-0.7318923473358154, 1.3074743747711182, 1.774648904800415,
+         -1.255831241607666],
+        [-0.004573418293148279, 1.2440720796585083, -0.7181130647659302,
+         -0.10693216323852539]], rtol=0, atol=1e-6)
+
+
 def test_release_frees_each_wide_leaf_once_it_is_cast(bench):
     model = model_of(dtype=jnp.bfloat16)
     variables = variables_of(bench)
