@@ -24,9 +24,10 @@ mixer a layer and no separate MLP; every norm is RMSNorm (eps
   ``relu(h W_up)^2 W_down`` at full width. The layer is told which
   experts it holds (``expert_offset``, ``n_routed_experts``): what the
   absent ones would add is left out, nothing stands in for them. The
-  held experts are stacked (``[E, latent, width]``) and one loop, traced
-  once, walks the passes over their segments of the sorted assignments:
-  an expert nobody chose is not read.
+  held experts are stacked (``[E, latent, width]``) and one grouped
+  kernel (:mod:`..ops.grouped_experts`) runs them over their segments of
+  the sorted assignments: each touched expert's matrices are read once a
+  layer, an expert nobody chose not at all.
 - **Attention**: query heads over fewer key/value heads, ``head_dim``
   each, no positional encoding of any kind, scale ``head_dim^-0.5``.
 - An untied head: final norm, then ``lm_head``, float32 logits.
@@ -65,8 +66,9 @@ import numpy as np
 from jax import lax
 
 from ..ops.flash_attention import attention_reference, flash_attention
+from ..ops.grouped_experts import grouped_experts
 from ..ops.ssd_scan import ssd_scan, ssd_scan_reference
-from .routing import expert_tile, route
+from .routing import route
 from .transformer import rms_norm
 
 # Leaves held in float32 whatever the model's dtype: norm gains, the
@@ -75,8 +77,6 @@ from .transformer import rms_norm
 _FLOAT32_ENDINGS = (("scale",), ("bias",), ("conv", "kernel"),
                     ("router", "kernel"))
 _KINDS = {"M": "mamba", "E": "moe", "*": "attn"}
-# Most rows of one pass over a held expert (``routing.expert_tile``).
-EXPERT_TILE = 256
 
 
 def layer_kinds(pattern: str) -> tuple:
@@ -578,31 +578,12 @@ class NemotronHLM:
 
     def _experts(self, u, r, experts):
         """The held experts over their segments of the sorted assignments
-        ``r``, in the latent ``u`` ``[T, latent]``: one loop over every
-        pass of every expert (an expert with no rows has none), each pass
-        ``tile`` rows through the expert, added into the result at their
-        tokens by their weights. Returns ``[T, latent]`` float32."""
-        t, k = u.shape[0], self.num_experts_per_tok
-        tile = expert_tile(t, k, self.router_width, EXPERT_TILE)
-        step, lane = np.int32(tile), jnp.arange(tile, dtype=jnp.int32)
-        passes = (r.counts + (step - 1)) // step
-        ends = jnp.cumsum(passes, dtype=jnp.int32)
-        order = jnp.concatenate([r.order, jnp.zeros(tile, jnp.int32)])
-
-        def one_pass(i, acc):
-            e = jnp.sum(ends <= i, dtype=jnp.int32)
-            first = (i - (ends[e] - passes[e])) * step
-            idx = lax.dynamic_slice(order, (r.starts[e] + first,), (tile,))
-            token = idx // np.int32(k)
-            y = self._relu2(u[token], experts["up"][e], experts["down"][e])
-            # Rows past the segment's end are another expert's: computed,
-            # weighted 0.
-            scale = jnp.where(first + lane < r.counts[e], r.weight[idx],
-                              np.float32(0))
-            return acc.at[token].add(y * scale[:, None])
-
-        return lax.fori_loop(np.int32(0), ends[-1], one_pass,
-                             jnp.zeros(u.shape, jnp.float32))
+        ``r``, in the latent ``u`` ``[T, latent]``: one grouped kernel
+        call (:mod:`..ops.grouped_experts`), each touched expert's two
+        matrices read once. Returns ``[T, latent]`` float32."""
+        return grouped_experts(u, experts["up"], experts["down"], r.counts,
+                               r.starts, r.order, r.weight,
+                               k=self.num_experts_per_tok)
 
     def _embed(self, params, ids):
         with jax.named_scope("embed"):

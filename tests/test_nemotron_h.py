@@ -45,8 +45,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from jax import lax
+
 from dss_ml_at_scale_tpu import telemetry
 from dss_ml_at_scale_tpu.models.nemotron_h import NemotronHLM, layer_kinds
+from dss_ml_at_scale_tpu.models.routing import expert_tile
 from dss_ml_at_scale_tpu.ops.ssd_scan import ssd_scan, ssd_scan_reference
 from dss_ml_at_scale_tpu.serving.lm import (
     LMConfig,
@@ -394,6 +397,76 @@ def test_two_slots_decode_together_as_each_alone(variables):
         for s in seqs:
             one = step(alone[s], {s: feeds[s]})
             assert np.max(np.abs(both[s] - one[s])) < SAME_TOL, (s, k)
+
+
+def _loop_experts(self, u, r, experts):
+    """The per-pass loop that the grouped kernel replaced, kept as its
+    reference: one ``fori_loop`` over every pass of every held expert,
+    each pass at most 256 rows of the expert's segment
+    (``routing.expert_tile``), gathered by token, through the expert and
+    added at their tokens by their weights."""
+    t, k = u.shape[0], self.num_experts_per_tok
+    tile = expert_tile(t, k, self.router_width, 256)
+    step, lane = np.int32(tile), jnp.arange(tile, dtype=jnp.int32)
+    passes = (r.counts + (step - 1)) // step
+    ends = jnp.cumsum(passes, dtype=jnp.int32)
+    order = jnp.concatenate([r.order, jnp.zeros(tile, jnp.int32)])
+
+    def one_pass(i, acc):
+        e = jnp.sum(ends <= i, dtype=jnp.int32)
+        first = (i - (ends[e] - passes[e])) * step
+        idx = lax.dynamic_slice(order, (r.starts[e] + first,), (tile,))
+        token = idx // np.int32(k)
+        y = self._relu2(u[token], experts["up"][e], experts["down"][e])
+        scale = jnp.where(first + lane < r.counts[e], r.weight[idx],
+                          np.float32(0))
+        return acc.at[token].add(y * scale[:, None])
+
+    return lax.fori_loop(np.int32(0), ends[-1], one_pass,
+                         jnp.zeros(u.shape, jnp.float32))
+
+
+def test_the_expert_kernel_gives_the_per_pass_loops_outputs(served,
+                                                             monkeypatch):
+    """A prompt's second chunk (rows 16-31, 27 real, resuming from the
+    first) and a decode step over six slots (two idle), through the
+    grouped kernel and again through the per-pass loop it replaced: the
+    logits, the stats and every cache slab agree to ``SAME_TOL``."""
+    model = model_of(attention="reference")
+    padded = jnp.asarray([tokens_of(32, 31)])
+    tokens = jnp.asarray(tokens_of(6, 32), jnp.int32)
+    pos = jnp.asarray([0, 3, 5, 0, 7, 9], jnp.int32)
+
+    def run():
+        cache = kvcache.make_arena(model, 1, 64)
+        for start in (0, 16):
+            logits, stats, cache = jax.jit(
+                lambda v, t, c, start=start: kvcache.prefill_bucket(
+                    model, v, t, c, jnp.int32(27), start=start)
+            )(served, padded[:, start:start + 16], cache)
+        step = jax.jit(lambda v, t, a, p: model.decode_slots(v, t, a, p))(
+            served, tokens, cache_of_slots(), pos)
+        return (logits, stats, cache), step
+
+    def cache_of_slots():
+        arena = kvcache.make_arena(model, 6, 64)
+        rng = np.random.default_rng(6)
+        return jax.tree_util.tree_map(
+            lambda a: jnp.asarray(0.1 * rng.standard_normal(a.shape),
+                                  a.dtype), arena)
+
+    kernel = run()
+    monkeypatch.setattr(NemotronHLM, "_experts", _loop_experts)
+    loop = run()
+    for got, want in zip(jax.tree_util.tree_leaves(kernel),
+                         jax.tree_util.tree_leaves(loop)):
+        if jnp.issubdtype(got.dtype, jnp.integer):
+            assert np.array_equal(np.asarray(got), np.asarray(want))
+        else:
+            assert np.max(np.abs(np.asarray(got) - np.asarray(want))) \
+                < SAME_TOL
+    chunk_stats, step_stats = kernel[0][1], kernel[1][1]
+    assert int(chunk_stats[0]) > 0 and int(step_stats[0]) > 0
 
 
 # -- the kernel ---------------------------------------------------------------
