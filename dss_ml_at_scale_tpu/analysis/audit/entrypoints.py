@@ -358,7 +358,8 @@ def _served_mla_moe(mesh):
         llama_4_scaling_beta=0.1, attention="reference",
     )
     return model, jax.device_put(
-        model.init(jax.random.key(0)), _replicated(mesh)
+        model.serving_variables(model.init(jax.random.key(0))),
+        _replicated(mesh),
     )
 
 

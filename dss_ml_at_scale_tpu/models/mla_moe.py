@@ -24,9 +24,12 @@ anywhere, an untied output head, and per layer
   and adds the part of the result its own experts give. What absent
   experts would have added is left out; nothing stands in for them.
   No token is dropped whatever the imbalance: assignments are sorted by
-  expert and each held expert runs over its own segment in tiles, as
-  many as it got (none where it got none, so a decode step reads only
-  the experts it touched).
+  expert and one gated grouped kernel call (:mod:`..ops.grouped_experts`)
+  runs each held expert over its own segment, each touched expert's
+  three matrices streamed once a call (none where it got no row, so a
+  decode step reads only the experts it touched). Each layer's held
+  experts are one stacked leaf a matrix: ``serving_variables`` stacks
+  them, and every program reads the stacked tree.
 
 The model is a frozen dataclass (hashable: the serving programs take it
 as their static argument) over a plain tree of arrays; it gives the
@@ -42,7 +45,6 @@ layers, from which the decoder feeds the ``lm_moe_*`` counters.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import json
 import math
 from typing import Any
@@ -53,7 +55,8 @@ import numpy as np
 from jax import lax
 
 from ..ops.flash_attention import attention_reference, flash_attention
-from .routing import expert_tile, route
+from ..ops.grouped_experts import gated_experts
+from .routing import route, stack_held
 from .transformer import rms_norm
 
 # Leaves multiplied in float32 whatever the model's dtype: norm gains
@@ -132,7 +135,6 @@ class MlaMoeLM:
     llama_4_scaling_beta: float = 0.0
     attention: str = "flash"     # the prefill's: "flash" | "reference"
     dtype: Any = jnp.bfloat16
-    expert_tile: int = 256       # most rows of one pass over a held expert
 
     cache_kind = "latent"
 
@@ -279,10 +281,13 @@ class MlaMoeLM:
 
     def serving_variables(self, variables, *, release: bool = False):
         """``variables`` with each leaf at the width the model multiplies
-        it in: cast once here, leaf by leaf. With ``release`` each wider
-        original is deleted from the device as soon as its narrow copy
-        exists (the caller hands its tree over and reads it no more), so
-        the two trees are never alive together."""
+        it in (cast once here, leaf by leaf) and each layer's held experts
+        stacked, ``experts/gate`` and ``experts/up`` ``[E, d, F]``,
+        ``experts/down`` ``[E, F, d]``; a tree stacked already stays as it
+        is. With ``release`` each original is deleted from the device as
+        soon as what replaces it exists (the caller hands its tree over
+        and reads it no more), so the two trees are never alive together:
+        the stacking holds one layer's experts twice at most."""
 
         def at_width(path, leaf):
             name = "/".join(str(getattr(k, "key", k)) for k in path)
@@ -296,7 +301,19 @@ class MlaMoeLM:
                 leaf.delete()
             return narrow
 
-        return jax.tree_util.tree_map_with_path(at_width, variables)
+        tree = jax.tree_util.tree_map_with_path(at_width, variables)
+        params = dict(tree["params"])
+        for i in range(self.num_layers):
+            layer = dict(params[f"layer_{i}"])
+            if "expert_0" not in layer:
+                continue
+            parts = [layer.pop(f"expert_{e}")
+                     for e in range(self.n_routed_experts)]
+            layer["experts"] = {
+                name: stack_held([p[name]["kernel"] for p in parts], release)
+                for name in ("gate", "up", "down")}
+            params[f"layer_{i}"] = layer
+        return {**tree, "params": params}
 
     # -- the cache -----------------------------------------------------
 
@@ -449,38 +466,18 @@ class MlaMoeLM:
         ``x`` ``[T, d]`` (normed; ``x32`` the same before it was rounded
         to ``dtype``: the router's input), ``valid`` ``[T]`` bool: a row
         that is not valid (an idle slot, a prompt's padding) is routed
-        nowhere and counted nowhere. Returns (``[T, d]`` float32,
-        stats)."""
-        t, k = x.shape[0], self.num_experts_per_tok
-        held = self.n_routed_experts
-        r = route(x32, w["router"]["kernel"], valid, k=k, held=held,
+        nowhere and counted nowhere. ``w`` the layer's leaves, its held
+        experts stacked. Returns (``[T, d]`` float32, stats)."""
+        r = route(x32, w["router"]["kernel"], valid,
+                  k=self.num_experts_per_tok, held=self.n_routed_experts,
                   offset=self.expert_offset, score="softmax",
                   norm_topk=self.norm_topk_prob,
                   scale=self.routed_scaling_factor)
-        order, weight, counts, starts = r.order, r.weight, r.counts, r.starts
+        experts = w["experts"]
         with jax.named_scope("moe_experts"):
-            # A pass reads the expert's weights whatever its rows, and
-            # computes all of them: it takes at most twice a held expert's
-            # share of the T x k assignments, in whole 128-row tiles. A
-            # prefill chunk of 2,048 tokens, top-4 of 128 experts, gives a
-            # held expert 64: one pass of 128 rows, not of 256 (on one
-            # v5e chip the document cell served 7.6% more tokens a second
-            # with it).
-            tile = expert_tile(t, k, self.router_width, self.expert_tile)
-            step = np.int32(tile)
-            passes = (counts + (step - 1)) // step
-            order = jnp.concatenate([order, jnp.zeros(tile, jnp.int32)])
-            out = jnp.zeros((t, x.shape[1]), jnp.float32)
-            for e in range(held):
-                ew = w[f"expert_{e}"]
-
-                def one_pass(i, acc, e=e, ew=ew):
-                    return _expert_pass(
-                        self, tile, i, acc, x, order, weight, starts[e],
-                        counts[e], ew["gate"]["kernel"], ew["up"]["kernel"],
-                        ew["down"]["kernel"])
-
-                out = lax.fori_loop(np.int32(0), passes[e], one_pass, out)
+            out = gated_experts(x, experts["gate"], experts["up"],
+                                experts["down"], r,
+                                k=self.num_experts_per_tok)
         return out, r.stats
 
     def _ffn(self, x, valid, w):
@@ -614,24 +611,6 @@ class MlaMoeLM:
         x, _, stats = self._expanded(
             params, tokens, jnp.ones(tokens.shape[0], bool))
         return self._head(x, params), stats
-
-
-@functools.partial(jax.jit, static_argnums=(0, 1))
-def _expert_pass(model, tile, i, acc, x, order, weight, start, count, gate,
-                 up, down):
-    """Pass ``i`` over one held expert's segment of the sorted
-    assignments (``start``, ``count``): ``tile`` rows of ``x`` through the
-    expert, added into ``acc`` at their tokens by their weights. Jitted,
-    so a program traces it once a shape, not once an expert a layer."""
-    step, lane = np.int32(tile), jnp.arange(tile, dtype=jnp.int32)
-    idx = lax.dynamic_slice(order, (start + i * step,), (tile,))
-    inside = (i * step + lane) < count
-    token = idx // np.int32(model.num_experts_per_tok)
-    y = model._gated(x[token], gate, up, down)
-    # Rows past the segment's end are another expert's: computed,
-    # weighted 0.
-    scale = jnp.where(inside, weight[idx], np.float32(0))
-    return acc.at[token].add(y * scale[:, None])
 
 
 def _cast(x, dtype):

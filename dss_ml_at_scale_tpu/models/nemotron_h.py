@@ -68,7 +68,7 @@ from jax import lax
 from ..ops.flash_attention import attention_reference, flash_attention
 from ..ops.grouped_experts import grouped_experts
 from ..ops.ssd_scan import ssd_scan, ssd_scan_reference
-from .routing import route
+from .routing import route, stack_held
 from .transformer import rms_norm
 
 # Leaves held in float32 whatever the model's dtype: norm gains, the
@@ -341,7 +341,7 @@ class NemotronHLM:
             parts = [moe.pop(f"expert_{e}")
                      for e in range(self.n_routed_experts)]
             moe["experts"] = {
-                name: _stack([p[name]["kernel"] for p in parts], release)
+                name: stack_held([p[name]["kernel"] for p in parts], release)
                 for name in ("up", "down")}
             params[f"layer_{i}"] = {**params[f"layer_{i}"], "moe": moe}
         return {**tree, "params": params}
@@ -679,15 +679,6 @@ class NemotronHLM:
         x, _, stats = self._run(params, tokens, self.init_cache(1, t), 0,
                                 jnp.int32(t))
         return self._head(x, params), stats
-
-
-def _stack(leaves, release: bool):
-    stacked = jnp.stack(leaves)
-    if release:
-        stacked.block_until_ready()
-        for leaf in leaves:
-            leaf.delete()
-    return stacked
 
 
 def _cast(x, dtype):

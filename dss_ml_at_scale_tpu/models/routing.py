@@ -38,13 +38,16 @@ class Routing:
     held expert, those of absent experts and of rows that are not valid
     past the last; ``counts`` and ``starts`` ``[held]`` int32, each held
     expert's segment of ``order``; ``stats`` int32 ``[held assignments,
-    absent assignments, experts touched, per held expert...]``."""
+    absent assignments, experts touched, per held expert...]``;
+    ``expert`` ``[T * k]`` int32, each assignment's held expert (``held``
+    where it is not held)."""
 
     weight: jax.Array
     order: jax.Array
     counts: jax.Array
     starts: jax.Array
     stats: jax.Array
+    expert: jax.Array
 
 
 def route(x32, kernel, valid, *, k: int, held: int, offset: int = 0,
@@ -84,7 +87,20 @@ def route(x32, kernel, valid, *, k: int, held: int, offset: int = 0,
             jnp.stack([n_held, n_valid - n_held,
                        jnp.sum(counts > 0, dtype=jnp.int32)]),
             counts])
-    return Routing(weight, order, counts, starts, stats)
+    return Routing(weight, order, counts, starts, stats, expert)
+
+
+def stack_held(leaves, release: bool):
+    """One matrix of each held expert, ``leaves``, as one ``[held, ...]``
+    leaf, the held experts' leaf that the grouped kernels read. With
+    ``release`` each leaf is deleted from the device once the stack
+    exists."""
+    stacked = jnp.stack(leaves)
+    if release:
+        stacked.block_until_ready()
+        for leaf in leaves:
+            leaf.delete()
+    return stacked
 
 
 def expert_tile(t: int, k: int, router_width: int, most: int) -> int:

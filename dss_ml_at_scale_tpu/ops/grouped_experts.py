@@ -1,6 +1,9 @@
-"""Pallas TPU grouped-expert kernel: one expert layer's held experts over
-their segments of the sorted assignments, in one call.
+"""Pallas TPU grouped-expert kernels: one expert layer's held experts over
+their segments of the sorted assignments, in one call. Two forms, one a
+kind of expert, each with its own body and call: which one a model runs
+follows from the weights it holds (a ``gate`` or none).
 
+**The ungated form** (``grouped_experts``; Nemotron-H's latent experts).
 For rows ``u`` ``[T, L]`` (the layer's latent input) and the held
 experts' stacked matrices ``up`` ``[E, L, F]`` and ``down`` ``[E, F, L]``,
 the result is, at each token's row,
@@ -43,6 +46,45 @@ Design (pallas_guide.md patterns):
   layer traces and lowers it once a shape. The call is named
   ``moe_experts``, which is how a device trace names its operation.
 
+**The gated form** (``gated_experts``; the latent-attention model's SiLU
+experts). For rows ``x`` ``[T, d]`` and stacked ``gate``, ``up``
+``[E, d, F]`` and ``down`` ``[E, F, d]``,
+
+    out[t] = sum over held assignments a of token t:
+             weight[a] * ((silu(x[t] gate[e_a]) * (x[t] up[e_a])) down[e_a])
+
+with ``silu(x gate) * (x up)`` in float32, cast to the operands' width,
+times ``down`` with float32 accumulation, and the experts added into a
+float32 sum in expert order: the rounding of the per-expert loop it
+replaces. At the widths it serves (d 4,096, F 2,048) one expert's three
+matrices are 48 MiB and the rows of a whole prompt as float32 128 MiB, so
+neither the weights whole nor the rows stay in VMEM:
+
+- One grid step. A **visit** is a touched expert's segment in tiles of
+  ``tile`` rows (``row_tile``): one visit an expert wherever its segment
+  fits one tile, and none for an expert with no row. The kernel walks
+  the visits in expert order and, in each, F in blocks of ``BLOCK_F``
+  columns: ``silu(x gate_f) * (x up_f)`` is separable over F, and
+  ``down_f``'s products add up over the blocks in float32. Each block's
+  ``gate``, ``up`` and ``down`` come by DMA into one of two buffers, the
+  next block's fetch started before the current block's products (the
+  rest of this expert, or the next visit's first block), so the weights
+  stream once a visit under the products.
+- The rows come from HBM one DMA a row into the visit's tile, fetched
+  under the previous visit's products. The layer's input is handed over
+  as ``[T, d / 128, 128]``, so a row is whole tiles (a one-row slice of a
+  2-D array in HBM is not aligned to its tiling).
+- Each row of a finished visit is scaled by its weight and written to its
+  token's row of the output, one DMA a row: by the token's first held
+  expert as it is, by a later one added to the sum read back (a row's
+  code carries which). A visit's writes land before the next visit reads
+  back or refills its buffer. A token that no held expert took is never
+  written; ``gated_experts`` gives it 0 outside the kernel.
+- Each visit's expert and row count, each row's token code and weight
+  are scalar-prefetched into SMEM. Visits, blocks and rows are loops
+  traced once, and ``_gated_call`` is jitted: a program lowers the
+  kernel once a shape. The call is named ``moe_experts`` too.
+
 On a CPU backend (tests) the kernel runs in Pallas interpret mode
 (``_pallas.resolve_interpret``); on ``tpu`` it is always compiled.
 """
@@ -61,9 +103,11 @@ from ._pallas import resolve_interpret
 
 # Most rows of one tile of an expert's segment.
 TILE = 128
-# Scoped VMEM the call may use: two buffers of one expert's two matrices
-# (22 MB at Nemotron-3-Super's widths), the resident rows and output,
-# and a tile's temporaries.
+# Scoped VMEM a call may use. The ungated form: two buffers of one
+# expert's two matrices (22 MB at Nemotron-3-Super's widths), the resident
+# rows and output, and a tile's temporaries. The gated form: two buffers
+# of a block's three matrices (24 MiB at Mistral-Small-4's widths) and a
+# tile's rows, sums and temporaries.
 VMEM_LIMIT = 96 * 1024 * 1024
 
 
@@ -206,3 +250,242 @@ def grouped_experts(u, up, down, counts, starts, order, weight, *, k: int,
         rows.reshape(padded // 8, 8, latent), up.astype(u.dtype),
         down.astype(u.dtype), tile=row_tile(t), interpret=interpret)
     return out.reshape(padded, latent)[:t]
+
+
+# -- the gated form -----------------------------------------------------------
+
+# Columns of one block of the intermediate width F, where it divides F.
+BLOCK_F = 512
+# Buffers of the blocks' pipeline: the fetch of the block this many less
+# one ahead runs under each block's products. On one v5e chip a third
+# buffer read no faster at the decode step's shape or a chunk's.
+BUFFERS = 2
+
+
+def _gated_kernel(nv_ref, vexp_ref, vrows_ref, code_ref, w_ref, x_hbm,
+                  gate_hbm, up_hbm, down_hbm, out_hbm, xbuf, xs, wg, wu, wd,
+                  acc, obuf, ibuf, sem_w, sem_x, sem_r, sem_o, *, tile,
+                  block, blocks):
+    from jax.experimental.pallas import tpu as pltpu
+
+    nv = nv_ref[0]
+    zero, one = np.int32(0), np.int32(1)
+    last = np.int32(blocks - 1)
+
+    def weights(v, f, slot):
+        """Block ``f`` of visit ``v``'s expert: its three DMAs."""
+        e = vexp_ref[v]
+        cols = pl.ds(pl.multiple_of(f * np.int32(block), block), block)
+        return (pltpu.make_async_copy(gate_hbm.at[e, :, cols], wg.at[slot],
+                                      sem_w.at[slot]),
+                pltpu.make_async_copy(up_hbm.at[e, :, cols], wu.at[slot],
+                                      sem_w.at[slot]),
+                pltpu.make_async_copy(down_hbm.at[e, cols, :], wd.at[slot],
+                                      sem_w.at[slot]))
+
+    def rows(v, body):
+        """``body(r, code)`` for each row ``r`` of visit ``v``."""
+        def one_row(r, c):
+            body(r, code_ref[v * np.int32(tile) + r])
+            return c
+
+        lax.fori_loop(zero, vrows_ref[v], one_row, None)
+
+    # A row's code is its token twice, plus one where an earlier expert
+    # of the token wrote its row already.
+    def fetch(r, code):
+        return pltpu.make_async_copy(x_hbm.at[code >> one], xbuf.at[r],
+                                     sem_x.at[0])
+
+    def read(r, code):
+        return pltpu.make_async_copy(out_hbm.at[code >> one], ibuf.at[r],
+                                     sem_r.at[0])
+
+    def write(r, code):
+        return pltpu.make_async_copy(obuf.at[r], out_hbm.at[code >> one],
+                                     sem_o.at[0])
+
+    def if_back(fn):
+        def body(r, code):
+            @pl.when((code & one) == one)
+            def _():
+                fn(r, code)
+        return body
+
+    def combine(v):
+        """Visit ``v``'s rows, times their weights, into their tokens'
+        rows of the output."""
+        # The visit before this one wrote its rows from ``obuf``: they
+        # land before ``obuf`` is filled again or any is read back.
+        @pl.when(v > zero)
+        def _():
+            rows(v - one, lambda r, code: write(r, code).wait())
+
+        obuf[...] = acc[...].reshape(obuf.shape)
+        rows(v, if_back(lambda r, code: read(r, code).start()))
+        rows(v, if_back(lambda r, code: read(r, code).wait()))
+
+        def add(r, code):
+            y = obuf[r] * w_ref[v * np.int32(tile) + r]
+            obuf[r] = jnp.where((code & one) == one, ibuf[r] + y, y)
+            write(r, code).start()
+
+        rows(v, add)
+
+    def advance(v, f, slot):
+        """The block after block ``f`` of visit ``v``, and its buffer."""
+        end = f == last
+        return (jnp.where(end, v + one, v), jnp.where(end, zero, f + one),
+                jnp.where(slot == np.int32(BUFFERS - 1), zero, slot + one))
+
+    total = nv * np.int32(blocks)
+
+    def one_block(b, carry):
+        # Block ``b`` of the walk is block ``f`` of visit ``v``, in buffer
+        # ``slot``; ``BUFFERS - 1`` blocks on is block ``fa`` of ``va``.
+        v, f, slot, va, fa, sa = carry
+
+        # That fetch goes out before this block's products, so the reads
+        # run on under the products and under a visit's ends.
+        @pl.when(b + np.int32(BUFFERS - 1) < total)
+        def _():
+            for cp in weights(va, fa, sa):
+                cp.start()
+
+        @pl.when(f == zero)
+        def _():
+            rows(v, lambda r, code: fetch(r, code).wait())
+            xs[...] = xbuf[...].reshape(xs.shape)
+
+            @pl.when(v + one < nv)
+            def _():
+                rows(v + one, lambda r, code: fetch(r, code).start())
+
+        for cp in weights(v, f, slot):
+            cp.wait()
+        # Rows past the visit's own are another visit's (or nothing yet):
+        # computed, never written.
+        x = xs[...]
+        mid = (jax.nn.silu(jnp.dot(x, wg[slot],
+                                   preferred_element_type=jnp.float32))
+               * jnp.dot(x, wu[slot], preferred_element_type=jnp.float32))
+        part = jnp.dot(mid.astype(x.dtype), wd[slot],
+                       preferred_element_type=jnp.float32)
+
+        @pl.when(f == zero)
+        def _():
+            acc[...] = part
+
+        @pl.when(f > zero)
+        def _():
+            acc[...] += part
+
+        @pl.when(f == last)
+        def _():
+            combine(v)
+
+        return (*advance(v, f, slot), *advance(va, fa, sa))
+
+    @pl.when(nv > zero)
+    def _run():
+        ahead = (zero, zero, zero)
+        for i in range(BUFFERS - 1):
+            @pl.when(np.int32(i) < total)
+            def _():
+                for cp in weights(*ahead):
+                    cp.start()
+
+            ahead = advance(*ahead)
+        rows(zero, lambda r, code: fetch(r, code).start())
+        lax.fori_loop(zero, total, one_block, (zero, zero, zero, *ahead))
+        rows(nv - one, lambda r, code: write(r, code).wait())
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def _gated_call(nv, vexp, vrows, code, w, x, gate, up, down, *, tile,
+                interpret):
+    from jax.experimental.pallas import tpu as pltpu
+
+    t, n, lanes = x.shape
+    experts, d, width = gate.shape
+    block = BLOCK_F if width % BLOCK_F == 0 else width
+    any_space = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        functools.partial(_gated_kernel, tile=tile, block=block,
+                          blocks=width // block),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(1,),
+            in_specs=[any_space] * 4,
+            out_specs=any_space,
+            scratch_shapes=[
+                pltpu.VMEM((tile, n, lanes), x.dtype),        # rows fetched
+                pltpu.VMEM((tile, d), x.dtype),               # rows as one
+                pltpu.VMEM((BUFFERS, d, block), x.dtype),     # gate blocks
+                pltpu.VMEM((BUFFERS, d, block), x.dtype),     # up blocks
+                pltpu.VMEM((BUFFERS, block, d), x.dtype),     # down blocks
+                pltpu.VMEM((tile, d), jnp.float32),           # sum over F
+                pltpu.VMEM((tile, n, lanes), jnp.float32),    # rows out
+                pltpu.VMEM((tile, n, lanes), jnp.float32),    # rows back
+                pltpu.SemaphoreType.DMA((BUFFERS,)),
+                pltpu.SemaphoreType.DMA((1,)),
+                pltpu.SemaphoreType.DMA((1,)),
+                pltpu.SemaphoreType.DMA((1,)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((t, n, lanes), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        name="moe_experts",
+    )(nv, vexp, vrows, code, w, x, gate, up, down)
+
+
+def gated_experts(x, gate, up, down, r, *, k: int,
+                  interpret: bool | None = None):
+    """The held gated experts of one layer over their segments of the
+    sorted assignments, as the gated kernel.
+
+    ``x`` ``[T, d]``; ``gate``, ``up`` ``[E, d, F]`` and ``down``
+    ``[E, F, d]`` at ``x``'s width; ``r`` the layer's
+    :class:`..models.routing.Routing` (``counts``, ``starts``, ``order``,
+    ``weight`` and ``expert``). Returns ``[T, d]`` float32."""
+    interpret = resolve_interpret(interpret)
+    i32, zero = jnp.int32, np.int32(0)
+    t, d = x.shape
+    experts = gate.shape[0]
+    tile = row_tile(t)
+    step = np.int32(tile)
+    visits = experts + -(-t * k // tile)
+    # Each touched expert's segment in tiles of ``tile`` rows, one visit a
+    # tile, in expert order.
+    tiles = (r.counts + step - np.int32(1)) // step
+    ends = jnp.cumsum(tiles, dtype=i32)
+    v = jnp.arange(visits, dtype=i32)
+    vexp = jnp.minimum(jnp.sum(v[:, None] >= ends[None, :], axis=1,
+                               dtype=i32), np.int32(experts - 1))
+    j = v - (ends[vexp] - tiles[vexp])
+    vrows = jnp.where(v < ends[-1],
+                      jnp.clip(r.counts[vexp] - j * step, zero, step), zero)
+    lane = jnp.arange(tile, dtype=i32)
+    live = lane[None, :] < vrows[:, None]
+    a = r.order[jnp.where(live, (r.starts[vexp] + j * step)[:, None]
+                          + lane[None, :], zero)]
+    # Whether an earlier expert of the same token was added before: that
+    # row is read back and this expert added to it; the token's first
+    # expert writes its row. A token no held expert took stays 0.
+    mine = r.expert.reshape(t, k)
+    held = mine < experts
+    earlier = jnp.any(held[:, None, :] & (mine[:, None, :] < mine[:, :, None]),
+                      axis=-1).reshape(-1)
+    code = jnp.where(live, (a // np.int32(k)) * np.int32(2)
+                     + earlier[a].astype(i32), zero)
+    w = jnp.where(live, r.weight[a], np.float32(0))
+    lanes = 128 if d % 128 == 0 else d
+    out = _gated_call(
+        ends[-1:], vexp, vrows, code.reshape(-1), w.reshape(-1),
+        x.reshape(t, d // lanes, lanes), gate.astype(x.dtype),
+        up.astype(x.dtype), down.astype(x.dtype), tile=tile,
+        interpret=interpret)
+    return jnp.where(jnp.any(held, axis=1)[:, None], out.reshape(t, d), 0)

@@ -260,6 +260,108 @@ def test_grouped_experts_compile_at_the_agent_cells_widths(
     assert "moe_experts" in call.split(" = ")[0]
 
 
+@pytest.mark.parametrize("rows", [32, 2048, 8192])
+def test_gated_experts_compile_at_the_document_cells_widths(
+        one_chip, no_compile_cache, rows):
+    """The expert layer's gated kernel at Mistral-Small-4's widths (16
+    held experts of 4,096 -> 2,048 -> 4,096, top-4) over the decode
+    step's 32 rows, a chunk's 2,048 and a whole 8,192-token prompt: the
+    weights in blocks of F, the rows fetched and written back a row a
+    DMA, the visits' token codes and weights in SMEM. The op is named
+    ``moe_experts``."""
+    import jax
+    import jax.numpy as jnp
+
+    from dss_ml_at_scale_tpu.models.routing import Routing
+    from dss_ml_at_scale_tpu.ops.grouped_experts import gated_experts
+
+    def shaped(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    compiled = _compile(
+        lambda x, gate, up, down, c, s, o, w, e: gated_experts(
+            x, gate, up, down, Routing(w, o, c, s, None, e), k=4,
+            interpret=False),
+        shaped(bf16, rows, 4096), shaped(bf16, 16, 4096, 2048),
+        shaped(bf16, 16, 4096, 2048), shaped(bf16, 16, 2048, 4096),
+        shaped(i32, 16), shaped(i32, 16), shaped(i32, rows * 4),
+        shaped(jnp.float32, rows * 4), shaped(i32, rows * 4),
+    )
+    call = next(line for line in compiled.as_text().splitlines()
+                if "tpu_custom_call" in line and "custom-call(" in line)
+    assert "moe_experts" in call.split(" = ")[0]
+
+
+@pytest.mark.parametrize("program", ["decode", "last_chunk"])
+def test_the_document_cells_programs_compile_whole(one_chip, no_compile_cache,
+                                                   monkeypatch, program):
+    """The document cell's own programs at its sizes, all six layers: the
+    decode step over 32 slots of 9,216 positions and the last chunk of
+    an 8,192-token prompt (start 6,144): one ``moe_experts`` call a
+    layer and no loop over the experts. The donated cache comes back
+    aliased, and what the program needs beside its arguments stays
+    small."""
+    import importlib
+    import json
+    from pathlib import Path
+
+    import jax
+    import jax.numpy as jnp
+
+    from dss_ml_at_scale_tpu.models.mla_moe import MlaMoeLM
+    from dss_ml_at_scale_tpu.serving.lm import kvcache
+
+    for name in ("flash_attention", "grouped_experts"):
+        monkeypatch.setattr(
+            importlib.import_module(f"dss_ml_at_scale_tpu.ops.{name}"),
+            "resolve_interpret", lambda _: False)
+    cfg = json.loads((Path(__file__).resolve().parents[1] / "perfbench"
+                      / "configs" / "mistral_small_4_119b_ep8.json"
+                      ).read_text())
+    model = MlaMoeLM.from_config(cfg)
+
+    def described(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    variables = described(jax.eval_shape(
+        lambda: model.serving_variables(model.init(jax.random.key(0)))))
+    if program == "decode":
+        cache = described(jax.eval_shape(
+            lambda: kvcache.make_arena(model, 32, 9216)))
+        vec = jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip)
+        compiled = jax.jit(
+            kvcache.slot_decode, static_argnums=0, donate_argnums=(3,)
+        ).lower(model, variables, vec, cache, vec, vec).compile()
+    else:
+        cache = described(jax.eval_shape(
+            lambda: kvcache.make_arena(model, 1, 9216)))
+        tokens = jax.ShapeDtypeStruct((1, 2048), jnp.int32,
+                                      sharding=one_chip)
+        n_real = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+        compiled = jax.jit(
+            kvcache.prefill_bucket, static_argnums=0,
+            static_argnames=("start",), donate_argnums=(3,)
+        ).lower(model, variables, tokens, cache, n_real,
+                start=6144).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and " = " in line
+             and line.split(" = ")[0].strip().lstrip("%").startswith(
+                 "moe_experts")]
+    assert len(calls) == cfg["num_layers"]
+    # the loops left are the decode step's cache-row scatters
+    assert not [line for line in text.splitlines()
+                if " while(" in line and "moe_experts" in line]
+    slabs = sum(a.size * a.dtype.itemsize
+                for a in jax.tree_util.tree_leaves(cache))
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= slabs
+    assert memory.temp_size_in_bytes < 1 << 30
+
+
 @pytest.mark.parametrize("program", ["decode", "last_chunk"])
 def test_the_agent_cells_programs_compile_whole(one_chip, no_compile_cache,
                                                 monkeypatch, program):

@@ -1,5 +1,6 @@
-"""The grouped-expert kernel (``ops/grouped_experts.py``) against a plain
-per-token reference, at small widths on the CPU (interpret mode).
+"""The grouped-expert kernels (``ops/grouped_experts.py``), the ungated
+one and the gated one, against plain per-token references, at small
+widths on the CPU (interpret mode).
 
 The reference here is written from the layer's definition, not from the
 kernel's sorted segments: each token's assignments one by one, an
@@ -13,7 +14,10 @@ Tolerance. With float32 operands the kernel and the reference differ by
 the order of the products' sums only: about 1e-7 of the largest value
 (read), held to ``TOL`` 1e-5. With bfloat16 operands both round the
 same float32 ``relu(u up)^2`` to bfloat16 and read 0 apart (read); held
-to ``TOL`` as well.
+to ``TOL`` as well. The gated kernel's reference is the same walk with
+``(silu(x gate) * (x up))`` in float32, cast to the operands' width,
+times ``down``; the kernel sums ``down``'s product over blocks of F,
+which moves the float32 sums' order only.
 """
 
 from __future__ import annotations
@@ -24,7 +28,12 @@ import numpy as np
 import pytest
 
 from dss_ml_at_scale_tpu.models.routing import Routing, route
-from dss_ml_at_scale_tpu.ops.grouped_experts import grouped_experts, row_tile
+from dss_ml_at_scale_tpu.ops.grouped_experts import (
+    BLOCK_F,
+    gated_experts,
+    grouped_experts,
+    row_tile,
+)
 
 TOL = 1e-5
 LATENT, WIDTH = 32, 24
@@ -41,7 +50,7 @@ def routing_of(local, valid, weight, held):
                      dtype=jnp.int32)
     starts = jnp.cumsum(counts, dtype=jnp.int32) - counts
     return Routing(weight.reshape(-1).astype(jnp.float32), order, counts,
-                   starts, None)
+                   starts, None, expert.astype(jnp.int32))
 
 
 def per_token(u, up, down, local, valid, weight):
@@ -222,3 +231,120 @@ def test_the_kernel_body_is_traced_once():
         return _kernel_equations(traced.jaxpr)
 
     assert size(32, 4, 2) == size(1024, 64, 22) == size(300, 16, 4)
+
+
+# -- the gated kernel ---------------------------------------------------------
+
+# Widths of the gated tests: one 128-lane row slab, F in two blocks.
+MODEL, INNER = 128, 2 * BLOCK_F
+
+
+def gated_per_token(x, gate, up, down, local, valid, weight):
+    """The gated layer by its definition, token by token."""
+    out = np.zeros(x.shape, np.float32)
+    for e in range(gate.shape[0]):
+        mid = (jax.nn.silu(jnp.matmul(x, gate[e],
+                                      preferred_element_type=jnp.float32))
+               * jnp.matmul(x, up[e], preferred_element_type=jnp.float32))
+        y = np.asarray(jnp.matmul(mid.astype(x.dtype), down[e],
+                                  preferred_element_type=jnp.float32))
+        for t in range(x.shape[0]):
+            for s in range(local.shape[1]):
+                if valid[t] and local[t, s] == e:
+                    out[t] += np.float32(weight[t, s]) * y[t]
+    return out
+
+
+def check_gated(local, valid=None, held=8, dtype=jnp.float32, seed=0):
+    local = np.asarray(local, np.int32)
+    t, k = local.shape
+    valid = np.ones(t, bool) if valid is None else np.asarray(valid)
+    rng = np.random.default_rng(seed)
+    weight = rng.uniform(0.1, 1.0, (t, k)).astype(np.float32)
+    keys = jax.random.split(jax.random.key(seed), 4)
+    x = jax.random.normal(keys[0], (t, MODEL)).astype(dtype)
+    gate, up = ((MODEL ** -0.5 * jax.random.normal(
+        key, (held, MODEL, INNER))).astype(dtype) for key in keys[1:3])
+    down = (INNER ** -0.5 * jax.random.normal(
+        keys[3], (held, INNER, MODEL))).astype(dtype)
+    r = routing_of(jnp.asarray(local), jnp.asarray(valid),
+                   jnp.asarray(weight), held)
+    got = np.asarray(gated_experts(x, gate, up, down, r, k=k))
+    want = gated_per_token(x, gate, up, down, local, valid, weight)
+    scale = max(1.0, float(np.max(np.abs(want))))
+    assert np.max(np.abs(got - want)) < TOL * scale
+    return got, want, r
+
+
+DTYPES = pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                                 ids=["f32", "bf16"])
+
+
+@DTYPES
+def test_the_gated_kernel_at_a_decode_step(dtype):
+    """32 rows, top-4 of 16 experts of which 8 are held, every fifth row
+    an idle slot (not valid): a row each expert or two, one row tile."""
+    valid = np.arange(32) % 5 != 4
+    got, _, _ = check_gated(distinct_choices(32, 4, 16, seed=11), valid,
+                            dtype=dtype)
+    assert np.all(got[~valid] == 0)
+
+
+@DTYPES
+def test_the_gated_kernel_over_several_row_tiles_and_blocks_of_f(dtype):
+    """300 rows, top-2 of 4 held experts: every segment is 150 rows in the
+    mean, more than one tile of 128 and none a multiple of it; F is two
+    blocks."""
+    _, _, r = check_gated(distinct_choices(300, 2, 4, seed=12), held=4,
+                          dtype=dtype)
+    counts = np.asarray(r.counts)
+    assert np.all(counts > row_tile(300)) and INNER // BLOCK_F == 2
+    assert np.any(counts % row_tile(300) != 0)
+
+
+@DTYPES
+def test_the_gated_kernel_at_a_row_count_off_the_tiling(dtype):
+    """37 rows: no multiple of 8 or 16."""
+    check_gated(distinct_choices(37, 4, 8, seed=13), dtype=dtype)
+
+
+@DTYPES
+def test_a_held_expert_with_no_rows_costs_nothing(dtype):
+    """Only experts 2, 3 and 5 of 8 get rows."""
+    rng = np.random.default_rng(14)
+    local = np.stack([rng.permutation([2, 3, 5])[:2] for _ in range(40)])
+    _, _, r = check_gated(local, dtype=dtype)
+    assert [int(c > 0) for c in r.counts] == [0, 0, 1, 1, 0, 1, 0, 0]
+
+
+@DTYPES
+def test_no_held_expert_touched_gives_zeros_gated(dtype):
+    got, _, _ = check_gated(np.full((32, 2), 9), dtype=dtype)
+    assert np.all(got == 0)
+
+
+def test_the_gated_kernel_body_is_traced_once():
+    """Visits, blocks of F and rows are loops: the gated kernel's jaxpr
+    holds as many equations at 16 experts over 1,024 rows with F in four
+    blocks as at 4 over 32 with F one block."""
+
+    def size(t, held, width, k):
+        f32, i32, bf16 = jnp.float32, jnp.int32, jnp.bfloat16
+        shapes = (jax.ShapeDtypeStruct((t, MODEL), bf16),
+                  jax.ShapeDtypeStruct((held, MODEL, width), bf16),
+                  jax.ShapeDtypeStruct((held, MODEL, width), bf16),
+                  jax.ShapeDtypeStruct((held, width, MODEL), bf16),
+                  jax.ShapeDtypeStruct((held,), i32),
+                  jax.ShapeDtypeStruct((held,), i32),
+                  jax.ShapeDtypeStruct((t * k,), i32),
+                  jax.ShapeDtypeStruct((t * k,), f32),
+                  jax.ShapeDtypeStruct((t * k,), i32))
+
+        def layer(x, gate, up, down, counts, starts, order, weight, expert):
+            r = Routing(weight, order, counts, starts, None, expert)
+            return gated_experts(x, gate, up, down, r, k=k)
+
+        return _kernel_equations(jax.make_jaxpr(layer)(*shapes).jaxpr)
+
+    assert size(32, 4, 24, 2) == size(1024, 16, 4 * BLOCK_F, 4) \
+        == size(300, 8, BLOCK_F, 4)

@@ -34,7 +34,8 @@ import numpy as np
 import pytest
 
 from dss_ml_at_scale_tpu import telemetry
-from dss_ml_at_scale_tpu.models.mla_moe import MlaMoeLM, _expert_pass
+from dss_ml_at_scale_tpu.models.mla_moe import MlaMoeLM
+from dss_ml_at_scale_tpu.ops import grouped_experts
 from dss_ml_at_scale_tpu.serving.lm import (
     LMConfig,
     LMEngine,
@@ -89,6 +90,12 @@ def variables_of(bench, cfg=CFG, seed=SEED):
     return weights.nest(weights.make(reference.param_shapes(cfg), seed))
 
 
+def served_of(bench, cfg=CFG, seed=SEED):
+    """The tree the model's programs read: each layer's experts
+    stacked."""
+    return model_of(cfg).serving_variables(variables_of(bench, cfg, seed))
+
+
 def tokens_of(n, seed=0):
     return [int(t) for t in
             np.random.default_rng(seed).integers(0, CFG["vocab_size"], n)]
@@ -113,7 +120,7 @@ def test_the_program_and_the_reference_name_the_same_leaves(bench):
 def test_full_forward_logits_match_the_reference(bench):
     _, reference = bench
     toks = tokens_of(48)
-    got, _ = jax.jit(model_of().logits)(variables_of(bench),
+    got, _ = jax.jit(model_of().logits)(served_of(bench),
                                         jnp.asarray(toks))
     want = reference.logits(toks, SEED, CFG)
     assert float(jnp.max(jnp.abs(want))) > 1.0       # logits of size
@@ -133,7 +140,7 @@ def test_bfloat16_fails_the_float32_tolerance(bench):
 def test_the_flash_prefill_matches_the_plain_one(bench):
     # the Pallas kernel (interpreted on the CPU) at head size 8 + 8 = 16
     toks = jnp.asarray(tokens_of(32))
-    variables = variables_of(bench)
+    variables = served_of(bench)
     plain, _ = jax.jit(model_of().logits)(variables, toks)
     flash = MlaMoeLM.from_config(CFG, attention="flash", dtype=jnp.float32)
     got, _ = jax.jit(flash.logits)(variables, toks)
@@ -145,7 +152,7 @@ def test_prefill_then_decode_through_the_latent_arena(bench):
     and every decoded row (the absorbed path over the latent rows) equal
     the reference's full forward of that slot's sequence."""
     _, reference = bench
-    model, variables = model_of(), variables_of(bench)
+    model, variables = model_of(), served_of(bench)
     seqs = {0: tokens_of(48, 1), 2: tokens_of(40, 2), 3: tokens_of(30, 3)}
     prompts = {0: 20, 2: 33, 3: 9}
     want = {s: np.asarray(reference.logits(t, SEED, CFG))
@@ -205,7 +212,7 @@ def test_a_prompt_in_chunks_is_the_prompt_in_one(bench, attention, n,
     to the whole prefill's, expert by expert, padding routed nowhere; a
     chunk that does not hold the last token returns no logits."""
     model = MlaMoeLM.from_config(CFG, attention=attention, dtype=jnp.float32)
-    variables = variables_of(bench)
+    variables = served_of(bench)
     rows = -(-n // chunk) * chunk
     padded = np.zeros((1, rows), np.int32)
     padded[0, :n] = tokens_of(n, 7)
@@ -258,29 +265,38 @@ def nested(flat):
     return tree
 
 
+def served_layer(model, tree):
+    """One layer's leaves as the model's programs read them: its held
+    experts stacked."""
+    return model.serving_variables({"params": {"layer_0": tree}}
+                                   )["params"]["layer_0"]
+
+
 def test_every_token_to_one_expert_and_none_dropped(bench):
     """A router that sends every token to experts 2 and 3: expert 2
-    (held, local 0) gets all 40 tokens, in passes of 16 rows, and the
-    layer's output is the reference's."""
+    (held, local 0) gets all 300 tokens, a segment of three row tiles of
+    the kernel (the last one part full), and the layer's output is the
+    reference's."""
     _, reference = bench
     cfg = {**CFG, "num_layers": 1}
     flat = layer_weights(bench, cfg)
     router = np.zeros((64, 8), np.float32)
     router[:, 2], router[:, 3] = 0.5, 0.25
-    x = jax.random.normal(jax.random.key(1), (40, 64), jnp.float32)
+    x = jax.random.normal(jax.random.key(1), (300, 64), jnp.float32)
     # gains are positive and x's mean is not 0: the gates order alike
     x = jnp.abs(x)
     flat = {**flat, "router/kernel": jnp.asarray(router)}
     model = MlaMoeLM.from_config(cfg, attention="reference",
                                  dtype=jnp.float32)
-    model = type(model)(**{**model.__dict__, "expert_tile": 16})
+    tree = served_layer(model, nested(flat))
+    assert 300 > 2 * grouped_experts.row_tile(300)
     hid = model._norm(x, flat["ffn_norm/scale"])
-    routed, stats = jax.jit(model._experts)(hid, hid, jnp.ones(40, bool),
-                                            nested(flat))
+    routed, stats = jax.jit(model._experts)(hid, hid, jnp.ones(300, bool),
+                                            tree)
     counts = [int(n) for n in stats[3:]]
-    assert counts == [40, 40, 0, 0] and int(stats[0]) == 80
+    assert counts == [300, 300, 0, 0] and int(stats[0]) == 600
     assert int(stats[1]) == 0 and int(stats[2]) == 2
-    got, _ = jax.jit(model._ffn)(x, jnp.ones(40, bool), nested(flat))
+    got, _ = jax.jit(model._ffn)(x, jnp.ones(300, bool), tree)
     probs = jax.nn.softmax(hid @ flat["router/kernel"], -1)
     top = probs[:, 2:4] / jnp.sum(probs[:, 2:4], -1, keepdims=True)
     want = x + reference._gated(
@@ -294,29 +310,27 @@ def test_every_token_to_one_expert_and_none_dropped(bench):
     assert float(jnp.max(jnp.abs(got - want))) < F32_TOL
 
 
-def test_an_expert_pass_is_traced_once_a_shape(bench, monkeypatch):
-    """The expert layer's pass is one jitted function: a program of two
-    layers holding four experts each traces the gated expert three times
-    (the shared expert of each layer, and the pass once), not ten, and
-    the chunk program at a second start traces the pass no more."""
+def test_the_gated_call_is_traced_once_a_shape(bench, monkeypatch):
+    """The expert layer's kernel call is one jitted function: two chunk
+    programs (starts 0 and 16) of two layers each trace its kernel body
+    once between them, not once a layer or a program."""
     traced = []
-    gated = MlaMoeLM._gated
+    kernel = grouped_experts._gated_kernel
 
-    def counted(self, *args):
-        traced.append(args[0].shape)
-        return gated(self, *args)
+    def counted(*args, **kwargs):
+        traced.append(kwargs["tile"])
+        return kernel(*args, **kwargs)
 
-    monkeypatch.setattr(MlaMoeLM, "_gated", counted)
-    _expert_pass.clear_cache()       # traced by the tests before this one
-    model, variables = model_of(), variables_of(bench)
+    monkeypatch.setattr(grouped_experts, "_gated_kernel", counted)
+    grouped_experts._gated_call.clear_cache()   # traced by earlier tests
+    model, variables = model_of(), served_of(bench)
     cache = kvcache.make_arena(model, 1, 64)
     for start in (0, 16):
         jax.make_jaxpr(model.prefill_chunk, static_argnums=(3,))(
             variables, jnp.zeros((1, 16), jnp.int32), cache, start,
             jnp.int32(20))
-    # each program: the chunk's 16 rows through two layers' shared
-    # experts; the pass (16 rows: min(tile, T)) traced in the first only
-    assert traced == [(16, 64)] * (2 + 1 + 2)
+    # the chunk's 16 rows: one tile of 16
+    assert traced == [16]
 
 
 @pytest.mark.parametrize("chips", [2, 4])
@@ -344,7 +358,7 @@ def test_the_shares_add_up_to_the_uncut_layer(bench, chips):
                                      dtype=jnp.float32)
         # the share's reference layer is the program's share, too
         share_ref = reference._layer(x, mine, cfg, reference._exact)
-        tree = nested(mine)
+        tree = served_layer(model, nested(mine))
         pos = jnp.arange(48, dtype=jnp.int32)
         q_nope, q_rope, row = model._qkv_latent(x, tree, pos)
         attn = model._attend_expanded(q_nope, q_rope, row, tree, pos)
@@ -465,7 +479,7 @@ def test_the_shared_routing_leaves_the_outputs_and_stats_as_they_were():
         n_routed_experts=4, router_width=8, expert_offset=2,
         num_experts_per_tok=2, routed_scaling_factor=1.5,
         attention="reference", dtype=jnp.float32)
-    variables = model.init(jax.random.key(3))
+    variables = model.serving_variables(model.init(jax.random.key(3)))
     toks = jnp.asarray(np.random.default_rng(4).integers(0, 128, 24),
                        jnp.int32)
     logits, stats = jax.jit(model.logits)(variables, toks)
@@ -490,6 +504,35 @@ def test_release_frees_each_wide_leaf_once_it_is_cast(bench):
     dtypes = {str(a.dtype) for a in jax.tree_util.tree_leaves(served)}
     assert dtypes == {"bfloat16", "float32"}
     assert served["params"]["layer_0"]["router"]["kernel"].dtype == jnp.float32
+
+
+def test_serving_variables_stack_each_layers_held_experts(bench):
+    """Each layer's held experts become three stacked leaves in expert
+    order at the served width; a stacked tree is served as it is; with
+    ``release`` neither the wide leaves nor their narrow per-expert
+    copies outlive the call."""
+    model = model_of(dtype=jnp.bfloat16)
+    variables = variables_of(bench)
+    want = {name: np.stack([np.asarray(
+        variables["params"]["layer_1"][f"expert_{e}"][name]["kernel"]
+        .astype(jnp.bfloat16)) for e in range(4)])
+        for name in ("gate", "up", "down")}
+    before = {id(a) for a in jax.live_arrays()}
+    served = model.serving_variables(variables, release=True)
+    leaves = jax.tree_util.tree_leaves(served)
+    assert {id(a) for a in jax.live_arrays()} - before \
+        <= {id(a) for a in leaves}
+    layer = served["params"]["layer_1"]
+    assert not any(name.startswith("expert_") for name in layer)
+    for name, shape in (("gate", (4, 64, 32)), ("up", (4, 64, 32)),
+                        ("down", (4, 32, 64))):
+        got = layer["experts"][name]
+        assert got.shape == shape and got.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(np.asarray(got), want[name])
+    again = model.serving_variables(served, release=True)
+    assert all(a is b for a, b in
+               zip(jax.tree_util.tree_leaves(again), leaves))
+    assert not any(a.is_deleted() for a in leaves)
 
 
 # -- the command --------------------------------------------------------------
