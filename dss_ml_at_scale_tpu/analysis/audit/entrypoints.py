@@ -259,346 +259,156 @@ def decode_step_lm(mesh) -> ProgramSpec:
     )
 
 
-def _served_lm(mesh):
-    """The audit's LM at the dtype ``dsst serve-lm`` builds (bfloat16,
-    the class's own default) and the tree its ``TransformerDecoder``
-    holds: each leaf at the width the model multiplies it in, through
-    the same function the decoder calls — so the audited arguments are
-    the served ones."""
-    import jax
-    import jax.numpy as jnp
+# -- the served LMs -----------------------------------------------------------
 
-    from ...models.transformer import serving_variables
-
-    model = _lm_task().model.clone(dtype=jnp.bfloat16)
-    variables = model.init(
-        jax.random.key(0), jnp.zeros((1, 8), jnp.int32)
-    )
-    return model, jax.device_put(
-        serving_variables(model, variables), _replicated(mesh)
-    )
-
-
-def slot_decode_lm(mesh) -> ProgramSpec:
-    """The continuous-batching serving step: one batched decode over the
-    slot arena with a PER-SLOT position vector, as the engine calls it:
-    the tokens are the previous step's ids merged on the device with
-    the host's overrides, and the greedy ids come back beside the
-    logits. The donation pin is the whole point — the engine holds ONE
-    live arena for the life of the server, and this rule certifies
-    every step aliases it in-place (zero per-token cache copies)."""
-    import jax
-    import jax.numpy as jnp
-
-    from ...serving.lm import kvcache
-
-    model, variables = _served_lm(mesh)
-    replicated = _replicated(mesh)
-    arena = jax.device_put(kvcache.make_arena(model, 4, 32), replicated)
-    tokens = jax.device_put(jnp.zeros((4,), jnp.int32), replicated)
-    pos = jax.device_put(jnp.zeros((4,), jnp.int32), replicated)
-    override = jax.device_put(jnp.zeros((4,), jnp.int32), replicated)
-    return ProgramSpec(
-        name="slot_decode.lm",
-        fn=kvcache.slot_decode,
-        args=(model, variables, tokens, arena, pos, override),
-        # out_shardings pinned for the same reason as decode_step.lm:
-        # committed inputs + UNSPECIFIED outputs silently drop the
-        # arena aliasing.
-        jit_kwargs={
-            "static_argnums": 0,
-            "donate_argnums": (3,),
-            "out_shardings": replicated,
-        },
-        expect_donated=(3,),
-    )
-
-
-def prefill_lm(mesh) -> ProgramSpec:
-    """One bucketed prefill (the canonical 16-token bucket): prompt
-    through one causal pass into a donated single-sequence cache the
-    engine recycles across admissions."""
-    import jax
-    import jax.numpy as jnp
-
-    from ...serving.lm import kvcache
-
-    model, variables = _served_lm(mesh)
-    replicated = _replicated(mesh)
-    cache = jax.device_put(kvcache.make_arena(model, 1, 32), replicated)
-    tokens = jax.device_put(jnp.zeros((1, 16), jnp.int32), replicated)
-    return ProgramSpec(
-        name="prefill.lm",
-        fn=kvcache.prefill_bucket,
-        args=(model, variables, tokens, cache),
-        jit_kwargs={
-            "static_argnums": 0,
-            "donate_argnums": (3,),
-            "out_shardings": replicated,
-        },
-        expect_donated=(3,),
-    )
-
-
-def _served_mla_moe(mesh):
-    """A small latent-attention expert model at the dtype ``dsst
-    serve-lm --model-config`` serves (bfloat16), holding experts 2..5 of
-    the 8 its router scores, with the tree its decoder holds."""
-    import jax
-
-    from ...models.mla_moe import MlaMoeLM
-
-    model = MlaMoeLM(
+# The tiny config-built served models, at the dtype ``dsst serve-lm
+# --model-config`` serves (bfloat16, the classes' default): family ->
+# (module.class under ``models``, constructor arguments).
+_TINY_SERVED = {
+    # Latent attention, holding experts 2..5 of the 8 its router scores.
+    "mla_moe": ("mla_moe.MlaMoeLM", dict(
         vocab_size=128, hidden_size=64, num_layers=2,
         num_attention_heads=4, q_lora_rank=32, kv_lora_rank=16,
         qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=16,
         moe_intermediate_size=32, n_routed_experts=4, router_width=8,
         expert_offset=2, num_experts_per_tok=2, rope_factor=4.0,
         original_max_position_embeddings=16, mscale_all_dim=1.0,
-        llama_4_scaling_beta=0.1, attention="reference",
-    )
-    return model, jax.device_put(
-        model.serving_variables(model.init(jax.random.key(0))),
-        _replicated(mesh),
-    )
-
-
-def slot_decode_mla_moe(mesh) -> ProgramSpec:
-    """The serving step of the latent-attention expert model: one
-    batched call over the latent slot arena (the expert layer routes
-    every slot's token together), the arena donated as in
-    ``slot_decode.lm``."""
-    import jax
-    import jax.numpy as jnp
-
-    from ...serving.lm import kvcache
-
-    model, variables = _served_mla_moe(mesh)
-    replicated = _replicated(mesh)
-    arena = jax.device_put(kvcache.make_arena(model, 4, 32), replicated)
-    zeros = jax.device_put(jnp.zeros((4,), jnp.int32), replicated)
-    return ProgramSpec(
-        name="slot_decode.mla_moe",
-        fn=kvcache.slot_decode,
-        args=(model, variables, zeros, arena, zeros, zeros),
-        jit_kwargs={
-            "static_argnums": 0,
-            "donate_argnums": (3,),
-            "out_shardings": replicated,
-        },
-        expect_donated=(3,),
-    )
-
-
-def prefill_mla_moe(mesh) -> ProgramSpec:
-    """One bucketed prefill of the latent-attention expert model
-    through the expanded path into a donated one-slot latent cache; the
-    count of real tokens is an argument and one logits row comes
-    back."""
-    import jax
-    import jax.numpy as jnp
-
-    from ...serving.lm import kvcache
-
-    model, variables = _served_mla_moe(mesh)
-    replicated = _replicated(mesh)
-    cache = jax.device_put(kvcache.make_arena(model, 1, 32), replicated)
-    tokens = jax.device_put(jnp.zeros((1, 16), jnp.int32), replicated)
-    n_real = jax.device_put(jnp.int32(9), replicated)
-    return ProgramSpec(
-        name="prefill.mla_moe",
-        fn=kvcache.prefill_bucket,
-        args=(model, variables, tokens, cache, n_real),
-        jit_kwargs={
-            "static_argnums": 0,
-            "donate_argnums": (3,),
-            "out_shardings": replicated,
-        },
-        expect_donated=(3,),
-    )
-
-
-def prefill_chunk_mla_moe(mesh) -> ProgramSpec:
-    """The second chunk of a prompt of the latent-attention expert
-    model (``start`` 16, static), over the rows the first one wrote into
-    the donated one-slot cache: how the decoder prefills this model. The
-    production jit names ``start`` static (``static_argnames``); here it
-    is the sixth argument, static by number, which lowers the same
-    program."""
-    import jax
-    import jax.numpy as jnp
-
-    from ...serving.lm import kvcache
-
-    model, variables = _served_mla_moe(mesh)
-    replicated = _replicated(mesh)
-    cache = jax.device_put(kvcache.make_arena(model, 1, 32), replicated)
-    tokens = jax.device_put(jnp.zeros((1, 16), jnp.int32), replicated)
-    n_real = jax.device_put(jnp.int32(25), replicated)
-    return ProgramSpec(
-        name="prefill_chunk.mla_moe",
-        fn=kvcache.prefill_bucket,
-        args=(model, variables, tokens, cache, n_real, 16),
-        jit_kwargs={
-            "static_argnums": (0, 5),
-            "donate_argnums": (3,),
-            "out_shardings": replicated,
-        },
-        expect_donated=(3,),
-    )
-
-
-def _served_jamba(mesh):
-    """A small hybrid state-space model at the dtype ``dsst serve-lm
-    --model-config`` serves (bfloat16): one period of four layers, the
-    third attention (one key/value head under four query heads), the
-    others Mamba, with the tree its decoder holds."""
-    import jax
-
-    from ...models.jamba import JambaLM
-
-    model = JambaLM(
+        llama_4_scaling_beta=0.1)),
+    # One period of four layers, the third attention (one key/value head
+    # under four query heads), the others Mamba.
+    "jamba": ("jamba.JambaLM", dict(
         vocab_size=128, hidden_size=64, num_layers=4,
         num_attention_heads=4, num_key_value_heads=1, intermediate_size=96,
         d_state=16, d_conv=4, expand=2, dt_rank=8, attn_layer_period=4,
-        attn_layer_offset=2, attention="reference",
-    )
-    return model, jax.device_put(
-        model.init(jax.random.key(0)), _replicated(mesh)
-    )
-
-
-def slot_decode_jamba(mesh) -> ProgramSpec:
-    """The serving step of the hybrid model: one batched call that
-    advances every slot's recurrent state and writes its key/value row,
-    the arena donated as in ``slot_decode.lm``."""
-    import jax
-    import jax.numpy as jnp
-
-    from ...serving.lm import kvcache
-
-    model, variables = _served_jamba(mesh)
-    replicated = _replicated(mesh)
-    arena = jax.device_put(kvcache.make_arena(model, 4, 32), replicated)
-    zeros = jax.device_put(jnp.zeros((4,), jnp.int32), replicated)
-    return ProgramSpec(
-        name="slot_decode.jamba",
-        fn=kvcache.slot_decode,
-        args=(model, variables, zeros, arena, zeros, zeros),
-        jit_kwargs={
-            "static_argnums": 0,
-            "donate_argnums": (3,),
-            "out_shardings": replicated,
-        },
-        expect_donated=(3,),
-    )
-
-
-def prefill_jamba(mesh) -> ProgramSpec:
-    """One bucketed prefill of the hybrid model into a donated one-slot
-    cache: the scan stops at the count of real tokens, an argument, and
-    one logits row comes back."""
-    import jax
-    import jax.numpy as jnp
-
-    from ...serving.lm import kvcache
-
-    model, variables = _served_jamba(mesh)
-    replicated = _replicated(mesh)
-    cache = jax.device_put(kvcache.make_arena(model, 1, 32), replicated)
-    tokens = jax.device_put(jnp.zeros((1, 16), jnp.int32), replicated)
-    n_real = jax.device_put(jnp.int32(9), replicated)
-    return ProgramSpec(
-        name="prefill.jamba",
-        fn=kvcache.prefill_bucket,
-        args=(model, variables, tokens, cache, n_real),
-        jit_kwargs={
-            "static_argnums": 0,
-            "donate_argnums": (3,),
-            "out_shardings": replicated,
-        },
-        expect_donated=(3,),
-    )
-
-
-def _served_nemotron_h(mesh):
-    """A small Mamba-2 / latent-expert / attention model at the dtype
-    ``dsst serve-lm --model-config`` serves (bfloat16): the pattern
-    ``MEM*E`` (two Mamba-2 mixers of 8 heads in 2 groups, two expert
-    layers holding experts 4..11 of the 16 their sigmoid router scores,
-    attention of 4 query heads over 2 key/value heads), with the tree its
-    decoder holds (the experts stacked)."""
-    import jax
-
-    from ...models.nemotron_h import NemotronHLM
-
-    model = NemotronHLM(
+        attn_layer_offset=2)),
+    # The pattern MEM*E: two Mamba-2 mixers of 8 heads in 2 groups, two
+    # expert layers holding experts 4..11 of the 16 their sigmoid router
+    # scores, attention of 4 query heads over 2 key/value heads.
+    "nemotron_h": ("nemotron_h.NemotronHLM", dict(
         vocab_size=128, hidden_size=64, pattern="MEM*E",
         num_attention_heads=4, num_key_value_heads=2, head_dim=16,
         mamba_num_heads=8, mamba_head_dim=8, ssm_state_size=16,
         n_groups=2, moe_intermediate_size=24, moe_latent_size=32,
         moe_shared_expert_intermediate_size=48, n_routed_experts=8,
         router_width=16, expert_offset=4, num_experts_per_tok=4,
-        routed_scaling_factor=2.5, chunk_size=8, attention="reference",
-    )
-    variables = model.serving_variables(model.init(jax.random.key(0)))
-    return model, jax.device_put(variables, _replicated(mesh))
+        routed_scaling_factor=2.5, chunk_size=8)),
+}
+
+# Each served model's prefill of a 16-token bucket: name -> (family, the
+# count of real tokens, an argument: None for the LM's, which takes the
+# whole bucket; the start, static, where the decoder prefills the model
+# a chunk at a time: the second chunk, over the rows the first wrote).
+_PREFILLS = {
+    "prefill.lm": ("lm", None, None),
+    "prefill.mla_moe": ("mla_moe", 9, None),
+    "prefill_chunk.mla_moe": ("mla_moe", 25, 16),
+    "prefill.jamba": ("jamba", 9, None),
+    "prefill.nemotron_h": ("nemotron_h", 25, 16),
+}
 
 
-def slot_decode_nemotron_h(mesh) -> ProgramSpec:
-    """The serving step of the Mamba-2 / latent-expert model: one
-    batched call that advances every slot's Mamba-2 state, routes every
-    slot's token together and writes its key/value row, the arena
-    donated as in ``slot_decode.lm``."""
+def _served(mesh, family):
+    """The audit's served ``family`` (``lm``, the chat model at the
+    dtype ``dsst serve-lm`` builds, bfloat16, or one of
+    :data:`_TINY_SERVED`) and the tree its ``TransformerDecoder`` holds:
+    each leaf at the width the model multiplies it in, each layer's held
+    experts stacked, through the ``serving_variables`` the decoder calls
+    — so the audited arguments are the served ones."""
+    import importlib
+
     import jax
     import jax.numpy as jnp
 
-    from ...serving.lm import kvcache
-
-    model, variables = _served_nemotron_h(mesh)
-    replicated = _replicated(mesh)
-    arena = jax.device_put(kvcache.make_arena(model, 4, 32), replicated)
-    zeros = jax.device_put(jnp.zeros((4,), jnp.int32), replicated)
-    return ProgramSpec(
-        name="slot_decode.nemotron_h",
-        fn=kvcache.slot_decode,
-        args=(model, variables, zeros, arena, zeros, zeros),
-        jit_kwargs={
-            "static_argnums": 0,
-            "donate_argnums": (3,),
-            "out_shardings": replicated,
-        },
-        expect_donated=(3,),
+    if family == "lm":
+        model = _lm_task().model.clone(dtype=jnp.bfloat16)
+        variables = model.init(
+            jax.random.key(0), jnp.zeros((1, 8), jnp.int32)
+        )
+    else:
+        path, kwargs = _TINY_SERVED[family]
+        module, name = path.rsplit(".", 1)
+        cls = getattr(importlib.import_module(
+            f"...models.{module}", __package__), name)
+        model = cls(**kwargs, attention="reference")
+        variables = model.init(jax.random.key(0))
+    return model, jax.device_put(
+        model.serving_variables(variables), _replicated(mesh)
     )
 
 
-def prefill_nemotron_h(mesh) -> ProgramSpec:
-    """How the decoder prefills the Mamba-2 / latent-expert model: the
-    second chunk of a prompt (``start`` 16, static, the sixth argument
-    here as ``prefill_chunk.mla_moe``), resuming from the state, conv
-    inputs and key/value rows the first chunk left in the donated
-    one-slot cache."""
-    import jax
-    import jax.numpy as jnp
+def _served_jit_kwargs(mesh, static_argnums=0):
+    # out_shardings pinned: with committed inputs and UNSPECIFIED
+    # outputs jax silently drops the arena aliasing (as decode_step.lm).
+    return {
+        "static_argnums": static_argnums,
+        "donate_argnums": (3,),
+        "out_shardings": _replicated(mesh),
+    }
 
-    from ...serving.lm import kvcache
 
-    model, variables = _served_nemotron_h(mesh)
-    replicated = _replicated(mesh)
-    cache = jax.device_put(kvcache.make_arena(model, 1, 32), replicated)
-    tokens = jax.device_put(jnp.zeros((1, 16), jnp.int32), replicated)
-    n_real = jax.device_put(jnp.int32(25), replicated)
-    return ProgramSpec(
-        name="prefill.nemotron_h",
-        fn=kvcache.prefill_bucket,
-        args=(model, variables, tokens, cache, n_real, 16),
-        jit_kwargs={
-            "static_argnums": (0, 5),
-            "donate_argnums": (3,),
-            "out_shardings": replicated,
-        },
-        expect_donated=(3,),
-    )
+def _slot_decode(family):
+    """The continuous-batching serving step of ``family``: one batched
+    decode over the slot arena with a PER-SLOT position vector, as the
+    engine calls it (the tokens the previous step's ids merged on the
+    device with the host's overrides, the greedy ids back beside the
+    logits). The donation pin is the point: the engine holds ONE live
+    arena for the life of the server, and this certifies that every
+    step aliases it in place (zero per-token cache copies)."""
+
+    def build(mesh) -> ProgramSpec:
+        import jax
+        import jax.numpy as jnp
+
+        from ...serving.lm import kvcache
+
+        model, variables = _served(mesh, family)
+        replicated = _replicated(mesh)
+        arena = jax.device_put(kvcache.make_arena(model, 4, 32), replicated)
+        zeros = jax.device_put(jnp.zeros((4,), jnp.int32), replicated)
+        return ProgramSpec(
+            name=f"slot_decode.{family}",
+            fn=kvcache.slot_decode,
+            args=(model, variables, zeros, arena, zeros, zeros),
+            jit_kwargs=_served_jit_kwargs(mesh),
+            expect_donated=(3,),
+        )
+
+    return build
+
+
+def _prefill(name):
+    """One bucketed prefill (``_PREFILLS[name]``) into a donated
+    one-slot cache the engine recycles across admissions; one logits row
+    comes back. The production jit names ``start`` static
+    (``static_argnames``); here it is the sixth argument, static by
+    number, which lowers the same program."""
+    family, n_real, start = _PREFILLS[name]
+
+    def build(mesh) -> ProgramSpec:
+        import jax
+        import jax.numpy as jnp
+
+        from ...serving.lm import kvcache
+
+        model, variables = _served(mesh, family)
+        replicated = _replicated(mesh)
+        cache = jax.device_put(kvcache.make_arena(model, 1, 32), replicated)
+        tokens = jax.device_put(jnp.zeros((1, 16), jnp.int32), replicated)
+        args = (model, variables, tokens, cache)
+        if n_real is not None:
+            args += (jax.device_put(jnp.int32(n_real), replicated),)
+        if start is not None:
+            args += (start,)
+        return ProgramSpec(
+            name=name,
+            fn=kvcache.prefill_bucket,
+            args=args,
+            jit_kwargs=_served_jit_kwargs(
+                mesh, 0 if start is None else (0, 5)),
+            expect_donated=(3,),
+        )
+
+    return build
 
 
 def serving_score(mesh) -> ProgramSpec:
@@ -779,15 +589,9 @@ _BUILDERS: dict[str, Callable] = {
     "train_step.lm": train_step_lm,
     "train_step.pipelined_lm": train_step_pipelined_lm,
     "decode_step.lm": decode_step_lm,
-    "slot_decode.lm": slot_decode_lm,
-    "prefill.lm": prefill_lm,
-    "slot_decode.mla_moe": slot_decode_mla_moe,
-    "prefill.mla_moe": prefill_mla_moe,
-    "prefill_chunk.mla_moe": prefill_chunk_mla_moe,
-    "slot_decode.jamba": slot_decode_jamba,
-    "prefill.jamba": prefill_jamba,
-    "slot_decode.nemotron_h": slot_decode_nemotron_h,
-    "prefill.nemotron_h": prefill_nemotron_h,
+    **{f"slot_decode.{family}": _slot_decode(family)
+       for family in ("lm", *_TINY_SERVED)},
+    **{name: _prefill(name) for name in _PREFILLS},
     "serving.score": serving_score,
     "ops.fused_matmul.grad": fused_matmul_grad,
     "ops.fused_norm.grad": fused_norm_grad,

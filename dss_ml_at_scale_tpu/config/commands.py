@@ -1895,15 +1895,15 @@ def register_serve_lm(sub: argparse._SubParsersAction) -> None:
                     default="reference")
     sv.add_argument(
         "--model-config", default=None, metavar="JSON",
-        help="architecture file with the published config.json keys: "
-        "model_type jamba builds the hybrid state-space model "
-        "(models/jamba.py), nemotron_h the hybrid Mamba-2 / latent-expert "
-        "model (models/nemotron_h.py, with num_layers, n_routed_experts "
-        "and vocab_size as held here), any other a latent-attention "
-        "expert model "
-        "(models/mla_moe.py, with num_layers, n_routed_experts and "
-        "vocab_size as held here, router_width and expert_offset beside "
-        "them); served with random weights through the same decoder and "
+        help="architecture file with the published config.json keys; "
+        "its model_type picks the model (models/served.py build_served): "
+        "jamba the hybrid state-space model (models/jamba.py), nemotron_h "
+        "the hybrid Mamba-2 / latent-expert model (models/nemotron_h.py), "
+        "mistral4 or none a latent-attention expert model "
+        "(models/mla_moe.py); an expert model reads num_layers, "
+        "n_routed_experts and vocab_size as held here, router_width and "
+        "expert_offset beside them; any other model_type is refused. "
+        "Served with random weights through the same decoder and "
         "engine, in place of the TransformerLM that "
         "--vocab/--dim/--heads/--layers size",
     )
@@ -1952,7 +1952,8 @@ def _cmd_serve_lm(args: argparse.Namespace) -> int:
         import jax
         import jax.numpy as jnp
 
-        from ..models import MlaMoeLM, TransformerLM
+        from ..models import TransformerLM
+        from ..models.served import build_served
         from ..serving.lm import TransformerDecoder
 
         # Neither tree gets a name here: the decoder keeps its own copy
@@ -1960,17 +1961,11 @@ def _cmd_serve_lm(args: argparse.Namespace) -> int:
         if args.model_config:
             with open(args.model_config) as f:
                 arch = json.load(f)
-            if arch.get("model_type") == "jamba":
-                from ..models.jamba import JambaLM
-
-                model = JambaLM.from_config(arch, attention=args.attention)
-            elif arch.get("model_type") == "nemotron_h":
-                from ..models.nemotron_h import NemotronHLM
-
-                model = NemotronHLM.from_config(
-                    arch, attention=args.attention)
-            else:
-                model = MlaMoeLM.from_config(arch, attention=args.attention)
+            try:
+                model = build_served(arch, attention=args.attention)
+            except ValueError as e:
+                print(e)
+                return 1
             variables = model.init(jax.random.key(args.seed))
         else:
             model = TransformerLM(

@@ -15,5 +15,4 @@ from .transformer import (  # noqa: F401
     next_token_loss,
 )
 from .moe import MoEMLP, collect_aux_loss  # noqa: F401
-from .mla_moe import MlaMoeLM  # noqa: F401
 from .pipelined_lm import PipelinedLM, PipelinedLMTask  # noqa: F401

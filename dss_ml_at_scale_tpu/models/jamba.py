@@ -28,34 +28,29 @@ token, so the state it leaves is the one after the prompt whatever the
 bucket. A decode step advances every slot's state by one token.
 
 The model is a frozen dataclass (hashable: the serving programs take it
-as their static argument) over a plain tree of arrays, and gives the
+as their static argument) over a plain tree of arrays, its shared
+part (:class:`.served.ServedLM`) inherited, and gives the
 decoder what :mod:`..serving.lm.kvcache` asks of a served model.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import json
 import math
 from typing import Any
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 
 from ..ops.flash_attention import attention_reference, flash_attention
 from ..ops.selective_scan import selective_scan, selective_scan_reference
+from .served import ServedLM, _cast, _mm
 from .transformer import rms_norm
-
-# Leaves held in float32 whatever the model's dtype: norm gains, the
-# state's own parameters (A_log, D, the conv) and the biases. The
-# recurrence runs in float32.
-_FLOAT32_ENDINGS = (("scale",), ("bias",), ("conv", "kernel"))
 
 
 @dataclasses.dataclass(frozen=True)
-class JambaLM:
+class JambaLM(ServedLM):
     """The architecture's numbers; see the module docstring."""
 
     vocab_size: int
@@ -76,14 +71,18 @@ class JambaLM:
     attention: str = "flash"
     dtype: Any = jnp.bfloat16
 
+    # Leaves held in float32 whatever the model's dtype: norm gains, the
+    # state's own parameters (A_log, D, the conv) and the biases. The
+    # recurrence runs in float32.
+    float32_endings = (("scale",), ("bias",), ("conv", "kernel"))
+    # ``ssm``: a Mamba layer's state and conv inputs; ``kv``: keys and
+    # values.
+    cache_kinds = {"ssm": "ssm", "conv": "ssm", "k": "kv", "v": "kv"}
+
     @classmethod
-    def from_config(cls, config: dict | str, *, attention: str = "flash",
-                    dtype: Any = jnp.bfloat16) -> "JambaLM":
-        """From an architecture file (a path or its object) with the
-        published ``config.json`` keys."""
-        if not isinstance(config, dict):
-            with open(config) as f:
-                config = json.load(f)
+    def _from_keys(cls, config: dict, *, attention: str,
+                   dtype: Any) -> "JambaLM":
+        """The published ``config.json`` keys."""
         unsupported = {
             "num_experts": (config.get("num_experts", 1), 1),
             "tie_word_embeddings": (config.get("tie_word_embeddings", True),
@@ -113,8 +112,7 @@ class JambaLM:
         )
 
     def __post_init__(self):
-        if self.attention not in ("flash", "reference"):
-            raise ValueError(f"unknown attention backend {self.attention!r}")
+        super().__post_init__()
         if self.hidden_size % self.num_attention_heads:
             raise ValueError("hidden_size is no multiple of the heads")
         if self.num_attention_heads % self.num_key_value_heads:
@@ -179,67 +177,27 @@ class JambaLM:
 
     # -- weights -------------------------------------------------------
 
-    def _width(self, path: str):
-        names = tuple(path.split("/"))
-        wide = any(names[-len(end):] == end for end in _FLOAT32_ENDINGS)
-        return jnp.float32 if wide else jnp.dtype(self.dtype)
-
-    def init(self, key) -> dict:
-        """Random weights at Mamba's own initialisation (there is no
-        checkpoint format for this family yet), each leaf at the width it
-        is served in: ``A_log = log(1..d_state)`` on every channel,
-        ``D = 1``, ``dt_proj``'s bias the inverse softplus of a step drawn
-        log-uniform in [0.001, 0.1] and its kernel uniform within
-        ``dt_rank^-0.5``; the conv uniform within ``d_conv^-0.5``; normal
-        ``1/sqrt(fan-in)`` kernels, ``0.02`` embeddings, unit gains."""
-        tree: dict = {}
-        for n, (path, shape) in enumerate(self.variable_shapes().items()):
-            k = jax.random.fold_in(key, n)
-            name = path.split("/", 2)[-1]          # the path in its layer
-            if name == "mamba/A_log/scale":
-                leaf = jnp.broadcast_to(jnp.log(jnp.arange(
-                    1, shape[1] + 1, dtype=jnp.float32)), shape)
-            elif name == "mamba/D/scale" or path.endswith("scale"):
-                leaf = jnp.ones(shape, jnp.float32)
-            elif name == "mamba/dt_proj/bias":
-                step = jnp.exp(jax.random.uniform(
-                    k, shape, jnp.float32, math.log(1e-3), math.log(1e-1)))
-                leaf = step + jnp.log(-jnp.expm1(-step))
-            elif name == "mamba/dt_proj/kernel":
-                bound = self.dt_rank ** -0.5
-                leaf = jax.random.uniform(k, shape, jnp.float32, -bound, bound)
-            elif name.startswith("mamba/conv/"):
-                bound = self.d_conv ** -0.5
-                leaf = jax.random.uniform(k, shape, jnp.float32, -bound, bound)
-            else:
-                std = 0.02 if path.endswith("embedding") else shape[0] ** -0.5
-                leaf = std * jax.random.normal(k, shape, jnp.float32)
-            node = tree
-            *parents, last = path.split("/")
-            for p in parents:
-                node = node.setdefault(p, {})
-            node[last] = jnp.asarray(leaf, self._width(path))
-        return tree
-
-    def serving_variables(self, variables, *, release: bool = False):
-        """``variables`` with each leaf at the width the model multiplies
-        it in: cast once here, leaf by leaf. With ``release`` each wider
-        original is deleted from the device as soon as its narrow copy
-        exists, so the two trees are never alive together."""
-
-        def at_width(path, leaf):
-            name = "/".join(str(getattr(k, "key", k)) for k in path)
-            want = self._width(name)
-            if not jnp.issubdtype(leaf.dtype, jnp.floating) \
-                    or leaf.dtype == want:
-                return jnp.asarray(leaf)
-            narrow = jnp.asarray(leaf, want)
-            if release and isinstance(leaf, jax.Array):
-                narrow.block_until_ready()
-                leaf.delete()
-            return narrow
-
-        return jax.tree_util.tree_map_with_path(at_width, variables)
+    def _init_leaf(self, path: str, shape, key):
+        """Mamba's own initialisation: ``A_log = log(1..d_state)`` on
+        every channel, ``D = 1``, ``dt_proj``'s bias the inverse softplus
+        of a step drawn log-uniform in [0.001, 0.1] and its kernel
+        uniform within ``dt_rank^-0.5``; the conv uniform within
+        ``d_conv^-0.5``; the rest as :meth:`ServedLM._init_leaf`."""
+        name = path.split("/", 2)[-1]          # the path in its layer
+        if name == "mamba/A_log/scale":
+            return jnp.broadcast_to(jnp.log(jnp.arange(
+                1, shape[1] + 1, dtype=jnp.float32)), shape)
+        if name == "mamba/dt_proj/bias":
+            step = jnp.exp(jax.random.uniform(
+                key, shape, jnp.float32, math.log(1e-3), math.log(1e-1)))
+            return step + jnp.log(-jnp.expm1(-step))
+        if name == "mamba/dt_proj/kernel":
+            bound = self.dt_rank ** -0.5
+            return jax.random.uniform(key, shape, jnp.float32, -bound, bound)
+        if name.startswith("mamba/conv/"):
+            bound = self.d_conv ** -0.5
+            return jax.random.uniform(key, shape, jnp.float32, -bound, bound)
+        return super()._init_leaf(path, shape, key)
 
     # -- the cache -----------------------------------------------------
 
@@ -257,19 +215,7 @@ class JambaLM:
              "conv": jnp.zeros((slots, self.d_conv - 1, di), jnp.float32)}
             for kind in self.layer_kinds)
 
-    def cache_bytes_by_kind(self, cache) -> dict:
-        """Bytes of ``cache`` by the kind of state: ``ssm`` (a Mamba
-        layer's state and conv inputs) and ``kv`` (keys and values)."""
-        out = {"ssm": 0, "kv": 0}
-        for slabs in cache:
-            for name, slab in slabs.items():
-                out["kv" if name in ("k", "v") else "ssm"] += slab.nbytes
-        return out
-
     # -- pieces of a layer ---------------------------------------------
-
-    def _norm(self, x, scale):
-        return _cast(rms_norm(x, scale, self.rms_norm_eps), self.dtype)
 
     def _ssm_inputs(self, u, w):
         """From the conv's output ``u`` ``[T, d_inner]`` (float32): the
@@ -335,23 +281,13 @@ class JambaLM:
             return (self._gate_out(y, uz[:, di:], m),
                     {"ssm": ssm, "conv": window[:, 1:]})
 
-    def _qkv(self, x, w):
-        """``[T, heads, head]`` q and ``[T, kv_heads, head]`` k, v."""
-        t, hd = x.shape[0], self.head_dim
-        hid = self._norm(x, w["norm_in"]["scale"])
-        a = w["attn"]
-        q = _mm(hid, a["q"]["kernel"], self.dtype).reshape(t, -1, hd)
-        k = _mm(hid, a["k"]["kernel"], self.dtype).reshape(t, -1, hd)
-        v = _mm(hid, a["v"]["kernel"], self.dtype).reshape(t, -1, hd)
-        return q, k, v
-
     def _attn_prefill(self, x, w, slabs):
         """Causal attention over the prompt; its keys and values written
         into the one-slot ``slabs`` from row 0. Returns ``[T, d]`` and the
         slabs."""
         t, h = x.shape[0], self.num_attention_heads
         with jax.named_scope("attn"):
-            q, k, v = self._qkv(x, w)
+            q, k, v = self._qkv(x, w, "norm_in")
             slabs = {name: lax.dynamic_update_slice(
                 slabs[name], rows.transpose(1, 0, 2)[None], (0, 0, 0, 0))
                 for name, rows in (("k", k), ("v", v))}
@@ -365,37 +301,6 @@ class JambaLM:
             out = out[0].transpose(1, 0, 2).reshape(t, h * self.head_dim)
             return _mm(out, w["attn"]["o"]["kernel"], self.dtype), slabs
 
-    def _attn_decode(self, x, w, slabs, pos):
-        """One query a slot over that slot's rows ``0..pos`` (its new row
-        written at ``pos`` first), by a masked einsum over the slab. The
-        slabs are the left operand of both products, read in the layout
-        they are stored in."""
-        s, h, hd = x.shape[0], self.num_attention_heads, self.head_dim
-        kvh = self.num_key_value_heads
-        with jax.named_scope("attn"):
-            q, k, v = self._qkv(x, w)
-            # A write at or past max_len clamps into the slot's own last
-            # row: no slot can reach another's.
-            slabs = {name: jax.vmap(
-                lambda slab, r, p: lax.dynamic_update_slice(
-                    slab, r[:, None, :], (np.int32(0), p, np.int32(0)))
-            )(slabs[name], rows, pos)
-                for name, rows in (("k", k), ("v", v))}
-            q = q.reshape(s, kvh, h // kvh, hd)
-            scores = jnp.einsum("skld,skgd->sklg", slabs["k"], q,
-                                preferred_element_type=jnp.float32)
-            length = slabs["k"].shape[2]
-            live = jnp.arange(length, dtype=jnp.int32)[None, :] \
-                <= pos[:, None]
-            scores = jnp.where(live[:, None, :, None],
-                               scores * np.float32(hd ** -0.5),
-                               np.float32(-1e30))
-            probs = _cast(jax.nn.softmax(scores, axis=2), self.dtype)
-            out = jnp.einsum("skld,sklg->skgd", slabs["v"], probs,
-                             preferred_element_type=jnp.float32)
-            out = _cast(out, self.dtype).reshape(s, h * hd)
-            return _mm(out, w["attn"]["o"]["kernel"], self.dtype), slabs
-
     def _mlp(self, x, w):
         with jax.named_scope("mlp"):
             hid = self._norm(x, w["norm_ff"]["scale"])
@@ -403,10 +308,6 @@ class JambaLM:
             mid = _cast(jax.nn.silu(_mm(hid, m["gate"]["kernel"], jnp.float32))
                         * _mm(hid, m["up"]["kernel"], jnp.float32), self.dtype)
             return x + _mm(mid, m["down"]["kernel"], x.dtype)
-
-    def _embed(self, params, ids):
-        with jax.named_scope("embed"):
-            return _cast(params["embed"]["embedding"][ids], self.dtype)
 
     def _head(self, x, params):
         """The final norm, then the tied head ``x E^T``: float32."""
@@ -455,7 +356,8 @@ class JambaLM:
         for i, kind in enumerate(self.layer_kinds):
             w = params[f"layer_{i}"]
             if kind == "attn":
-                out, state = self._attn_decode(x, w, cache[i], pos)
+                out, state = self._attn_decode(x, w, cache[i], pos,
+                                               "norm_in")
             else:
                 out, state = self._mamba_decode(x, w, cache[i])
             x = self._mlp(x + out, w)
@@ -471,14 +373,3 @@ class JambaLM:
         x, _ = self._forward(params, tokens, jnp.int32(t), cache)
         return self._head(x, params)
 
-
-def _cast(x, dtype):
-    """``x`` at ``dtype``; ``x`` itself where it is there already (no
-    same-width convert in the traced program)."""
-    return x if x.dtype == jnp.dtype(dtype) else x.astype(dtype)
-
-
-def _mm(a, b, out_dtype):
-    """``a @ b`` at the operands' width with float32 accumulation."""
-    return _cast(jnp.matmul(a, _cast(b, a.dtype),
-                            preferred_element_type=jnp.float32), out_dtype)

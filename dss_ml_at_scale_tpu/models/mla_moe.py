@@ -32,7 +32,8 @@ anywhere, an untied output head, and per layer
   them, and every program reads the stacked tree.
 
 The model is a frozen dataclass (hashable: the serving programs take it
-as their static argument) over a plain tree of arrays; it gives the
+as their static argument) over a plain tree of arrays, its shared
+part (:class:`.served.ServedLM`) inherited; it gives the
 decoder what :mod:`..serving.lm.kvcache` asks of a served model:
 ``init_cache``, ``prefill_cache``, ``decode_slots``,
 ``serving_variables``, and ``prefill_chunk``, a chunk of a prompt at a
@@ -45,7 +46,6 @@ layers, from which the decoder feeds the ``lm_moe_*`` counters.
 from __future__ import annotations
 
 import dataclasses
-import json
 import math
 from typing import Any
 
@@ -56,12 +56,9 @@ from jax import lax
 
 from ..ops.flash_attention import attention_reference, flash_attention
 from ..ops.grouped_experts import gated_experts
-from .routing import route, stack_held
+from .routing import route
+from .served import ServedLM, _cast, _mm
 from .transformer import rms_norm
-
-# Leaves multiplied in float32 whatever the model's dtype: norm gains
-# and the router (a float32 softmax decides the routing).
-_FLOAT32_ENDINGS = (("scale",), ("router", "kernel"))
 
 
 def yarn_inv_freq(dim: int, base: float, factor: float, beta_fast: float,
@@ -104,7 +101,7 @@ def rotate_interleaved(x, angles):
 
 
 @dataclasses.dataclass(frozen=True)
-class MlaMoeLM:
+class MlaMoeLM(ServedLM):
     """The architecture's numbers; see the module docstring."""
 
     vocab_size: int
@@ -136,18 +133,19 @@ class MlaMoeLM:
     attention: str = "flash"     # the prefill's: "flash" | "reference"
     dtype: Any = jnp.bfloat16
 
-    cache_kind = "latent"
+    # Leaves multiplied in float32 whatever the model's dtype: norm gains
+    # and the router (a float32 softmax decides the routing).
+    float32_endings = (("scale",), ("router", "kernel"))
+    cache_kinds = {"c_kv": "latent", "k_r": "latent"}
+    expert_matrices = ("gate", "up", "down")
+    embed_name = "tok_embed"
 
     @classmethod
-    def from_config(cls, config: dict | str, *, attention: str = "flash",
-                    dtype: Any = jnp.bfloat16) -> "MlaMoeLM":
-        """From an architecture file (a path or its object): the
-        published ``config.json`` keys, with ``num_layers``,
+    def _from_keys(cls, config: dict, *, attention: str,
+                   dtype: Any) -> "MlaMoeLM":
+        """The published ``config.json`` keys, with ``num_layers``,
         ``n_routed_experts`` and ``vocab_size`` as held here,
         ``router_width`` and ``expert_offset`` beside them."""
-        if not isinstance(config, dict):
-            with open(config) as f:
-                config = json.load(f)
         rope = config.get("rope_parameters", {})
         return cls(
             vocab_size=config["vocab_size"],
@@ -185,22 +183,13 @@ class MlaMoeLM:
         )
 
     def __post_init__(self):
-        if self.attention not in ("flash", "reference"):
-            raise ValueError(f"unknown attention backend {self.attention!r}")
+        super().__post_init__()
         if self.qk_nope_head_dim + self.qk_rope_head_dim != self.v_head_dim:
             raise ValueError(
                 "the prefill's attention takes one head size for q, k and "
                 f"v: qk {self.qk_nope_head_dim}+{self.qk_rope_head_dim} "
                 f"!= v {self.v_head_dim}"
             )
-        if self.expert_offset + self.n_routed_experts > self.router_width:
-            raise ValueError(
-                f"experts [{self.expert_offset}, "
-                f"{self.expert_offset + self.n_routed_experts}) lie past "
-                f"the router's {self.router_width}"
-            )
-        if self.num_experts_per_tok > self.router_width:
-            raise ValueError("more experts a token than the router has")
         if self.mscale_all_dim and self.mscale != self.mscale_all_dim:
             raise ValueError(
                 "the rotary cos/sin factor mscale/mscale_all_dim is taken "
@@ -209,10 +198,6 @@ class MlaMoeLM:
             )
 
     # -- sizes ---------------------------------------------------------
-
-    @property
-    def n_stats(self) -> int:
-        return 3 + self.n_routed_experts
 
     def layer_shapes(self) -> dict:
         """One layer's leaves, '/'-joined path -> shape."""
@@ -252,69 +237,6 @@ class MlaMoeLM:
                 shapes[f"params/layer_{i}/{name}"] = shape
         return shapes
 
-    # -- weights -------------------------------------------------------
-
-    def _width(self, path: str):
-        names = tuple(path.split("/"))
-        wide = any(names[-len(end):] == end for end in _FLOAT32_ENDINGS)
-        return jnp.float32 if wide else jnp.dtype(self.dtype)
-
-    def init(self, key) -> dict:
-        """Random weights, each leaf made at the width it is served in
-        (there is no checkpoint format for this family yet): normal
-        ``1/sqrt(fan-in)`` kernels, ``0.02`` embeddings, unit gains."""
-        tree: dict = {}
-        for n, (path, shape) in enumerate(self.variable_shapes().items()):
-            k = jax.random.fold_in(key, n)
-            if path.endswith("scale"):
-                leaf = jnp.ones(shape, jnp.float32)
-            else:
-                std = 0.02 if path.endswith("embedding") else shape[0] ** -0.5
-                leaf = (std * jax.random.normal(k, shape, jnp.float32)
-                        ).astype(self._width(path))
-            node = tree
-            *parents, last = path.split("/")
-            for p in parents:
-                node = node.setdefault(p, {})
-            node[last] = leaf
-        return tree
-
-    def serving_variables(self, variables, *, release: bool = False):
-        """``variables`` with each leaf at the width the model multiplies
-        it in (cast once here, leaf by leaf) and each layer's held experts
-        stacked, ``experts/gate`` and ``experts/up`` ``[E, d, F]``,
-        ``experts/down`` ``[E, F, d]``; a tree stacked already stays as it
-        is. With ``release`` each original is deleted from the device as
-        soon as what replaces it exists (the caller hands its tree over
-        and reads it no more), so the two trees are never alive together:
-        the stacking holds one layer's experts twice at most."""
-
-        def at_width(path, leaf):
-            name = "/".join(str(getattr(k, "key", k)) for k in path)
-            want = self._width(name)
-            if not jnp.issubdtype(leaf.dtype, jnp.floating) \
-                    or leaf.dtype == want:
-                return jnp.asarray(leaf)
-            narrow = jnp.asarray(leaf, want)
-            if release and isinstance(leaf, jax.Array):
-                narrow.block_until_ready()
-                leaf.delete()
-            return narrow
-
-        tree = jax.tree_util.tree_map_with_path(at_width, variables)
-        params = dict(tree["params"])
-        for i in range(self.num_layers):
-            layer = dict(params[f"layer_{i}"])
-            if "expert_0" not in layer:
-                continue
-            parts = [layer.pop(f"expert_{e}")
-                     for e in range(self.n_routed_experts)]
-            layer["experts"] = {
-                name: stack_held([p[name]["kernel"] for p in parts], release)
-                for name in ("gate", "up", "down")}
-            params[f"layer_{i}"] = layer
-        return {**tree, "params": params}
-
     # -- the cache -----------------------------------------------------
 
     def init_cache(self, slots: int, max_len: int):
@@ -330,9 +252,6 @@ class MlaMoeLM:
             for _ in range(self.num_layers))
 
     # -- pieces of a layer ---------------------------------------------
-
-    def _norm(self, x, scale):
-        return _cast(rms_norm(x, scale, self.rms_norm_eps), self.dtype)
 
     def _angles(self, pos):
         dim = self.qk_rope_head_dim
@@ -490,11 +409,6 @@ class MlaMoeLM:
                                  sw["up"]["kernel"], sw["down"]["kernel"])
         return x + _cast(routed + shared, x.dtype), stats
 
-    def _head(self, x, params):
-        with jax.named_scope("lm_head"):
-            hid = self._norm(x, params["final_norm"]["scale"])
-            return _mm(hid, params["lm_head"]["kernel"], jnp.float32)
-
     # -- what the decoder asks of a served model -----------------------
 
     def _expanded(self, params, ids, valid):
@@ -502,8 +416,7 @@ class MlaMoeLM:
         path. Returns the last hidden states ``[T, d]``, each layer's
         cache rows, the stats."""
         pos = jnp.arange(ids.shape[0], dtype=jnp.int32)
-        with jax.named_scope("embed"):
-            x = _cast(params["tok_embed"]["embedding"][ids], self.dtype)
+        x = self._embed(params, ids)
         stats = jnp.zeros(self.n_stats, jnp.int32)
         rows = []
         for i in range(self.num_layers):
@@ -545,9 +458,7 @@ class MlaMoeLM:
         params = variables["params"]
         c = tokens.shape[1]
         pos = start + jnp.arange(c, dtype=jnp.int32)
-        with jax.named_scope("embed"):
-            x = _cast(params["tok_embed"]["embedding"][tokens[0]],
-                      self.dtype)
+        x = self._embed(params, tokens[0])
         stats = jnp.zeros(self.n_stats, jnp.int32)
         new_cache = []
         for i in range(self.num_layers):
@@ -562,14 +473,8 @@ class MlaMoeLM:
             x = x + _mm(attn, w["o"]["kernel"], self.dtype)
             x, layer_stats = self._ffn(x, pos < n_real, w)
             stats = stats + layer_stats
-        last = n_real - 1 - start
-        logits = lax.cond(
-            (last >= 0) & (last < c),
-            lambda x: self._head(
-                lax.dynamic_slice_in_dim(x, jnp.clip(last, 0, c - 1), 1,
-                                         axis=0), params),
-            lambda x: jnp.zeros((1, self.vocab_size), jnp.float32), x)
-        return logits, stats, tuple(new_cache)
+        return (self._chunk_logits(x, params, start, n_real), stats,
+                tuple(new_cache))
 
     def decode_slots(self, variables, tokens, cache, pos):
         """One token for every slot at once (the expert layer routes all
@@ -581,8 +486,7 @@ class MlaMoeLM:
         (logits ``[slots, vocab]`` float32, stats, cache)."""
         params = variables["params"]
         active = pos > 0
-        with jax.named_scope("embed"):
-            x = _cast(params["tok_embed"]["embedding"][tokens], self.dtype)
+        x = self._embed(params, tokens)
         stats = jnp.zeros(self.n_stats, jnp.int32)
         new_cache = []
         for i in range(self.num_layers):
@@ -612,14 +516,3 @@ class MlaMoeLM:
             params, tokens, jnp.ones(tokens.shape[0], bool))
         return self._head(x, params), stats
 
-
-def _cast(x, dtype):
-    """``x`` at ``dtype``; ``x`` itself where it is there already (no
-    same-width convert in the traced program)."""
-    return x if x.dtype == jnp.dtype(dtype) else x.astype(dtype)
-
-
-def _mm(a, b, out_dtype):
-    """``a @ b`` at the operands' width with float32 accumulation."""
-    return _cast(jnp.matmul(a, _cast(b, a.dtype),
-                            preferred_element_type=jnp.float32), out_dtype)

@@ -45,7 +45,8 @@ input are zero, so they leave the state as it was, and they are routed
 to no expert.
 
 The model is a frozen dataclass (hashable: the serving programs take it
-as their static argument) over a plain tree of arrays; it gives the
+as their static argument) over a plain tree of arrays, its shared
+part (:class:`.served.ServedLM`) inherited; it gives the
 decoder what :mod:`..serving.lm.kvcache` asks of a served model. Its
 ``variables`` hold each expert apart (``expert_{e}``), as a checkpoint
 does; ``serving_variables`` stacks them, and every program reads the
@@ -56,7 +57,6 @@ per held expert...]`` summed over the expert layers.
 from __future__ import annotations
 
 import dataclasses
-import json
 import math
 from typing import Any
 
@@ -68,14 +68,10 @@ from jax import lax
 from ..ops.flash_attention import attention_reference, flash_attention
 from ..ops.grouped_experts import grouped_experts
 from ..ops.ssd_scan import ssd_scan, ssd_scan_reference
-from .routing import route, stack_held
+from .routing import route
+from .served import ServedLM, _cast, _mm
 from .transformer import rms_norm
 
-# Leaves held in float32 whatever the model's dtype: norm gains, the
-# state's own parameters (A_log, D, dt_bias, the conv), the router and
-# its selection bias. The recurrence and the routing run in float32.
-_FLOAT32_ENDINGS = (("scale",), ("bias",), ("conv", "kernel"),
-                    ("router", "kernel"))
 _KINDS = {"M": "mamba", "E": "moe", "*": "attn"}
 
 
@@ -90,7 +86,7 @@ def layer_kinds(pattern: str) -> tuple:
 
 
 @dataclasses.dataclass(frozen=True)
-class NemotronHLM:
+class NemotronHLM(ServedLM):
     """The architecture's numbers; see the module docstring."""
 
     vocab_size: int
@@ -123,16 +119,24 @@ class NemotronHLM:
     attention: str = "flash"
     dtype: Any = jnp.bfloat16
 
+    # Leaves held in float32 whatever the model's dtype: norm gains, the
+    # state's own parameters (A_log, D, dt_bias, the conv), the router and
+    # its selection bias. The recurrence and the routing run in float32.
+    float32_endings = (("scale",), ("bias",), ("conv", "kernel"),
+                       ("router", "kernel"))
+    # ``ssm``: the Mamba layers' states; ``conv``: their conv inputs;
+    # ``kv``: keys and values.
+    cache_kinds = {"ssm": "ssm", "conv": "conv", "k": "kv", "v": "kv"}
+    expert_matrices = ("up", "down")
+    expert_scope = "moe"
+
     @classmethod
-    def from_config(cls, config: dict | str, *, attention: str = "flash",
-                    dtype: Any = jnp.bfloat16) -> "NemotronHLM":
-        """From an architecture file (a path or its object): the
-        published ``config.json`` keys, with ``num_layers`` (the first
-        layers of the pattern), ``n_routed_experts`` and ``vocab_size`` as
-        held here, ``router_width`` and ``expert_offset`` beside them."""
-        if not isinstance(config, dict):
-            with open(config) as f:
-                config = json.load(f)
+    def _from_keys(cls, config: dict, *, attention: str,
+                   dtype: Any) -> "NemotronHLM":
+        """The published ``config.json`` keys, with ``num_layers`` (the
+        first layers of the pattern), ``n_routed_experts`` and
+        ``vocab_size`` as held here, ``router_width`` and
+        ``expert_offset`` beside them."""
         unsupported = {
             "mamba_hidden_act": ("silu",), "mlp_hidden_act": ("relu2",),
             "n_group": (1,), "n_shared_experts": (1,),
@@ -179,20 +183,12 @@ class NemotronHLM:
         )
 
     def __post_init__(self):
-        if self.attention not in ("flash", "reference"):
-            raise ValueError(f"unknown attention backend {self.attention!r}")
+        super().__post_init__()
         layer_kinds(self.pattern)
         if self.num_attention_heads % self.num_key_value_heads:
             raise ValueError("query heads are no multiple of key/value heads")
         if self.mamba_num_heads % self.n_groups:
             raise ValueError("Mamba heads are no multiple of the groups")
-        if self.expert_offset + self.n_routed_experts > self.router_width:
-            raise ValueError(
-                f"experts [{self.expert_offset}, "
-                f"{self.expert_offset + self.n_routed_experts}) lie past "
-                f"the router's {self.router_width}")
-        if self.num_experts_per_tok > self.router_width:
-            raise ValueError("more experts a token than the router has")
 
     # -- sizes ---------------------------------------------------------
 
@@ -213,8 +209,8 @@ class NemotronHLM:
         return self.d_inner + 2 * self.n_groups * self.ssm_state_size
 
     @property
-    def n_stats(self) -> int:
-        return 3 + self.n_routed_experts
+    def norm_eps(self) -> float:
+        return self.layer_norm_epsilon
 
     def layer_shapes(self, kind: str) -> dict:
         """One layer's leaves of ``kind``, '/'-joined path -> shape."""
@@ -266,85 +262,28 @@ class NemotronHLM:
 
     # -- weights -------------------------------------------------------
 
-    def _width(self, path: str):
-        names = tuple(path.split("/"))
-        wide = any(names[-len(end):] == end for end in _FLOAT32_ENDINGS)
-        return jnp.float32 if wide else jnp.dtype(self.dtype)
-
-    def init(self, key) -> dict:
-        """Random weights at Mamba-2's own initialisation (there is no
-        checkpoint format for this family yet), each leaf at the width it
-        is held in: ``A`` uniform in [1, 16] a head (``A_log`` its log),
-        ``D = 1``, ``dt_bias`` the inverse softplus of a step drawn
-        log-uniform in [time_step_min, time_step_max] (floored at
-        time_step_floor); the conv uniform within ``conv_kernel^-0.5``;
-        normal ``1/sqrt(fan-in)`` kernels, ``0.02`` embeddings, unit
-        gains, biases (the conv's, the router's selection bias) normal
-        ``0.05``."""
-        tree: dict = {}
-        for n, (path, shape) in enumerate(self.variable_shapes().items()):
-            k = jax.random.fold_in(key, n)
-            name = path.split("/", 2)[-1]          # the path in its layer
-            if name == "mamba/A_log/scale":
-                leaf = jnp.log(jax.random.uniform(k, shape, jnp.float32,
-                                                  1.0, 16.0))
-            elif name == "mamba/dt_bias/bias":
-                step = jnp.maximum(jnp.exp(jax.random.uniform(
-                    k, shape, jnp.float32, math.log(self.time_step_min),
-                    math.log(self.time_step_max))), self.time_step_floor)
-                leaf = step + jnp.log(-jnp.expm1(-step))
-            elif path.endswith("scale"):
-                leaf = jnp.ones(shape, jnp.float32)
-            elif name == "mamba/conv/kernel":
-                bound = self.conv_kernel ** -0.5
-                leaf = jax.random.uniform(k, shape, jnp.float32, -bound, bound)
-            elif path.endswith("bias"):
-                leaf = 0.05 * jax.random.normal(k, shape, jnp.float32)
-            else:
-                std = 0.02 if path.endswith("embedding") else shape[0] ** -0.5
-                leaf = std * jax.random.normal(k, shape, jnp.float32)
-            node = tree
-            *parents, last = path.split("/")
-            for p in parents:
-                node = node.setdefault(p, {})
-            node[last] = jnp.asarray(leaf, self._width(path))
-        return tree
-
-    def serving_variables(self, variables, *, release: bool = False):
-        """``variables`` with each leaf at the width the model multiplies
-        it in (cast once here, leaf by leaf) and each expert layer's held
-        experts stacked, ``moe/experts/up`` ``[E, latent, width]`` and
-        ``moe/experts/down`` ``[E, width, latent]``; a tree stacked
-        already stays as it is. With ``release`` each original is deleted
-        from the device as soon as what replaces it exists, so the two
-        trees are never alive together."""
-
-        def at_width(path, leaf):
-            name = "/".join(str(getattr(k, "key", k)) for k in path)
-            want = self._width(name)
-            if not jnp.issubdtype(leaf.dtype, jnp.floating) \
-                    or leaf.dtype == want:
-                return jnp.asarray(leaf)
-            narrow = jnp.asarray(leaf, want)
-            if release and isinstance(leaf, jax.Array):
-                narrow.block_until_ready()
-                leaf.delete()
-            return narrow
-
-        tree = jax.tree_util.tree_map_with_path(at_width, variables)
-        params = dict(tree["params"])
-        for i, kind in enumerate(self.layer_kinds):
-            moe = params[f"layer_{i}"].get("moe", {})
-            if kind != "moe" or "expert_0" not in moe:
-                continue
-            moe = dict(moe)
-            parts = [moe.pop(f"expert_{e}")
-                     for e in range(self.n_routed_experts)]
-            moe["experts"] = {
-                name: stack_held([p[name]["kernel"] for p in parts], release)
-                for name in ("up", "down")}
-            params[f"layer_{i}"] = {**params[f"layer_{i}"], "moe": moe}
-        return {**tree, "params": params}
+    def _init_leaf(self, path: str, shape, key):
+        """Mamba-2's own initialisation: ``A`` uniform in [1, 16] a head
+        (``A_log`` its log), ``D = 1``, ``dt_bias`` the inverse softplus
+        of a step drawn log-uniform in [time_step_min, time_step_max]
+        (floored at time_step_floor); the conv uniform within
+        ``conv_kernel^-0.5``; biases (the conv's, the router's selection
+        bias) normal ``0.05``; the rest as :meth:`ServedLM._init_leaf`."""
+        name = path.split("/", 2)[-1]          # the path in its layer
+        if name == "mamba/A_log/scale":
+            return jnp.log(jax.random.uniform(key, shape, jnp.float32,
+                                              1.0, 16.0))
+        if name == "mamba/dt_bias/bias":
+            step = jnp.maximum(jnp.exp(jax.random.uniform(
+                key, shape, jnp.float32, math.log(self.time_step_min),
+                math.log(self.time_step_max))), self.time_step_floor)
+            return step + jnp.log(-jnp.expm1(-step))
+        if name == "mamba/conv/kernel":
+            bound = self.conv_kernel ** -0.5
+            return jax.random.uniform(key, shape, jnp.float32, -bound, bound)
+        if path.endswith("bias"):
+            return 0.05 * jax.random.normal(key, shape, jnp.float32)
+        return super()._init_leaf(path, shape, key)
 
     # -- the cache -----------------------------------------------------
 
@@ -369,20 +308,7 @@ class NemotronHLM:
                 out.append({})
         return tuple(out)
 
-    def cache_bytes_by_kind(self, cache) -> dict:
-        """Bytes of ``cache`` by the kind of state: ``ssm`` (the Mamba
-        layers' states), ``conv`` (their conv inputs), ``kv`` (keys and
-        values)."""
-        out = {"ssm": 0, "conv": 0, "kv": 0}
-        for slabs in cache:
-            for name, slab in slabs.items():
-                out["kv" if name in ("k", "v") else name] += slab.nbytes
-        return out
-
     # -- pieces of a layer ---------------------------------------------
-
-    def _norm(self, x, scale):
-        return _cast(rms_norm(x, scale, self.layer_norm_epsilon), self.dtype)
 
     def _mamba_split(self, zxd):
         """``[z | xBC | dt]`` ``[T, in_proj]`` -> its three parts."""
@@ -483,16 +409,6 @@ class NemotronHLM:
                     {"ssm": ssm.reshape(state["ssm"].shape),
                      "conv": window[:, 1:]})
 
-    def _qkv(self, x, w):
-        """``[T, heads, head]`` q and ``[T, kv_heads, head]`` k, v."""
-        t, hd = x.shape[0], self.head_dim
-        hid = self._norm(x, w["norm"]["scale"])
-        a = w["attn"]
-        q = _mm(hid, a["q"]["kernel"], self.dtype).reshape(t, -1, hd)
-        k = _mm(hid, a["k"]["kernel"], self.dtype).reshape(t, -1, hd)
-        v = _mm(hid, a["v"]["kernel"], self.dtype).reshape(t, -1, hd)
-        return q, k, v
-
     def _attn_chunk(self, x, w, slabs, start: int):
         """Rows ``x`` ``[T, d]`` at positions ``start .. start + T``: their
         keys and values written into the one-slot ``slabs`` at ``start``,
@@ -500,8 +416,8 @@ class NemotronHLM:
         right aligned: the last query sees every row). Returns ``[T, d]``
         and the slabs."""
         t, h = x.shape[0], self.num_attention_heads
-        with jax.named_scope("attention"):
-            q, k, v = self._qkv(x, w)
+        with jax.named_scope("attn"):
+            q, k, v = self._qkv(x, w, "norm")
             slabs = {name: lax.dynamic_update_slice(
                 slabs[name], rows.transpose(1, 0, 2)[None], (0, 0, start, 0))
                 for name, rows in (("k", k), ("v", v))}
@@ -514,36 +430,6 @@ class NemotronHLM:
             out = attend(q.transpose(1, 0, 2)[None], k[None], v[None],
                          causal=True)
             out = out[0].transpose(1, 0, 2).reshape(t, h * self.head_dim)
-            return _mm(out, w["attn"]["o"]["kernel"], self.dtype), slabs
-
-    def _attn_decode(self, x, w, slabs, pos):
-        """One query a slot over that slot's rows ``0..pos`` (its new row
-        written at ``pos`` first), by a masked einsum over the slab, read
-        in the layout it is stored in."""
-        s, h, hd = x.shape[0], self.num_attention_heads, self.head_dim
-        kvh = self.num_key_value_heads
-        with jax.named_scope("attention"):
-            q, k, v = self._qkv(x, w)
-            # A write at or past max_len clamps into the slot's own last
-            # row: no slot can reach another's.
-            slabs = {name: jax.vmap(
-                lambda slab, r, p: lax.dynamic_update_slice(
-                    slab, r[:, None, :], (np.int32(0), p, np.int32(0)))
-            )(slabs[name], rows, pos)
-                for name, rows in (("k", k), ("v", v))}
-            q = q.reshape(s, kvh, h // kvh, hd)
-            scores = jnp.einsum("skld,skgd->sklg", slabs["k"], q,
-                                preferred_element_type=jnp.float32)
-            length = slabs["k"].shape[2]
-            live = jnp.arange(length, dtype=jnp.int32)[None, :] \
-                <= pos[:, None]
-            scores = jnp.where(live[:, None, :, None],
-                               scores * np.float32(hd ** -0.5),
-                               np.float32(-1e30))
-            probs = _cast(jax.nn.softmax(scores, axis=2), self.dtype)
-            out = jnp.einsum("skld,sklg->skgd", slabs["v"], probs,
-                             preferred_element_type=jnp.float32)
-            out = _cast(out, self.dtype).reshape(s, h * hd)
             return _mm(out, w["attn"]["o"]["kernel"], self.dtype), slabs
 
     def _relu2(self, x, up, down):
@@ -584,15 +470,6 @@ class NemotronHLM:
         return grouped_experts(u, experts["up"], experts["down"], r.counts,
                                r.starts, r.order, r.weight,
                                k=self.num_experts_per_tok)
-
-    def _embed(self, params, ids):
-        with jax.named_scope("embed"):
-            return _cast(params["embed"]["embedding"][ids], self.dtype)
-
-    def _head(self, x, params):
-        with jax.named_scope("lm_head"):
-            hid = self._norm(x, params["final_norm"]["scale"])
-            return _mm(hid, params["lm_head"]["kernel"], jnp.float32)
 
     # -- what the decoder asks of a served model -----------------------
 
@@ -635,16 +512,8 @@ class NemotronHLM:
         the last REAL token ``[1, vocab]`` where the chunk holds it (zeros
         where it does not: no head is computed), the stats, the cache."""
         params = variables["params"]
-        c = tokens.shape[1]
         x, cache, stats = self._run(params, tokens[0], cache, start, n_real)
-        last = n_real - 1 - start
-        logits = lax.cond(
-            (last >= 0) & (last < c),
-            lambda x: self._head(
-                lax.dynamic_slice_in_dim(x, jnp.clip(last, 0, c - 1), 1,
-                                         axis=0), params),
-            lambda x: jnp.zeros((1, self.vocab_size), jnp.float32), x)
-        return logits, stats, cache
+        return self._chunk_logits(x, params, start, n_real), stats, cache
 
     def decode_slots(self, variables, tokens, cache, pos):
         """One token for every slot at once, at each slot's ``pos``. A
@@ -662,7 +531,7 @@ class NemotronHLM:
             if kind == "mamba":
                 out, slabs = self._mamba_decode(x, w, slabs)
             elif kind == "attn":
-                out, slabs = self._attn_decode(x, w, slabs, pos)
+                out, slabs = self._attn_decode(x, w, slabs, pos, "norm")
             else:
                 out, layer_stats = self._moe(x, w, active)
                 stats = stats + layer_stats
@@ -680,14 +549,3 @@ class NemotronHLM:
                                 jnp.int32(t))
         return self._head(x, params), stats
 
-
-def _cast(x, dtype):
-    """``x`` at ``dtype``; ``x`` itself where it is there already (no
-    same-width convert in the traced program)."""
-    return x if x.dtype == jnp.dtype(dtype) else x.astype(dtype)
-
-
-def _mm(a, b, out_dtype):
-    """``a @ b`` at the operands' width with float32 accumulation."""
-    return _cast(jnp.matmul(a, _cast(b, a.dtype),
-                            preferred_element_type=jnp.float32), out_dtype)

@@ -103,14 +103,5 @@ def stack_held(leaves, release: bool):
     return stacked
 
 
-def expert_tile(t: int, k: int, router_width: int, most: int) -> int:
-    """Rows of one pass over a held expert's segment, for ``t`` rows of
-    ``k`` assignments each: at most ``most`` and ``t``, and at most
-    twice a held expert's expected share of the ``t x k`` assignments,
-    in whole 128-row tiles. A token is in one expert's segment at most
-    once, so a pass of at most ``t`` rows holds no token twice."""
-    return min(most, t, -(-2 * t * k // router_width // 128) * 128)
-
-
 def _int32(x):
     return x if x.dtype == jnp.int32 else x.astype(jnp.int32)

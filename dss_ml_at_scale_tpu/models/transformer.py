@@ -361,21 +361,16 @@ def serving_variables(model: TransformerLM, variables):
     ``--xla_allow_excess_precision=false``: by default XLA may skip a
     rounding to bfloat16 inside a fusion, it fuses a program with narrow
     arguments otherwise, and the logits differ by that rounding noise.)
-    Leaves consumed in float32 (``_FLOAT32_LEAVES``), and every leaf
-    already at its width (a float32 model's whole tree), come back as
-    the arrays they were; host (numpy) leaves are converted on the host
-    and placed narrow.
+    Leaves consumed in float32 (``_FLOAT32_LEAVES``: flax makes every
+    parameter float32), and every leaf already at its width (a float32
+    model's whole tree), come back as the arrays they were; host (numpy)
+    leaves are converted on the host and placed narrow
+    (:func:`.served.cast_tree`).
     """
-    dtype = jnp.dtype(model.dtype)
+    from .served import cast_tree, width
 
-    def at_width(path, leaf):
-        names = tuple(str(getattr(k, "key", k)) for k in path)
-        wide = any(names[-len(end):] == end for end in _FLOAT32_LEAVES)
-        if wide or not jnp.issubdtype(leaf.dtype, jnp.floating):
-            return jnp.asarray(leaf)
-        return jnp.asarray(leaf, dtype)
-
-    return jax.tree_util.tree_map_with_path(at_width, variables)
+    return cast_tree(
+        variables, lambda path: width(path, _FLOAT32_LEAVES, model.dtype))
 
 
 def init_kv_cache(model: TransformerLM, batch: int):

@@ -56,9 +56,15 @@ import jax.numpy as jnp
 
 Arena = tuple  # per layer, a dict of slabs whose leading axis is the slot
 
-# What a served model gives (``models.transformer.TransformerLM``,
-# ``models.mla_moe.MlaMoeLM``, ``models.jamba.JambaLM``): the model, not
-# this module, says what one slot's cache is and how a step reads it.
+# What a served model gives (``models.transformer.TransformerLM``, the
+# flax chat model, and the three config-built ones that
+# ``models.served.build_served`` picks by ``model_type``:
+# ``models.mla_moe.MlaMoeLM``, ``models.jamba.JambaLM``,
+# ``models.nemotron_h.NemotronHLM``): the model, not this module, says
+# what one slot's cache is and how a step reads it. The config-built
+# models inherit ``models.served.ServedLM``, which gives them
+# ``serving_variables`` (with ``release``), ``cache_bytes_by_kind``,
+# ``from_config`` and ``init``; each writes its own cache and programs.
 #
 #   init_cache(slots, max_len)           the cache for slots x max_len
 #   prefill_cache(variables, tokens[1, bucket], cache, n_real)

@@ -493,19 +493,6 @@ def test_the_shared_routing_leaves_the_outputs_and_stats_as_they_were():
          -0.10693216323852539]], rtol=0, atol=1e-6)
 
 
-def test_release_frees_each_wide_leaf_once_it_is_cast(bench):
-    model = model_of(dtype=jnp.bfloat16)
-    variables = variables_of(bench)
-    wide = jax.tree_util.tree_leaves(variables)
-    served = model.serving_variables(variables, release=True)
-    kept = {id(a) for a in jax.tree_util.tree_leaves(served)}
-    for leaf in wide:
-        assert leaf.is_deleted() != (id(leaf) in kept)
-    dtypes = {str(a.dtype) for a in jax.tree_util.tree_leaves(served)}
-    assert dtypes == {"bfloat16", "float32"}
-    assert served["params"]["layer_0"]["router"]["kernel"].dtype == jnp.float32
-
-
 def test_serving_variables_stack_each_layers_held_experts(bench):
     """Each layer's held experts become three stacked leaves in expert
     order at the served width; a stacked tree is served as it is; with

@@ -49,7 +49,6 @@ from jax import lax
 
 from dss_ml_at_scale_tpu import telemetry
 from dss_ml_at_scale_tpu.models.nemotron_h import NemotronHLM, layer_kinds
-from dss_ml_at_scale_tpu.models.routing import expert_tile
 from dss_ml_at_scale_tpu.ops.ssd_scan import ssd_scan, ssd_scan_reference
 from dss_ml_at_scale_tpu.serving.lm import (
     LMConfig,
@@ -402,11 +401,12 @@ def test_two_slots_decode_together_as_each_alone(variables):
 def _loop_experts(self, u, r, experts):
     """The per-pass loop that the grouped kernel replaced, kept as its
     reference: one ``fori_loop`` over every pass of every held expert,
-    each pass at most 256 rows of the expert's segment
-    (``routing.expert_tile``), gathered by token, through the expert and
-    added at their tokens by their weights."""
+    each pass at most 256 rows of the expert's segment (and at most
+    ``t``, and at most twice a held expert's expected share of the
+    ``t x k`` assignments, in whole 128-row tiles), gathered by token,
+    through the expert and added at their tokens by their weights."""
     t, k = u.shape[0], self.num_experts_per_tok
-    tile = expert_tile(t, k, self.router_width, 256)
+    tile = min(256, t, -(-2 * t * k // self.router_width // 128) * 128)
     step, lane = np.int32(tile), jnp.arange(tile, dtype=jnp.int32)
     passes = (r.counts + (step - 1)) // step
     ends = jnp.cumsum(passes, dtype=jnp.int32)
